@@ -39,9 +39,14 @@ def run_subprocess_bench(module: str, devices: int = 8,
                          extra_env: dict = None):
     """Run ``python -m benchmarks.<module>`` with N placeholder devices and
     forward its CSV lines. ``extra_env`` adds/overrides environment entries
-    (the smoke job sets ``BENCH_SMOKE=1`` this way)."""
+    (the smoke job sets ``BENCH_SMOKE=1`` this way).
+
+    The children are CPU emulations by design, so they are pinned to the CPU
+    backend: on an accelerator host the parent may already hold the chip,
+    and a second process cannot open it."""
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     if extra_env:
         env.update(extra_env)

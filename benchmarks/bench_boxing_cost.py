@@ -23,10 +23,11 @@ def main():
     from repro.compat import shard_map
     from repro.core.boxing import boxing_fn, transition_cost
     from repro.core.sbp import Sbp, ndsbp
+    from repro.launch.mesh import make_mesh
     from repro.launch.dryrun import _HloTextParser, wire_bytes
     from benchmarks._util import emit, timeit
 
-    mesh = jax.make_mesh((8,), ("x",))
+    mesh = make_mesh((8,), ("x",))
     shape = (256, 512)
     T = 256 * 512 * 4
 

@@ -20,8 +20,9 @@ def main():
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
     from benchmarks._util import emit, timeit
     from repro.compat import shard_map
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = make_mesh((8,), ("model",))
     V, D, N = 1 << 18, 64, 4096
     rng = np.random.default_rng(0)
     table = jnp.asarray(rng.normal(size=(V, D)).astype(np.float32))

@@ -22,9 +22,10 @@ def main():
     from benchmarks._util import emit, timeit
     from repro.compat import shard_map
     from repro.kernels.softmax_xent.ref import combine_stats, local_stats_ref
+    from repro.launch.mesh import make_mesh
     from repro.launch.dryrun import _HloTextParser, wire_bytes
 
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = make_mesh((8,), ("model",))
     N, V = 2048, 8192
     Vl = V // 8
     rng = np.random.default_rng(0)

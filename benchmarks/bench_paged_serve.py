@@ -41,6 +41,7 @@ def main():
     from benchmarks._util import emit
     from repro import api
     from repro.configs.registry import get_config
+    from repro.launch.mesh import make_mesh
     from repro.models.model_zoo import build_model
     from repro.train.steps import plan_from_mesh
 
@@ -51,7 +52,7 @@ def main():
 
     cfg = get_config("qwen2.5-3b").reduced()
     cfg = dataclasses.replace(cfg, vocab_size=1000)   # padded-vocab head
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = build_model(cfg, plan_from_mesh(mesh)).init(jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
     # short requests: prompt + generation - 1 <= 16 positions (4 pages), so

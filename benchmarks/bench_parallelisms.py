@@ -26,6 +26,7 @@ def main():
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
     from benchmarks._util import emit, timeit
     from repro.configs.registry import ARCHITECTURES
+    from repro.launch.mesh import make_mesh
     from repro.train.steps import make_train_step
 
     cfg = dataclasses.replace(
@@ -42,7 +43,7 @@ def main():
         ("zero8", (8, 1), True), ("hybrid_2x4", (2, 4), True),
     ]
     for name, (d_, m_), zero in plans:
-        mesh = jax.make_mesh((d_, m_), ("data", "model"))
+        mesh = make_mesh((d_, m_), ("data", "model"))
         ts = make_train_step(cfg, mesh, zero=zero)
         params = ts.init_params(jax.random.PRNGKey(0))
         params = jax.tree.map(

@@ -31,6 +31,7 @@ def main():
 
     from repro.configs.registry import get_config
     from repro.data.pipeline import ActorDataPipeline, SyntheticLM
+    from repro.launch.mesh import make_mesh
     from repro.optim.adamw import AdamWConfig
     from repro.train.checkpoint import load_checkpoint, save_checkpoint
     from repro.train.steps import make_train_step
@@ -43,7 +44,7 @@ def main():
     print(f"model: {n_params/1e6:.1f}M params "
           f"({cfg.num_layers}L d={cfg.d_model})")
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     ts = make_train_step(cfg, mesh, optimizer=AdamWConfig(lr=3e-4), zero=True)
     params = ts.init_params(jax.random.PRNGKey(0))
     masters = ts.shard_params_fn(params)

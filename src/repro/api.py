@@ -49,6 +49,7 @@ from repro.core.lowering import (OptimizerSpec, PrecisionPolicy, lower_plan,
                                  lower_train_plan, lower_train_stages,
                                  reassemble_sinks, split_microbatches)
 from repro.core.planner import Plan, plan as plan_sbp
+from repro.launch.mesh import as_auto
 from repro.runtime.base import RUNTIME_KINDS
 from repro.runtime.pipeline import (
     ActorPipelineExecutor, InlineServeEngine, PipelinePlan,
@@ -893,6 +894,7 @@ def _compile_serve(cfg, *, backend: str, stages: Optional[int], regs,
     import jax
 
     from repro.configs.base import ModelConfig
+    from repro.launch.mesh import make_mesh
     from repro.models.model_zoo import build_model
     from repro.models.transformer import stack_layout
     from repro.train.steps import plan_from_mesh
@@ -905,7 +907,7 @@ def _compile_serve(cfg, *, backend: str, stages: Optional[int], regs,
             "mode='serve' compiles a repro.configs.base.ModelConfig (or an "
             f"--arch name), got {type(cfg).__name__}")
     if mesh is None:
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
     plan = plan_from_mesh(mesh)
     tp = plan.tp
     (num_groups, group_size, cache_len, max_prompt_len, max_new_tokens,
@@ -930,13 +932,22 @@ def _compile_serve(cfg, *, backend: str, stages: Optional[int], regs,
     elif stages is None:
         stages = min(2, n_units)
 
-    if params is None:
+    owned = params is None
+    if owned:
         params = build_model(cfg, plan_from_mesh(mesh)).init(
             jax.random.PRNGKey(0))
+    host_params = None
+    if backend != "monolithic" and runtime == "processes":
+        # workers re-lower from data: ship host copies of the params
+        host_params = jax.device_get(params)
+    # params built here are handed over: the lowering frees each stacked
+    # body leaf once it is sliced into stages
     sstaged = lower_serve_stages(cfg, mesh, params, num_stages=stages,
                                  cache_len=cache_len,
                                  max_prompt_len=max_prompt_len,
-                                 group_size=group_size)
+                                 group_size=group_size,
+                                 consume_params=owned)
+    del params
     if isinstance(regs, str):
         regs = _policy_regs(regs, stages, num_groups)
     # shared-prefix pages assume a prompt prefix's cache values are
@@ -955,9 +966,8 @@ def _compile_serve(cfg, *, backend: str, stages: Optional[int], regs,
     else:
         recipe = None
         if runtime == "processes":
-            # workers re-lower from data: ship host copies of the params and
-            # the mesh as device ids (repro.runtime.recipes)
-            recipe = ServeRecipe(cfg, jax.device_get(params),
+            # the mesh travels as device ids (repro.runtime.recipes)
+            recipe = ServeRecipe(cfg, host_params,
                                  num_stages=stages, cache_len=cache_len,
                                  max_prompt_len=max_prompt_len,
                                  group_size=group_size,
@@ -1275,6 +1285,20 @@ def compile(graph, *, mode: str = "infer",
             "to choose)")
     if runtime is None and backend == "actors":
         runtime = "threads"
+    if runtime == "processes":
+        import jax
+
+        if jax.default_backend() != "cpu":
+            raise ValueError(
+                "runtime='processes' runs one JAX client per pipeline node "
+                "and needs the CPU backend; on "
+                f"{jax.default_backend()!r} this process already holds the "
+                "accelerator, which allows one process per chip — use "
+                "runtime='threads'")
+    # every mesh the lowering sees has Auto axes (repro.launch.mesh)
+    mesh = as_auto(mesh)
+    if stage_meshes is not None:
+        stage_meshes = [as_auto(m) for m in stage_meshes]
     if mode != "train" and (zero or precision is not None
                             or loss_scale is not None):
         raise ValueError(

@@ -1,33 +1,14 @@
-"""JAX API compatibility shims.
+"""Thin wrappers over the jax APIs the repo uses in many places.
 
-``shard_map`` graduated from ``jax.experimental`` to the ``jax`` namespace
-(and its ``check_rep`` kwarg became ``check_vma``) across jax versions; the
-repo must run on both. Import :func:`shard_map` from here instead of jax.
-
-``jax.lax.pvary`` only exists on jax versions with varying-manual-axes (vma)
-tracking; on older versions there is no vma to widen, so the identity is the
-correct shim. Import :func:`pvary` from here instead of ``jax.lax``.
+:func:`shard_map` keeps one spelling of ``jax.shard_map`` across the code
+base: ``check`` is its ``check_vma`` flag (off by default — the vma
+inference is advisory and rejects some valid psum-synced programs).
 """
 from __future__ import annotations
 
 import jax
 
-if hasattr(jax.lax, "pvary"):
-    pvary = jax.lax.pvary
-else:  # pragma: no cover - exercised via reload in tests/test_compat.py
-    def pvary(x, axis_names):
-        return x
 
-if hasattr(jax, "shard_map"):
-    def shard_map(f, mesh, in_specs, out_specs, check: bool = False):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-else:  # pragma: no cover - jax < 0.6
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs, check: bool = False):
-        # pre-vma jax has no pvary to certify replication, so its check_rep
-        # inference rejects valid programs (e.g. psum-synced optimizer
-        # states); the check is advisory — disable it there.
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+def shard_map(f, mesh, in_specs, out_specs, check: bool = False):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)

@@ -1328,9 +1328,32 @@ def _serve_subtree(tree, lo: int, hi: int, n_pro: int, slice_periods: bool):
     return {"prologue": pro, "body": body}
 
 
+def _slice_body(body, period_bounds, consume: bool):
+    """Per-stage slices ``[plo, phi)`` of the stacked body params, built one
+    leaf at a time. A stage that spans a whole leaf shares it uncopied.
+    With ``consume``, each full leaf is deleted as soon as its slices
+    exist, so the full tree and all the slices are never resident at once
+    (the caller owns ``body`` and drops it)."""
+    leaves, treedef = jax.tree.flatten(body)
+    per_stage: List[List[Any]] = [[] for _ in period_bounds]
+    for a in leaves:
+        shared = False
+        for out, (plo, phi) in zip(per_stage, period_bounds):
+            if phi <= plo:
+                continue
+            whole = (plo, phi) == (0, a.shape[0])
+            shared |= whole
+            out.append(a if whole else a[plo:phi])
+        if consume and not shared:
+            a.delete()
+    return [treedef.unflatten(out) if phi > plo else []
+            for out, (plo, phi) in zip(per_stage, period_bounds)]
+
+
 def lower_serve_stages(cfg, mesh, params: Dict[str, Any], num_stages: int,
                        cache_len: int, max_prompt_len: int, group_size: int,
-                       sliding_window: int = 0) -> ServeStagedProgram:
+                       sliding_window: int = 0,
+                       consume_params: bool = False) -> ServeStagedProgram:
     """Cut the decode step of a :class:`repro.configs.base.ModelConfig`
     model into ``num_stages`` jitted stage programs (stage = contiguous
     slice of the layer stack; tensor parallelism via shard_map *inside*
@@ -1339,9 +1362,11 @@ def lower_serve_stages(cfg, mesh, params: Dict[str, Any], num_stages: int,
     ``params`` are the full model params (as built by
     ``repro.models.model_zoo.build_model(cfg, plan).init``); each stage gets
     its slice, plus the embedding on the first stage and the final norm +
-    unembedding head on the last.
+    unembedding head on the last. ``consume_params=True`` hands the body
+    params to the lowering: each stacked leaf is freed once its stage
+    slices exist, so peak device memory stays near one copy of the model.
     """
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.models import transformer as T
     from repro.models.common import MeshPlan
@@ -1390,11 +1415,17 @@ def lower_serve_stages(cfg, mesh, params: Dict[str, Any], num_stages: int,
         bounds.append((lo, lo + sz))
         lo += sz
 
+    bodies = _slice_body(params["body"], [
+        (max(lo - n_pro, 0), max(hi - n_pro, 0)) for lo, hi in bounds],
+        consume_params)
+
     stages: List[ServeStage] = []
     for s, (lo, hi) in enumerate(bounds):
         first, last = s == 0, s == num_stages - 1
         pro_kinds = lay.prologue[lo:min(hi, n_pro)]
-        sparams = _serve_subtree(params, lo, hi, n_pro, True)
+        sparams = {"prologue": list(params["prologue"][lo:min(hi, n_pro)]),
+                   "body": bodies[s]}
+        bodies[s] = None
         sspecs = _serve_subtree(pspecs_full, lo, hi, n_pro, False)
         grp_cspecs = _serve_subtree(cspecs_grp, lo, hi, n_pro, False)
         one_cspecs = _serve_subtree(cspecs_one, lo, hi, n_pro, False)
@@ -1405,6 +1436,11 @@ def lower_serve_stages(cfg, mesh, params: Dict[str, Any], num_stages: int,
             for k in ("final_norm", "unembed"):
                 sparams[k] = params[k]
                 sspecs[k] = pspecs_full[k]
+        # place the stage's params on its mesh once (free where they already
+        # sit there), so no call reshards them
+        sparams = jax.device_put(sparams, jax.tree.map(
+            lambda spec: NamedSharding(mesh, spec), sspecs,
+            is_leaf=lambda x: isinstance(x, P)))
 
         def local_decode(p, caches, xin, pos, _first=first, _last=last,
                          _kinds=pro_kinds):

@@ -75,7 +75,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            sliding_window: int = 0, q_offset: int = 0,
                            block_q: int = 128, block_k: int = 128,
-                           sm_scale=None, interpret: bool = True):
+                           sm_scale=None, interpret: bool = False):
     """q: (B, Sq, H, D); k, v: (B, Sk, KV, D), H % KV == 0.
 
     Matches :func:`repro.kernels.flash_attention.ref.flash_attention_ref`.

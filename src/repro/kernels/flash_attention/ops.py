@@ -10,7 +10,7 @@ from repro.kernels.flash_attention.ref import flash_attention_ref
 @partial(jax.jit, static_argnames=("causal", "sliding_window", "q_offset",
                                    "use_pallas", "interpret"))
 def flash_attention(q, k, v, *, causal=True, sliding_window=0, q_offset=0,
-                    use_pallas=False, interpret=True):
+                    use_pallas=False, interpret=False):
     if use_pallas:
         return flash_attention_pallas(q, k, v, causal=causal,
                                       sliding_window=sliding_window,

@@ -10,7 +10,7 @@ from repro.kernels.flash_decode.ref import flash_decode_partial_ref
 @partial(jax.jit, static_argnames=("k_offset", "sliding_window",
                                    "use_pallas", "interpret"))
 def flash_decode_partial(q, k, v, *, cur_pos, k_offset=0, sliding_window=0,
-                         use_pallas=False, interpret=True):
+                         use_pallas=False, interpret=False):
     if use_pallas:
         return flash_decode_pallas(q, k, v, cur_pos=cur_pos,
                                    k_offset=k_offset,

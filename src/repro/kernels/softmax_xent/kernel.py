@@ -7,7 +7,11 @@ combined across shards by the SBP partial-value boxing outside.
 Grid: (row_blocks, vocab_blocks) — vocab is the innermost (fastest) axis so
 the running stats live in VMEM scratch across vocab tiles and are emitted on
 the last tile. Tiles are MXU/VPU aligned: (block_rows x block_vocab) with
-block_vocab a multiple of 128.
+block_vocab a multiple of 128. Per-row vectors (labels, stats) are
+``(rows, 1)`` columns so every block is 2-D; the shard's vocab offset is a
+scalar-prefetch operand in SMEM. The label gather is an ``iota == label``
+masked row sum (exactly one column matches), which the TPU lowers to plain
+VPU compares and a lane reduction.
 """
 from __future__ import annotations
 
@@ -21,7 +25,7 @@ import jax.experimental.pallas.tpu as pltpu
 NEG_INF = -1e30
 
 
-def _xent_kernel(logits_ref, labels_ref, voff_ref,
+def _xent_kernel(voff_ref, logits_ref, labels_ref,
                  m_ref, s_ref, z_ref,
                  m_scr, s_scr, z_scr,
                  *, block_v: int, n_vblocks: int, vocab_local: int):
@@ -34,28 +38,27 @@ def _xent_kernel(logits_ref, labels_ref, voff_ref,
         z_scr[...] = jnp.zeros_like(z_scr)
 
     x = logits_ref[...].astype(jnp.float32)          # (bR, bV)
-    labels = labels_ref[...]                         # (bR,)
+    labels = labels_ref[...]                         # (bR, 1)
     voff = voff_ref[0]                               # global col of shard
 
     # mask the padding tail of the last vocab tile
-    col = vi * block_v + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    valid = col < vocab_local
+    tile_col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    valid = vi * block_v + tile_col < vocab_local
     x = jnp.where(valid, x, NEG_INF)
 
     m_old = m_scr[...]
-    m_new = jnp.maximum(m_old, x.max(axis=1))
+    m_new = jnp.maximum(m_old, x.max(axis=1, keepdims=True))
     scale = jnp.exp(m_old - m_new)
-    s_scr[...] = s_scr[...] * scale + jnp.exp(x - m_new[:, None]).sum(axis=1)
+    s_scr[...] = s_scr[...] * scale + jnp.exp(x - m_new).sum(
+        axis=1, keepdims=True)
     m_scr[...] = m_new
 
     # label gather: the label's local column may fall in this tile
     shard_col = labels - voff
-    local_col = shard_col - vi * block_v
-    hit = ((local_col >= 0) & (local_col < block_v)
-           & (shard_col >= 0) & (shard_col < vocab_local))
-    safe = jnp.clip(local_col, 0, block_v - 1)
-    picked = jnp.take_along_axis(x, safe[:, None], axis=1)[:, 0]
-    z_scr[...] = z_scr[...] + jnp.where(hit, picked, 0.0)
+    local_col = shard_col - vi * block_v             # (bR, 1)
+    hit = (tile_col == local_col) & valid & (shard_col >= 0)
+    z_scr[...] = z_scr[...] + jnp.where(hit, x, 0.0).sum(axis=1,
+                                                         keepdims=True)
 
     @pl.when(vi == n_vblocks - 1)
     def _emit():
@@ -66,7 +69,7 @@ def _xent_kernel(logits_ref, labels_ref, voff_ref,
 
 def xent_local_stats_pallas(logits, labels, vocab_offset, *,
                             block_rows: int = 256, block_v: int = 512,
-                            interpret: bool = True):
+                            interpret: bool = False):
     """logits: (N, Vl); labels: (N,) global ids; vocab_offset: scalar.
 
     Returns (m, s, z) local stats, identical to
@@ -78,36 +81,28 @@ def xent_local_stats_pallas(logits, labels, vocab_offset, *,
     pr = (-N) % block_rows
     pv = (-Vl) % block_v
     lp = jnp.pad(logits, ((0, pr), (0, pv)))
-    lbl = jnp.pad(labels, (0, pr))
+    lbl = jnp.pad(labels.astype(jnp.int32), (0, pr))[:, None]
     Np, Vp = lp.shape
     n_r, n_v = Np // block_rows, Vp // block_v
-    voff = jnp.asarray([vocab_offset], jnp.int32)
+    voff = jnp.reshape(jnp.asarray(vocab_offset, jnp.int32), (1,))
 
     kernel = functools.partial(_xent_kernel, block_v=block_v, n_vblocks=n_v,
                                vocab_local=Vl)
+    col = pl.BlockSpec((block_rows, 1), lambda r, v, off: (r, 0))
+    stat = jax.ShapeDtypeStruct((Np, 1), jnp.float32)
     m, s, z = pl.pallas_call(
         kernel,
-        grid=(n_r, n_v),
-        in_specs=[
-            pl.BlockSpec((block_rows, block_v), lambda r, v: (r, v)),
-            pl.BlockSpec((block_rows,), lambda r, v: (r,)),
-            pl.BlockSpec((1,), lambda r, v: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_rows,), lambda r, v: (r,)),
-            pl.BlockSpec((block_rows,), lambda r, v: (r,)),
-            pl.BlockSpec((block_rows,), lambda r, v: (r,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((Np,), jnp.float32),
-            jax.ShapeDtypeStruct((Np,), jnp.float32),
-            jax.ShapeDtypeStruct((Np,), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_rows,), jnp.float32),
-            pltpu.VMEM((block_rows,), jnp.float32),
-            pltpu.VMEM((block_rows,), jnp.float32),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n_r, n_v),
+            in_specs=[
+                pl.BlockSpec((block_rows, block_v), lambda r, v, off: (r, v)),
+                col,
+            ],
+            out_specs=[col, col, col],
+            scratch_shapes=[pltpu.VMEM((block_rows, 1), jnp.float32)] * 3,
+        ),
+        out_shape=[stat, stat, stat],
         interpret=interpret,
-    )(lp, lbl, voff)
-    return m[:N], s[:N], z[:N]
+    )(voff, lp, lbl)
+    return m[:N, 0], s[:N, 0], z[:N, 0]

@@ -9,7 +9,7 @@ from repro.kernels.softmax_xent.ref import local_stats_ref
 
 @partial(jax.jit, static_argnames=("vocab_offset", "use_pallas", "interpret"))
 def xent_local_stats(logits, labels, vocab_offset=0, *, use_pallas=False,
-                     interpret=True):
+                     interpret=False):
     if use_pallas:
         return xent_local_stats_pallas(logits, labels, vocab_offset,
                                        interpret=interpret)

@@ -9,7 +9,7 @@ from repro.kernels.ssd_scan.ref import ssd_chunked_ref
 
 @partial(jax.jit, static_argnames=("chunk", "use_pallas", "interpret"))
 def ssd_scan(x, dt, A, Bm, Cm, D, *, chunk=128, use_pallas=False,
-             interpret=True):
+             interpret=False):
     if use_pallas:
         return ssd_scan_pallas(x, dt, A, Bm, Cm, D, chunk=chunk,
                                interpret=interpret)

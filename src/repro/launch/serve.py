@@ -130,15 +130,16 @@ def main():
     ap.add_argument("--mesh", default="1x1")
     args = ap.parse_args()
 
-    import jax
-
     from repro.configs.registry import get_config
+    from repro.launch.mesh import make_mesh
+    from repro.launch.xla_env import enable_compile_cache
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.reduced()
     d_, m_ = (int(v) for v in args.mesh.split("x"))
-    mesh = jax.make_mesh((d_, m_), ("data", "model"))
+    mesh = make_mesh((d_, m_), ("data", "model"))
 
     if args.classic or cfg.embed_frontend or cfg.encoder_decoder:
         classic_loop(cfg, args, mesh)

@@ -35,15 +35,18 @@ def main():
 
     from repro.configs.registry import get_config
     from repro.data.pipeline import ActorDataPipeline, SyntheticLM
+    from repro.launch.mesh import make_mesh
+    from repro.launch.xla_env import enable_compile_cache
     from repro.optim.adamw import AdamWConfig
     from repro.train.checkpoint import save_checkpoint
     from repro.train.steps import make_train_step
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.reduced()
     d_, m_ = (int(v) for v in args.mesh.split("x"))
-    mesh = jax.make_mesh((d_, m_), ("data", "model"))
+    mesh = make_mesh((d_, m_), ("data", "model"))
 
     ts = make_train_step(cfg, mesh, optimizer=AdamWConfig(lr=args.lr),
                          zero=args.zero)

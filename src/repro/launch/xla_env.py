@@ -1,4 +1,5 @@
-"""Per-worker XLA environment setup for the process-backed actor runtime.
+"""XLA environment setup: per-worker flags for the process-backed actor
+runtime, and the persistent compilation cache of the entry points.
 
 Each :class:`repro.runtime.process.ProcessRuntime` worker is a fresh spawned
 interpreter, so it gets its own XLA client — the one chance to set
@@ -63,3 +64,23 @@ def apply_worker_env(node: int) -> None:
         os.environ["REPRO_WORKER_NODE"] = str(node)
         return
     os.environ.update(worker_env(node))
+
+
+def enable_compile_cache() -> None:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing is changed here. Otherwise the cache lives at the fixed path
+    ``<repo>/.jax_cache`` — the path is part of the cache key, so a
+    temporary or per-process directory would never hit. Called by the entry
+    points (``chip_smoke.py``, :mod:`repro.launch.train`,
+    :mod:`repro.launch.serve`), never on import.
+    """
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import pathlib
+
+    import jax
+
+    repo = pathlib.Path(__file__).resolve().parents[3]
+    jax.config.update("jax_compilation_cache_dir", str(repo / ".jax_cache"))

@@ -15,7 +15,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import pvary
 from repro.core.boxing import boxing_fn
 from repro.core.sbp import Split, ndsbp
 
@@ -125,20 +124,20 @@ def maybe_grad_sync(x, plan: "MeshPlan"):
 
 def bound_axes(axis_names):
     """Which of ``axis_names`` are live shard_map axes in this trace."""
-    live = set(jax.core.unsafe_get_axis_names_DO_NOT_USE())
+    live = set(jax.sharding.get_abstract_mesh().manual_axes)
     return tuple(n for n in axis_names if n in live)
 
 
 def force_vary(x, axis_names):
     """Make x's vma cover all live ``axis_names`` (scan carries must have
-    a consistent vma across architectures; pvary is free). No-op outside
+    a consistent vma across architectures; the pcast is free). No-op outside
     shard_map."""
     names = bound_axes(axis_names)
     if not names:
         return x
-    vma = getattr(jax.core.get_aval(x), "vma", frozenset()) or frozenset()
+    vma = jax.typeof(x).vma
     missing = tuple(n for n in names if n not in vma)
-    return pvary(x, missing) if missing else x
+    return jax.lax.pcast(x, missing, to="varying") if missing else x
 
 
 def certified_pmean(x, axis_name):

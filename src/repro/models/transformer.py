@@ -20,7 +20,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -246,6 +246,20 @@ def _cross_attn_decode(p, x, xk, xv, cfg, plan):
 # whole model
 # ---------------------------------------------------------------------------
 
+def _stack_trees(trees: List[Any]):
+    """Stack same-structured trees leaf by leaf, emptying ``trees`` as it
+    goes: each per-layer leaf is released once its stack exists, so the
+    stacked body and the per-layer copies are never both resident."""
+    treedef = jax.tree.structure(trees[0])
+    cols = [list(c) for c in zip(*(jax.tree.leaves(t) for t in trees))]
+    trees.clear()
+    stacked = []
+    for i in range(len(cols)):
+        stacked.append(jnp.stack(cols[i]))
+        cols[i] = None
+    return treedef.unflatten(stacked)
+
+
 def init_model(key, cfg: ModelConfig, plan: MeshPlan) -> Dict:
     d, Vp = cfg.d_model, cfg.padded_vocab()
     lay = stack_layout(cfg)
@@ -265,7 +279,7 @@ def init_model(key, cfg: ModelConfig, plan: MeshPlan) -> Dict:
         per = [init_block(jax.random.fold_in(kb[i], j), cfg, plan, kind,
                           mlp_kind, cross=cfg.encoder_decoder)
                for i in range(lay.n_periods)]
-        body.append(jax.tree.map(lambda *xs: jnp.stack(xs), *per))
+        body.append(_stack_trees(per))
     p["body"] = body
     if cfg.encoder_decoder:
         enc = [init_block(jax.random.fold_in(ks[3], i), cfg, plan,

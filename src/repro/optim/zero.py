@@ -258,6 +258,12 @@ def combine_model_grads(grads, combine, plan: MeshPlan):
 # the update (operates on flat shards)
 # ---------------------------------------------------------------------------
 
+def _sumsq(g):
+    """fp32 sum of squares over the flat element order. ZeRO and plain DP
+    both reduce this 1-d layout, so their clip norms agree bitwise."""
+    return jnp.sum(jnp.square(g.astype(jnp.float32).reshape(-1)))
+
+
 def zero_adamw_update(cfg: AdamWConfig, masters, grads_flat, state: ZeroState,
                       plan: MeshPlan, replication, lr_scale=1.0):
     """Adam on (1,1,chunk) master shards. ``grads_flat`` has the same layout
@@ -267,7 +273,7 @@ def zero_adamw_update(cfg: AdamWConfig, masters, grads_flat, state: ZeroState,
     axes = plan.data_axes if len(plan.data_axes) > 1 else plan.data_axes[0]
 
     sumsq = sum(
-        jnp.sum(jnp.square(g.astype(jnp.float32))) / r
+        _sumsq(g) / r
         for g, r in zip(jax.tree.leaves(grads_flat),
                         jax.tree.leaves(replication)))
     if dp > 1:
@@ -312,7 +318,7 @@ def plain_dp_adamw_update(cfg: AdamWConfig, params, grads, state,
 
     grads = jax.tree.map(reduce_grad, grads)
     sumsq = sum(
-        jnp.sum(jnp.square(g)) / r
+        _sumsq(g) / r
         for g, r in zip(jax.tree.leaves(grads), jax.tree.leaves(replication)))
     if plan.tp > 1:
         sumsq = jax.lax.psum(sumsq, plan.model_axis)
@@ -352,7 +358,7 @@ def _certify_replicated(tree, replication, plan: MeshPlan):
         return tree
 
     def fix(x, r):
-        vma = getattr(jax.core.get_aval(x), "vma", frozenset())
+        vma = jax.typeof(x).vma
         if plan.model_axis not in vma:
             return x
         if r <= 1 and plan.tp > 1:

@@ -129,12 +129,11 @@ class ServeRecipe:
     mesh: Optional[MeshSpec] = None
 
     def lower(self):
-        import jax
-
         from repro.core.lowering import lower_serve_stages
+        from repro.launch.mesh import make_mesh
 
         mesh = (self.mesh.to_mesh() if self.mesh is not None
-                else jax.make_mesh((1, 1), ("data", "model")))
+                else make_mesh((1, 1), ("data", "model")))
         return lower_serve_stages(self.cfg, mesh, self.params,
                                   num_stages=self.num_stages,
                                   cache_len=self.cache_len,

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import pvary, shard_map
+from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.models.common import MeshPlan
 from repro.models.model_zoo import build_model, cache_specs, make_decode_caches
@@ -150,10 +150,10 @@ def make_train_step(cfg: ModelConfig, mesh, optimizer: AdamWConfig = None,
     cdt = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
 
     def certified_mean(v):
-        vma = getattr(jax.core.get_aval(v), "vma", frozenset())
+        vma = jax.typeof(v).vma
         missing = tuple(n for n in plan.axis_names if n not in vma)
         if missing:
-            v = pvary(v, missing)
+            v = jax.lax.pcast(v, missing, to="varying")
         return jax.lax.pmean(v, plan.axis_names)
 
     metric_names = {"lm_loss": 0, "aux_loss": 0, "loss": 0,

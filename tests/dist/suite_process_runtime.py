@@ -79,12 +79,13 @@ def serve_processes_match_threads():
 
     from repro import api
     from repro.configs.registry import get_config
+    from repro.launch.mesh import make_mesh
     from repro.models.model_zoo import build_model
     from repro.train.steps import plan_from_mesh
 
     cfg = get_config("qwen2.5-3b").reduced()
     cfg = dataclasses.replace(cfg, vocab_size=1000)
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = make_mesh((1, 2), ("data", "model"))
     params = build_model(cfg, plan_from_mesh(mesh)).init(
         jax.random.PRNGKey(0))
     rng = np.random.default_rng(1)

@@ -53,12 +53,13 @@ def run_mesh(mesh_shape, group_size, num_groups, gens, label):
 
     from repro import api
     from repro.configs.registry import get_config
+    from repro.launch.mesh import make_mesh
     from repro.models.model_zoo import build_model
     from repro.train.steps import plan_from_mesh
 
     cfg = get_config("qwen2.5-3b").reduced()
     cfg = dataclasses.replace(cfg, vocab_size=1000)   # padded vocab
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = make_mesh(mesh_shape, ("data", "model"))
     params = build_model(cfg, plan_from_mesh(mesh)).init(jax.random.PRNGKey(0))
     rng = np.random.default_rng(1)
     prompts = [rng.integers(0, cfg.vocab_size, (PROMPT_LEN,)).astype(np.int32)

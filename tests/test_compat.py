@@ -1,48 +1,40 @@
-"""The jax compat shims (repro/compat.py).
-
-``jax.lax.pvary`` does not exist on older jax versions (pre-vma); the shim
-must resolve to the identity there so ``models/common.py:force_vary`` and the
-train-step metrics path keep working (the `bench_parallelisms` known issue
-from ROADMAP).
+"""The jax API wrappers (repro/compat.py) and the vma helpers built on the
+installed jax: ``jax.lax.pcast(..., to="varying")`` replaced the deprecated
+``jax.lax.pvary``, and ``jax.typeof`` replaced ``jax.core.get_aval``.
 """
-import importlib
-
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.sharding import PartitionSpec as P
 
 import repro.compat
 
 
 class TestPvaryShim:
     def test_pvary_resolves_on_current_jax(self):
-        # on a jax with jax.lax.pvary the shim is the real primitive
-        if hasattr(jax.lax, "pvary"):
-            assert repro.compat.pvary is jax.lax.pvary
+        """``pcast`` to varying widens the vma inside shard_map — the
+        primitive ``force_vary`` and the train-step metrics rely on."""
+        from repro.launch.mesh import make_mesh
 
-    def test_pvary_falls_back_to_identity_without_jax_lax_pvary(
-            self, monkeypatch):
-        """Simulate an old jax: delete the attribute, reload the shim, and
-        check pvary degrades to the identity (then restore)."""
-        monkeypatch.delattr(jax.lax, "pvary", raising=False)
-        try:
-            importlib.reload(repro.compat)
-            x = jnp.arange(3.0)
-            out = repro.compat.pvary(x, ("data", "model"))
-            assert out is x
-        finally:
-            monkeypatch.undo()
-            importlib.reload(repro.compat)
-        if hasattr(jax.lax, "pvary"):
-            assert repro.compat.pvary is jax.lax.pvary
+        mesh = make_mesh((1,), ("data",))
+
+        def f(x):
+            y = jax.lax.pcast(x, ("data",), to="varying")
+            assert "data" in jax.typeof(y).vma
+            return y
+
+        out = repro.compat.shard_map(f, mesh, P(), P("data"),
+                                     check=True)(jnp.ones(3))
+        np.testing.assert_array_equal(np.asarray(out), np.ones(3))
 
     def test_force_vary_routes_through_compat(self):
-        """models/common.py must import the shim, not jax.lax directly —
-        outside shard_map force_vary is a no-op either way."""
+        """models/common.py uses the installed jax's pcast, not the
+        deprecated pvary — outside shard_map force_vary is a no-op."""
         import repro.models.common as common
 
         src = open(common.__file__).read()
-        assert "from repro.compat import pvary" in src
-        assert "jax.lax.pvary" not in src
+        assert "jax.lax.pcast" in src
+        assert "pvary" not in src
         x = jnp.ones((2, 2))
         assert common.force_vary(x, ("data",)) is x  # no live axes -> no-op
 

@@ -47,7 +47,8 @@ def test_flash_attention_kernel_vs_oracle(case):
     v = jnp.asarray(RNG.normal(size=(B, Sk, KV, Dv)), dt)
     qoff = Sk - Sq if causal else 0
     got = flash_attention_pallas(q, k, v, causal=causal, sliding_window=w,
-                                 q_offset=qoff, block_q=16, block_k=16)
+                                 q_offset=qoff, block_q=16, block_k=16,
+                                 interpret=True)
     want = attention_dense_ref(q, k, v, causal=causal, sliding_window=w,
                                q_offset=qoff)
     assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32),
@@ -85,7 +86,7 @@ def test_flash_decode_kernel_vs_oracle(case):
     v = jnp.asarray(RNG.normal(size=(B, L, KV, D)), dt)
     cur = jnp.asarray(RNG.integers(10, L, size=(B,)), jnp.int32)
     m1, l1, a1 = flash_decode_pallas(q, k, v, cur_pos=cur, sliding_window=w,
-                                     block_k=16)
+                                     block_k=16, interpret=True)
     o1 = a1 / jnp.maximum(l1, 1e-30)[..., None]
     want = decode_attention_ref(q, k, v, cur, sliding_window=w)
     assert_allclose(np.asarray(o1, np.float32), np.asarray(want, np.float32),
@@ -101,7 +102,8 @@ def test_flash_decode_shard_combine():
     v = jnp.asarray(RNG.normal(size=(B, L, KV, D)), jnp.float32)
     cur = jnp.asarray([40, 63], jnp.int32)
     parts = [flash_decode_pallas(q, k[:, i*16:(i+1)*16], v[:, i*16:(i+1)*16],
-                                 cur_pos=cur, k_offset=i*16, block_k=8)
+                                 cur_pos=cur, k_offset=i*16, block_k=8,
+                                 interpret=True)
              for i in range(4)]
     m = jnp.stack([p[0] for p in parts])
     l = jnp.stack([p[1] for p in parts])
@@ -128,7 +130,8 @@ def test_xent_kernel_vs_oracle(case):
     N, Vl, off, dt = case
     logits = jnp.asarray(RNG.normal(size=(N, Vl)) * 3, dt)
     labels = jnp.asarray(RNG.integers(0, 3 * Vl, size=(N,)), jnp.int32)
-    m1, s1, z1 = xent_local_stats_pallas(logits, labels, off, block_v=256)
+    m1, s1, z1 = xent_local_stats_pallas(logits, labels, off, block_v=256,
+                                         interpret=True)
     m2, s2, z2 = local_stats_ref(logits, labels, off)
     tol = _tol(dt)
     assert_allclose(np.asarray(m1), np.asarray(m2), **tol)
@@ -143,7 +146,8 @@ def test_xent_shard_combine_matches_full():
     labels = jnp.asarray(RNG.integers(0, V, size=(N,)), jnp.int32)
     Vl = V // 4
     stats = [xent_local_stats_pallas(logits[:, i*Vl:(i+1)*Vl], labels, i*Vl,
-                                     block_v=128) for i in range(4)]
+                                     block_v=128, interpret=True)
+             for i in range(4)]
     m = jnp.stack([s[0] for s in stats])
     s_ = jnp.stack([s[1] for s in stats])
     z = jnp.stack([s[2] for s in stats])
@@ -173,7 +177,7 @@ def test_ssd_kernel_vs_sequential_oracle(case):
     Bm = jnp.asarray(RNG.normal(size=(B, L, G, N)), dt)
     Cm = jnp.asarray(RNG.normal(size=(B, L, G, N)), dt)
     D = jnp.asarray(RNG.normal(size=(H,)), jnp.float32)
-    y1, h1 = ssd_scan_pallas(x, dtv, A, Bm, Cm, D, chunk=Q)
+    y1, h1 = ssd_scan_pallas(x, dtv, A, Bm, Cm, D, chunk=Q, interpret=True)
     y2, h2 = ssd_sequential_ref(x, dtv, A, Bm, Cm, D)
     tol = _tol(dt)
     assert_allclose(np.asarray(y1, np.float32), np.asarray(y2, np.float32),

@@ -17,6 +17,7 @@ jax = pytest.importorskip("jax")
 
 from repro import api
 from repro.configs.registry import get_config
+from repro.launch.mesh import make_mesh
 from repro.models.model_zoo import build_model
 from repro.serve.paged_cache import PagePool, PagedCacheSpec
 from repro.train.steps import plan_from_mesh
@@ -32,7 +33,7 @@ NUM_PAGES = 8
 def serve_env():
     cfg = get_config("qwen2.5-3b").reduced()
     cfg = dataclasses.replace(cfg, vocab_size=1000)   # padded head columns
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = build_model(cfg, plan_from_mesh(mesh)).init(
         jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
@@ -155,7 +156,7 @@ class TestPagedTokenIdentity:
         """Recurrent state (SSM h, conv tails) lives in the per-request row
         pool, not the page slabs; paged serving must still match dense."""
         cfg = get_config("mamba2-370m").reduced()
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         params = build_model(cfg, plan_from_mesh(mesh)).init(
             jax.random.PRNGKey(0))
         rng = np.random.default_rng(2)

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from repro import api
 from repro.configs.registry import get_config
+from repro.launch.mesh import make_mesh
 from repro.models.model_zoo import build_model
 from repro.train.steps import (greedy_from_logits, make_serve_step,
                                plan_from_mesh)
@@ -33,7 +34,7 @@ def serve_env():
     # greedy selection must never pick
     cfg = dataclasses.replace(cfg, vocab_size=1000)
     assert cfg.padded_vocab() > cfg.vocab_size
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = build_model(cfg, plan_from_mesh(mesh)).init(jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, cfg.vocab_size, (PROMPT_LEN,)).astype(np.int32)
@@ -135,7 +136,7 @@ class TestSSMServe:
         at their natural length. Each request is checked against its own
         monolithic B=1 serve loop."""
         cfg = get_config("mamba2-370m").reduced()
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         params = build_model(cfg, plan_from_mesh(mesh)).init(
             jax.random.PRNGKey(0))
         rng = np.random.default_rng(2)
