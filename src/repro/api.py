@@ -663,63 +663,68 @@ class ServeSession:
         validates, drives the engine, and turns round results into
         tokens."""
         import numpy as np
+        from jax.profiler import TraceAnnotation
 
         from repro.serve.admission import AdmissionScheduler
 
         reqs = self._normalize(requests)
-        V = self.cfg.vocab_size
-        prompts = []
-        for i, r in enumerate(reqs):
-            toks = np.asarray(r.tokens, dtype=np.int32)
-            if toks.ndim != 1 or toks.size == 0:
-                raise ValueError(f"request {i}: prompt must be a non-empty "
-                                 f"1-d token array, got shape {toks.shape}")
-            if toks.size > self.max_prompt_len:
-                raise ValueError(
-                    f"request {i}: prompt length {toks.size} exceeds "
-                    f"max_prompt_len={self.max_prompt_len}")
-            if (toks < 0).any() or (toks >= V).any():
-                raise ValueError(f"request {i}: prompt ids must be in "
-                                 f"[0, {V})")
-            if not (1 <= r.max_new_tokens <= self.max_new_tokens):
-                raise ValueError(
-                    f"request {i}: max_new_tokens={r.max_new_tokens} must "
-                    f"be in [1, {self.max_new_tokens}]")
-            prompts.append(toks)
+        with TraceAnnotation("serve.generate"):
+            V = self.cfg.vocab_size
+            prompts = []
+            for i, r in enumerate(reqs):
+                toks = np.asarray(r.tokens, dtype=np.int32)
+                if toks.ndim != 1 or toks.size == 0:
+                    raise ValueError(
+                        f"request {i}: prompt must be a non-empty 1-d token "
+                        f"array, got shape {toks.shape}")
+                if toks.size > self.max_prompt_len:
+                    raise ValueError(
+                        f"request {i}: prompt length {toks.size} exceeds "
+                        f"max_prompt_len={self.max_prompt_len}")
+                if (toks < 0).any() or (toks >= V).any():
+                    raise ValueError(f"request {i}: prompt ids must be in "
+                                     f"[0, {V})")
+                if not (1 <= r.max_new_tokens <= self.max_new_tokens):
+                    raise ValueError(
+                        f"request {i}: max_new_tokens={r.max_new_tokens} "
+                        f"must be in [1, {self.max_new_tokens}]")
+                prompts.append(toks)
 
-        pool = None
-        if self.cache == "paged":
-            from repro.serve.paged_cache import PagePool
+            pool = None
+            if self.cache == "paged":
+                from repro.serve.paged_cache import PagePool
 
-            pool = PagePool(self.cache_spec)
-        sched = AdmissionScheduler(
-            prompts, [r.max_new_tokens for r in reqs],
-            num_groups=self.num_groups, group_size=self.group_size,
-            cache_len=self.cache_len, pool=pool,
-            prefill_chunk=self.prefill_chunk,
-            share_prefix=self.share_prefix)
-        t0 = time.perf_counter()
-        while not sched.done():
-            work, meta = sched.plan_round()
-            results = self._engine.run_round(work, timeout=self.timeout)
-            for m, res in zip(meta, results):
-                sched.absorb(m, self._pick_tokens(m, res))
-            self.history.append({"kind": "round", "items": len(work),
-                                 "makespan": self._engine.last_makespan})
+                pool = PagePool(self.cache_spec)
+            sched = AdmissionScheduler(
+                prompts, [r.max_new_tokens for r in reqs],
+                num_groups=self.num_groups, group_size=self.group_size,
+                cache_len=self.cache_len, pool=pool,
+                prefill_chunk=self.prefill_chunk,
+                share_prefix=self.share_prefix)
+            t0 = time.perf_counter()
+            while not sched.done():
+                with TraceAnnotation("serve.plan"):
+                    work, meta = sched.plan_round()
+                results = self._engine.run_round(work, timeout=self.timeout)
+                with TraceAnnotation("serve.absorb"):
+                    for m, res in zip(meta, results):
+                        sched.absorb(m, self._pick_tokens(m, res))
+                self.history.append({"kind": "round", "items": len(work),
+                                     "makespan": self._engine.last_makespan})
 
-        wall = time.perf_counter() - t0
-        total = sum(len(o) for o in sched.outputs)
-        self.last_stats = {
-            "requests": len(reqs), "tokens": total,
-            "rounds": self._engine.rounds, "wall_s": wall,
-            "tok_per_s": total / wall if wall > 0 else float("inf"),
-            "admitted_mid_flight": sched.admitted_mid_flight,
-        }
-        if pool is not None:
-            self.last_stats["peak_pages"] = pool.peak_pages
-            self.last_stats["shared_pages"] = sched.shared_pages
-        self.history.append({"kind": "generate", **self.last_stats})
-        return [np.asarray(o, np.int32) for o in sched.outputs]
+            wall = time.perf_counter() - t0
+            total = sum(len(o) for o in sched.outputs)
+            self.last_stats = {
+                "requests": len(reqs), "tokens": total,
+                "rounds": self._engine.rounds, "wall_s": wall,
+                "tok_per_s": total / wall if wall > 0 else float("inf"),
+                "admitted_mid_flight": sched.admitted_mid_flight,
+            }
+            if pool is not None:
+                self.last_stats["peak_pages"] = pool.peak_pages
+                self.last_stats["shared_pages"] = sched.shared_pages
+            self.history.append({"kind": "generate", **self.last_stats})
+            return [np.asarray(o, np.int32) for o in sched.outputs]
 
     def _pick_tokens(self, m, res):
         """One round result -> the item's token vector (``None`` for a

@@ -1459,6 +1459,10 @@ def lower_serve_stages(cfg, mesh, params: Dict[str, Any], num_stages: int,
                 out = x
             return out, new_caches
 
+        # stage-distinct program names, so a device trace tells stage 0's
+        # programs from stage 1's (jit names its module after the function)
+        local_decode.__name__ = f"local_decode_s{s}"
+
         xin_spec = P(dp)                 # token ids (B,) or hidden (B, 1, d)
         xout_spec = P(dp, mx) if last else P(dp)
         decode = jax.jit(shard_map(
@@ -1487,6 +1491,8 @@ def lower_serve_stages(cfg, mesh, params: Dict[str, Any], num_stages: int,
                 out = x
             return out, caches
 
+        local_prefill.__name__ = f"local_prefill_s{s}"
+
         pre_out_spec = P(None, mx) if last else P()
         prefill = jax.jit(shard_map(
             local_prefill, mesh=mesh,
@@ -1504,6 +1510,8 @@ def lower_serve_stages(cfg, mesh, params: Dict[str, Any], num_stages: int,
             ts = jnp.arange(xin.shape[0], dtype=jnp.int32)
             caches, outs = jax.lax.scan(step, caches, (xin, ts))
             return outs, caches
+
+        local_chunk.__name__ = f"local_chunk_s{s}"
 
         chunk_out_spec = P(None, dp, mx) if last else P(None, dp)
         chunk = jax.jit(shard_map(

@@ -1500,17 +1500,23 @@ class DenseStageCache:
             self.caches[group] = self.stage.init_caches(tok)
 
     def write_prefill(self, work, slot_caches) -> None:
+        from jax.profiler import TraceAnnotation
+
         self._ensure(work.group)
-        self.caches[work.group] = self.stage.write_slot(
-            self.caches[work.group], slot_caches, work.slot)
+        with TraceAnnotation("stage.scatter", kind="prefill"):
+            self.caches[work.group] = self.stage.write_slot(
+                self.caches[work.group], slot_caches, work.slot)
 
     def run_decode(self, work, xin):
         import jax
+        from jax.profiler import TraceAnnotation
 
         self._ensure(work.group)
-        xout, new_caches = self.stage.decode(
-            self.stage.params, self.caches[work.group], xin, work.pos)
-        xout = jax.block_until_ready(xout)
+        with TraceAnnotation("stage.program", kind="decode"):
+            xout, new_caches = self.stage.decode(
+                self.stage.params, self.caches[work.group], xin, work.pos)
+        with TraceAnnotation("stage.sync", kind="decode"):
+            xout = jax.block_until_ready(xout)
         self.caches[work.group] = new_caches
         return xout
 
@@ -1540,6 +1546,7 @@ def serve_stage_apply(stage, cache, work, xin):
     executor and the monolithic serve engine so their math is identical."""
     import jax
     import jax.numpy as jnp
+    from jax.profiler import TraceAnnotation
 
     if isinstance(cache, dict):
         dense = DenseStageCache(stage, 0)
@@ -1548,8 +1555,10 @@ def serve_stage_apply(stage, cache, work, xin):
         cache = dense
     if isinstance(work, PrefillWork):
         li = jnp.full((work.tokens.shape[0],), work.last_index, jnp.int32)
-        xout, slot_caches = stage.prefill(stage.params, xin, li)
-        xout = jax.block_until_ready(xout)
+        with TraceAnnotation("stage.program", kind="prefill"):
+            xout, slot_caches = stage.prefill(stage.params, xin, li)
+        with TraceAnnotation("stage.sync", kind="prefill"):
+            xout = jax.block_until_ready(xout)
         cache.write_prefill(work, slot_caches)
         return xout
     if isinstance(work, PrefillChunkWork):
@@ -1578,11 +1587,6 @@ class _ServeEngineBase:
 
             self.sampler = SamplerStream(sampling, sstaged.cfg.vocab_size)
         self.rounds = 0
-        self.total_makespan = 0.0
-
-    def _count_round(self) -> None:
-        self.rounds += 1
-        self.total_makespan += self.last_makespan
 
 
 def _finish_round_item(sampler, work, logits):
@@ -1611,15 +1615,18 @@ class InlineServeEngine(_ServeEngineBase):
         self.last_makespan: Optional[float] = None
 
     def run_round(self, work: Sequence, timeout: float = 300.0) -> List:
-        t0 = time.perf_counter()
-        results = []
-        for w in work:
-            xin = _work_input(w)
-            for cache in self.stage_caches:
-                xin = serve_stage_apply(cache.stage, cache, w, xin)
-            results.append(_finish_round_item(self.sampler, w, xin))
-        self.last_makespan = time.perf_counter() - t0
-        self._count_round()
+        from jax.profiler import TraceAnnotation
+
+        with TraceAnnotation("runtime.round"):
+            t0 = time.perf_counter()
+            results = []
+            for w in work:
+                xin = _work_input(w)
+                for cache in self.stage_caches:
+                    xin = serve_stage_apply(cache.stage, cache, w, xin)
+                results.append(_finish_round_item(self.sampler, w, xin))
+            self.last_makespan = time.perf_counter() - t0
+        self.rounds += 1
         return results
 
 
@@ -1723,7 +1730,7 @@ class ServePipelineExecutor(_StagedExecutorBase):
 
     Instrumentation mirrors the other executors (``last_makespan``,
     ``last_history``, ``last_peak_regs``, ``last_edge_bytes``) plus
-    ``rounds`` and ``total_makespan`` accumulated over the session.
+    ``rounds`` counted over the session.
     """
 
     def __init__(self, sstaged, regs: Optional[Sequence[int]] = None,
@@ -1736,7 +1743,6 @@ class ServePipelineExecutor(_StagedExecutorBase):
         self.cache_spec = cache_spec
         self.sampling = sampling
         self.rounds = 0
-        self.total_makespan = 0.0
 
     def _make_builder(self):
         return ServeSpecBuilder(regs=self.regs, fn_wrap=self.fn_wrap,
@@ -1749,6 +1755,8 @@ class ServePipelineExecutor(_StagedExecutorBase):
         through the stage actors; returns one entry per item in submission
         order — the last stage's logits, or ``{"logits", "tokens"}`` dicts
         when sampling is on."""
+        from jax.profiler import TraceAnnotation
+
         if not work:
             return []
         work = list(work)
@@ -1756,11 +1764,11 @@ class ServePipelineExecutor(_StagedExecutorBase):
         S = self.sstaged.num_stages
         fires = {"admit": n}
         fires.update({f"stage{s}": n for s in range(S)})
-        outs = self._run_rt({"admit": work}, fires, timeout)
+        with TraceAnnotation("runtime.round"):
+            outs = self._run_rt({"admit": work}, fires, timeout)
         if len(outs) != n:
             raise RuntimeError(f"collected {len(outs)} round results, "
                                f"expected {n}")
         self.rounds += 1
-        self.total_makespan += self.last_makespan
         # the final stage fires in FIFO submission order in one worker
         return [o["result"] for o in outs]

@@ -27,6 +27,12 @@ unsent ack means the producer's register is still refcounted, which keeps
 the producer's ``live`` non-zero. When every key is local, quiescence is
 exactly completion; across processes it feeds the termination protocol in
 :mod:`repro.runtime.process`.
+
+Each epoch's start and end (``runtime.epoch_start``, ``runtime.epoch_end``)
+and every fire (``actor.fire``, with ``actor``, ``epoch`` and ``fire``, the
+actor's version before it) are ``jax.profiler.TraceAnnotation`` spans:
+free while no trace records, and on the device trace's clock while one
+does.
 """
 from __future__ import annotations
 
@@ -111,40 +117,43 @@ class _LocalEngine:
         ``fires`` overrides per-actor fire bounds for this epoch only;
         ``ctx`` is routed to each actor's ``on_epoch`` hook (hooks with no
         entry still run with ``None`` so per-epoch state resets happen)."""
+        from jax.profiler import TraceAnnotation
+
         ctx = ctx or {}
         fires = fires or {}
         self._epoch += 1
-        if self.trace_recorder is not None:
-            # resequencer state resets per epoch; the trace sanitizer
-            # checks canonical order per (epoch, consumer, channel)
-            self.trace_recorder.current_epoch = self._epoch
-        self._stopping = False
-        for a in self.local_actors:
-            a.reset(max_fires=fires.get(a.spec.name))
-        # hooks run after every reset: an on_epoch that seeds an upstream
-        # cell must not race a half-reset consumer
-        for a in self.local_actors:
-            if a.spec.on_epoch is not None:
-                a.spec.on_epoch(ctx.get(a.spec.name))
-        pending = sum(a.max_fires - a.fired for a in self.local_actors
-                      if a.max_fires is not None)
-        # fresh mailboxes per epoch: anything a previous (timed-out) epoch
-        # left queued is unreachable garbage, not a poisoned message
-        self._mailboxes = {k: queue.Queue() for k in self.local_keys}
-        self._t0 = time.perf_counter()
-        with self._lock:
-            self._pending = pending
-            self._live = 0
-            self._quiescent = (pending == 0)
-            if self.on_quiescence is not None:
-                self.on_quiescence(self._quiescent)
-        self._threads = []
-        epoch = self._epoch
-        for key in self.local_keys:
-            t = threading.Thread(target=self._worker, args=(key, epoch),
-                                 daemon=True)
-            t.start()
-            self._threads.append(t)
+        with TraceAnnotation("runtime.epoch_start"):
+            if self.trace_recorder is not None:
+                # resequencer state resets per epoch; the trace sanitizer
+                # checks canonical order per (epoch, consumer, channel)
+                self.trace_recorder.current_epoch = self._epoch
+            self._stopping = False
+            for a in self.local_actors:
+                a.reset(max_fires=fires.get(a.spec.name))
+            # hooks run after every reset: an on_epoch that seeds an upstream
+            # cell must not race a half-reset consumer
+            for a in self.local_actors:
+                if a.spec.on_epoch is not None:
+                    a.spec.on_epoch(ctx.get(a.spec.name))
+            pending = sum(a.max_fires - a.fired for a in self.local_actors
+                          if a.max_fires is not None)
+            # fresh mailboxes per epoch: anything a previous (timed-out) epoch
+            # left queued is unreachable garbage, not a poisoned message
+            self._mailboxes = {k: queue.Queue() for k in self.local_keys}
+            self._t0 = time.perf_counter()
+            with self._lock:
+                self._pending = pending
+                self._live = 0
+                self._quiescent = (pending == 0)
+                if self.on_quiescence is not None:
+                    self.on_quiescence(self._quiescent)
+            self._threads = []
+            epoch = self._epoch
+            for key in self.local_keys:
+                t = threading.Thread(target=self._worker, args=(key, epoch),
+                                     daemon=True)
+                t.start()
+                self._threads.append(t)
 
     def stop_workers(self) -> None:
         self._stopping = True
@@ -273,6 +282,8 @@ class _LocalEngine:
                             stride, accepted)
 
     def _fire_ready(self, key: Tuple[int, int], epoch: int) -> None:
+        from jax.profiler import TraceAnnotation
+
         progressed = True
         while progressed and not self._stopping:
             progressed = False
@@ -284,7 +295,9 @@ class _LocalEngine:
                         # process (a KillWorker fault)
                         self.fault_injector.before_fire(actor.spec.name)
                     start = time.perf_counter() - self._t0
-                    out, acks, reg_id = actor.fire()
+                    with TraceAnnotation("actor.fire", actor=actor.spec.name,
+                                         epoch=epoch, fire=actor.version):
+                        out, acks, reg_id = actor.fire()
                     # wall-clock action history mirrors the simulator's, so
                     # pipeline overlap can be observed on real threads too
                     actor.history.append((start, time.perf_counter() - self._t0))
@@ -384,6 +397,8 @@ class ThreadedRuntime(Runtime):
         for this epoch. Returns the collected outputs: a flat list when a
         single actor name was given, else ``{name: [outputs...]}``.
         """
+        from jax.profiler import TraceAnnotation
+
         _check_epoch_names(self._engine.specs, ctx, fires)
         fires = fires or {}
         effective = {s.name: fires.get(s.name, s.max_fires)
@@ -404,10 +419,11 @@ class ThreadedRuntime(Runtime):
                 break
             self._wake.wait(remaining)
             self._wake.clear()
-        self._engine.stop_workers()
-        self._engine.join_workers(2.0)
-        (self.last_history, self.last_peak_regs,
-         self.last_edge_bytes, self.last_fired) = self._engine.snapshot()
+        with TraceAnnotation("runtime.epoch_end"):
+            self._engine.stop_workers()
+            self._engine.join_workers(2.0)
+            (self.last_history, self.last_peak_regs,
+             self.last_edge_bytes, self.last_fired) = self._engine.snapshot()
         if self._errors:
             exc, key = self._errors[0]
             if hasattr(exc, "add_note"):  # py3.11+

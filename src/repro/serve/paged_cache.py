@@ -285,14 +285,19 @@ class PagedStageCache:
         program, scatter back the one position each live slot wrote (plus
         the full per-request state rows)."""
         import jax
+        from jax.profiler import TraceAnnotation
 
         self._ensure()
-        window = self._fns["gather"](self.slabs, work.rows, work.sids)
-        xout, new_window = self.stage.decode(self.stage.params, window,
-                                             xin, work.pos)
-        xout = jax.block_until_ready(xout)
-        self.slabs = self._fns["scatter_decode"](
-            self.slabs, work.rows, work.sids, work.pos, new_window)
+        with TraceAnnotation("stage.gather", kind="decode"):
+            window = self._fns["gather"](self.slabs, work.rows, work.sids)
+        with TraceAnnotation("stage.program", kind="decode"):
+            xout, new_window = self.stage.decode(self.stage.params, window,
+                                                 xin, work.pos)
+        with TraceAnnotation("stage.sync", kind="decode"):
+            xout = jax.block_until_ready(xout)
+        with TraceAnnotation("stage.scatter", kind="decode"):
+            self.slabs = self._fns["scatter_decode"](
+                self.slabs, work.rows, work.sids, work.pos, new_window)
         return xout
 
     def write_prefill(self, work, slot_caches) -> None:
@@ -300,11 +305,13 @@ class PagedStageCache:
         ``work.row`` is the *write* row — shared-prefix entries are ``-1``
         so the prefix owner's pages are read-only."""
         import jax.numpy as jnp
+        from jax.profiler import TraceAnnotation
 
         self._ensure()
-        self.slabs = self._fns["scatter_prefill"](
-            self.slabs, jnp.asarray(work.row), jnp.int32(work.sid),
-            slot_caches)
+        with TraceAnnotation("stage.scatter", kind="prefill"):
+            self.slabs = self._fns["scatter_prefill"](
+                self.slabs, jnp.asarray(work.row), jnp.int32(work.sid),
+                slot_caches)
 
     def run_chunk(self, work, xin):
         """One chunked-prefill step: gather (state rows read via
@@ -312,15 +319,20 @@ class PagedStageCache:
         from exact zeros), run the stage's scan-of-decode chunk program,
         scatter the chunk's positions and the final state row back."""
         import jax
+        from jax.profiler import TraceAnnotation
 
         self._ensure()
-        window = self._fns["gather"](self.slabs, work.rows, work.sids_in)
-        xout, new_window = self.stage.chunk(self.stage.params, window,
-                                            xin, work.pos0, work.adv)
-        xout = jax.block_until_ready(xout)
-        self.slabs = self._fns["scatter_chunk"](
-            int(work.toks.shape[0]), self.slabs, work.rows, work.sids_out,
-            work.pos0, work.adv, new_window)
+        with TraceAnnotation("stage.gather", kind="chunk"):
+            window = self._fns["gather"](self.slabs, work.rows, work.sids_in)
+        with TraceAnnotation("stage.program", kind="chunk"):
+            xout, new_window = self.stage.chunk(self.stage.params, window,
+                                                xin, work.pos0, work.adv)
+        with TraceAnnotation("stage.sync", kind="chunk"):
+            xout = jax.block_until_ready(xout)
+        with TraceAnnotation("stage.scatter", kind="chunk"):
+            self.slabs = self._fns["scatter_chunk"](
+                int(work.toks.shape[0]), self.slabs, work.rows,
+                work.sids_out, work.pos0, work.adv, new_window)
         return xout
 
 
