@@ -245,7 +245,15 @@ class PagedStageCache:
     """One stage's paged serving state: the page slabs + row pools, and the
     jitted gather/scatter programs that bridge them to the unchanged dense
     stage programs. Built lazily (like the dense per-group caches) in
-    whichever worker owns the stage."""
+    whichever worker owns the stage.
+
+    Every scatter takes ``self.slabs`` by donation and updates it in place;
+    ``self.slabs`` is then the scatter's output. Two invariants make that
+    safe: one actor owns each stage's cache, so fires of one stage never
+    overlap; and nothing may keep a slab leaf across a scatter (a kept
+    leaf is deleted). A fire's gather reads the slabs before its scatter
+    is dispatched, on the same device queue, so the donation waits for
+    it."""
 
     def __init__(self, stage, group_size: int, cache_len: int,
                  spec: PagedCacheSpec):
@@ -330,14 +338,20 @@ class PagedStageCache:
         with TraceAnnotation("stage.sync", kind="chunk"):
             xout = jax.block_until_ready(xout)
         with TraceAnnotation("stage.scatter", kind="chunk"):
-            self.slabs = self._fns["scatter_chunk"](
-                int(work.toks.shape[0]), self.slabs, work.rows,
-                work.sids_out, work.pos0, work.adv, new_window)
+            self.slabs = self._fns["scatter_chunk"](int(work.toks.shape[0]))(
+                self.slabs, work.rows, work.sids_out, work.pos0, work.adv,
+                new_window)
         return xout
 
 
 def _build_paged_ops(spec: PagedCacheSpec, group_size: int, cache_len: int):
     """Jit the fixed-shape gather/scatter programs for one stage.
+
+    The scatters donate their ``slabs`` argument, so they write their few
+    positions into the slabs in place instead of copying each whole slab
+    first. The caller owns the slabs alone (one actor per stage, no
+    overlapping fires), replaces them with each scatter's output, and keeps
+    no slab leaf across a scatter.
 
     Physical index math: cache position ``pos`` of the slot with table row
     ``row`` lives at flat slab index ``row[pos // page_len] * page_len +
@@ -457,18 +471,18 @@ def _build_paged_ops(spec: PagedCacheSpec, group_size: int, cache_len: int):
                 return slab.at[sid_idx].set(win.astype(slab.dtype),
                                             mode="drop")
             return map2_cache_tree(slabs, window, sc)
-        return jax.jit(scatter_chunk_T)
+        return jax.jit(scatter_chunk_T, donate_argnums=(0,))
 
     chunk_fns: Dict[int, Any] = {}
 
-    def scatter_chunk_dispatch(T, slabs, rows, sids, pos0, adv, window):
+    def scatter_chunk(T):
         # one jit specialization per chunk length (mirrors the per-length
-        # prefill specializations of the dense path)
+        # prefill specializations of the dense path), slabs donated
         if T not in chunk_fns:
             chunk_fns[T] = make_scatter_chunk(T)
-        return chunk_fns[T](slabs, rows, sids, pos0, adv, window)
+        return chunk_fns[T]
 
     return {"gather": jax.jit(gather),
-            "scatter_decode": jax.jit(scatter_decode),
-            "scatter_prefill": jax.jit(scatter_prefill),
-            "scatter_chunk": scatter_chunk_dispatch}
+            "scatter_decode": jax.jit(scatter_decode, donate_argnums=(0,)),
+            "scatter_prefill": jax.jit(scatter_prefill, donate_argnums=(0,)),
+            "scatter_chunk": scatter_chunk}

@@ -9,6 +9,7 @@ agrees with the dense group cache at every position a live request's
 decode can observe.
 """
 import dataclasses
+import re
 
 import numpy as np
 import pytest
@@ -319,3 +320,117 @@ class TestPagedValidation:
         with pytest.raises(ValueError, match="cache_len"):
             PagedStageCache(stage=None, group_size=1, cache_len=24,
                             spec=spec)
+
+
+class TestSlabDonation:
+    """The scatter programs take the page slabs by donation: each updates
+    the slabs in place and the caller's old slab leaves are dead after the
+    call, so no program copies a whole slab to write a few positions."""
+
+    @pytest.fixture(scope="class")
+    def session(self, serve_env):
+        cfg, mesh, params, _ = serve_env
+        return api.compile(cfg, mode="serve", backend="monolithic",
+                           cache="paged", page_len=PAGE_LEN,
+                           num_pages=NUM_PAGES,
+                           **_kw(params, mesh, group_size=2))
+
+    @staticmethod
+    def _call(cache, kind):
+        """``(jitted program, its arguments)`` of one scatter kind, the
+        slabs first, over a live slot 0 and a parked slot 1."""
+        import jax.numpy as jnp
+
+        spec, B = cache.spec, cache.group_size
+        rows = np.full((B, spec.pages_per_req), -1, np.int32)
+        rows[0] = np.arange(spec.pages_per_req)
+        sids = np.array([0] + [-1] * (B - 1), np.int32)
+        window = cache._fns["gather"](cache.slabs, rows, sids)
+        window = jax.tree_util.tree_map(lambda x: x + 1, window)
+        if kind == "decode":
+            pos = np.array([5] + [CACHE_LEN - 1] * (B - 1), np.int32)
+            return (cache._fns["scatter_decode"],
+                    (cache.slabs, rows, sids, pos, window))
+        if kind == "prefill":
+            one = jax.eval_shape(cache.stage.init_caches,
+                                 jnp.zeros((1,), jnp.int32))
+            slot = jax.tree_util.tree_map(
+                lambda s: jnp.ones(s.shape, s.dtype), one)
+            return (cache._fns["scatter_prefill"],
+                    (cache.slabs, rows[0], np.int32(0), slot))
+        pos0 = np.array([2] + [CACHE_LEN - 1] * (B - 1), np.int32)
+        adv = np.array([1] + [0] * (B - 1), np.int32)
+        return (cache._fns["scatter_chunk"](3),
+                (cache.slabs, rows, sids, pos0, adv, window))
+
+    @pytest.mark.parametrize("kind", ["decode", "prefill", "chunk"])
+    def test_scatter_donates_slabs(self, session, kind):
+        from repro.runtime.pipeline import make_stage_cache
+
+        cache = make_stage_cache(session.sstaged.stages[0],
+                                 session.group_size, session.cache_len,
+                                 session.cache_spec)
+        cache._ensure()
+        fn, args = self._call(cache, kind)
+        leaves = jax.tree_util.tree_leaves(args[0])
+        # the module's header line carries input_output_alias: output
+        # index -> (parameter number, ...); the slab leaves come first
+        header = fn.lower(*args).compile().as_text().splitlines()[0]
+        aliased = {int(p) for p in
+                   re.findall(r"\{[\d,]*\}: \((\d+), ", header)}
+        assert aliased >= set(range(len(leaves))), header
+        out = fn(*args)
+        assert all(leaf.is_deleted() for leaf in leaves)
+        assert not any(leaf.is_deleted()
+                       for leaf in jax.tree_util.tree_leaves(out))
+
+
+class TestDonationOrdering:
+    @pytest.mark.parametrize("backend", ["monolithic", "actors"])
+    def test_interleaved_stage_steps_match_dense(self, serve_env, backend):
+        """Every scatter donates the slabs the stage's gather read earlier
+        in the same fire, and the next fire gathers from the scatter's
+        output. Two slot groups on a stage, a whole-prompt prefill scatter
+        between their decodes, then chunked prefills: no fire may touch a
+        donated buffer, and the greedy tokens stay the dense path's."""
+        from repro.serve.paged_cache import PagedStageCache
+
+        cfg, mesh, params, prompts = serve_env
+        rng = np.random.default_rng(3)
+        long = [rng.integers(0, cfg.vocab_size, (12,)).astype(np.int32)
+                for _ in range(2)]
+        reqs = [(prompts[0], 2), (prompts[1], 5), (prompts[2], 3),
+                (prompts[3], 6), (prompts[4], 3), (long[0], 3),
+                (long[1], 4)]
+        kw = _kw(params, mesh, group_size=2, max_prompt_len=12,
+                 cache_len=20)
+        ref = api.compile(cfg, mode="serve", backend="monolithic",
+                          **kw).generate(reqs)
+        steps = []
+
+        def logged(name):
+            orig = getattr(PagedStageCache, name)
+
+            def run(self, work, *a):
+                if self.stage.index == 0:
+                    steps.append((name, work.group))
+                return orig(self, work, *a)
+            return run
+
+        with pytest.MonkeyPatch.context() as mp:
+            for name in ("run_decode", "write_prefill", "run_chunk"):
+                mp.setattr(PagedStageCache, name, logged(name))
+            with api.compile(cfg, mode="serve", backend=backend,
+                             stages=1 if backend == "monolithic" else 2,
+                             cache="paged", page_len=PAGE_LEN,
+                             num_pages=20, prefill_chunk=8, **kw) as sess:
+                outs = sess.generate(reqs)
+        for i, (got, want) in enumerate(zip(outs, ref)):
+            assert np.array_equal(got, want), f"request {i}: {got} != {want}"
+        # a prefill scatter between the decodes of the two groups ...
+        assert any(a[0] == c[0] == "run_decode" and a[1] != c[1]
+                   and b[0] == "write_prefill"
+                   for a, b, c in zip(steps, steps[1:], steps[2:]))
+        # ... and chunked prefills after decodes have run
+        first_decode = steps.index(("run_decode", 0))
+        assert any(s[0] == "run_chunk" for s in steps[first_decode:])

@@ -1,4 +1,5 @@
-"""Compile the four Pallas kernels for a described TPU v5e (no chip needed).
+"""Compile the four Pallas kernels, and the paged cache's scatter programs,
+for a described TPU v5e (no chip needed).
 
 The TPU compiler is installed with jax; ``get_topology_desc`` describes a
 ``v5e:2x2`` host without attaching it, so ``lower(...).compile()`` raises
@@ -12,6 +13,7 @@ every worker imports this file.
 """
 import functools
 import os
+import re
 
 import pytest
 
@@ -84,3 +86,43 @@ def test_ssd_scan_compiles_mamba2_370m(one_chip):
              ((1, 2048, 32, 64), bf), ((1, 2048, 32), f32), ((32,), f32),
              ((1, 2048, 1, 128), bf), ((1, 2048, 1, 128), bf), ((32,), f32),
              sharding=one_chip)
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill", "chunk"])
+def test_paged_scatter_updates_slabs_in_place(one_chip, kind):
+    """At the qwen3-1.7b benchmark cell's sizes (one stage of 14 layers,
+    2 x 4 slots, cache 2448 in pages of 16), each scatter aliases the
+    donated slabs to its output and copies no whole slab."""
+    from repro.serve.paged_cache import PagedCacheSpec, _build_paged_ops
+
+    P, B, L, KV, HD = 14, 4, 2448, 8, 128
+    spec = PagedCacheSpec(page_len=16, num_pages=1224, max_requests=8,
+                          pages_per_req=153)
+    fns = _build_paged_ops(spec, B, L)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    slab_shape = (P, spec.num_pages, spec.page_len, KV, HD)
+    slabs = {"prologue": [], "body": [{"k": sds(slab_shape),
+                                       "v": sds(slab_shape)}]}
+    vec = sds((B,), jnp.int32)
+    rows = sds((B, spec.pages_per_req), jnp.int32)
+    window = {"prologue": [], "body": [{"k": sds((P, B, L, KV, HD)),
+                                        "v": sds((P, B, L, KV, HD))}]}
+    if kind == "decode":
+        lowered = fns["scatter_decode"].lower(slabs, rows, vec, vec, window)
+    elif kind == "prefill":
+        slot = {"prologue": [], "body": [{"k": sds((P, 1, L, KV, HD)),
+                                          "v": sds((P, 1, L, KV, HD))}]}
+        lowered = fns["scatter_prefill"].lower(
+            slabs, sds((spec.pages_per_req,), jnp.int32),
+            sds((), jnp.int32), slot)
+    else:
+        lowered = fns["scatter_chunk"](128).lower(slabs, rows, vec, vec, vec,
+                                                  window)
+    text = lowered.compile().as_text()
+    assert "input_output_alias={ {0}: (0, {}" in text
+    assert "{1}: (1, {}" in text
+    slab_copy = r"bf16\[%s\][^=]*copy\(" % ",".join(map(str, slab_shape))
+    assert not re.search(slab_copy, text)
