@@ -665,6 +665,7 @@ class ServeSession:
         import numpy as np
         from jax.profiler import TraceAnnotation
 
+        from repro.core.lowering import take_moe_counts
         from repro.serve.admission import AdmissionScheduler
 
         reqs = self._normalize(requests)
@@ -723,6 +724,9 @@ class ServeSession:
             if pool is not None:
                 self.last_stats["peak_pages"] = pool.peak_pages
                 self.last_stats["shared_pages"] = sched.shared_pages
+            # the expert layers' counts of this call (models with experts)
+            self.last_stats.update(
+                take_moe_counts(self.sstaged.stages) or {})
             self.history.append({"kind": "generate", **self.last_stats})
             return [np.asarray(o, np.int32) for o in sched.outputs]
 
@@ -937,29 +941,24 @@ def _compile_serve(cfg, *, backend: str, stages: Optional[int], regs,
     elif stages is None:
         stages = min(2, n_units)
 
-    owned = params is None
-    if owned:
+    if params is None:
         params = build_model(cfg, plan_from_mesh(mesh)).init(
             jax.random.PRNGKey(0))
     host_params = None
     if backend != "monolithic" and runtime == "processes":
         # workers re-lower from data: ship host copies of the params
         host_params = jax.device_get(params)
-    # params built here are handed over: the lowering frees each stacked
-    # body leaf once it is sliced into stages
     sstaged = lower_serve_stages(cfg, mesh, params, num_stages=stages,
                                  cache_len=cache_len,
                                  max_prompt_len=max_prompt_len,
-                                 group_size=group_size,
-                                 consume_params=owned)
+                                 group_size=group_size)
     del params
     if isinstance(regs, str):
         regs = _policy_regs(regs, stages, num_groups)
     # shared-prefix pages assume a prompt prefix's cache values are
-    # independent of the suffix — true for causal attention/SSM stacks, not
-    # under MoE capacity routing (expert drop counts see the whole prompt)
-    share_prefix = (cache == "paged"
-                    and getattr(cfg, "num_experts", 0) == 0)
+    # independent of the suffix: true of causal stacks with drop-free
+    # routing
+    share_prefix = cache == "paged"
     if backend == "monolithic":
         if fn_wrap is not None:
             raise ValueError("fn_wrap requires backend='actors' "

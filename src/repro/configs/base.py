@@ -42,14 +42,25 @@ class ModelConfig:
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
 
+    # YaRN rope scaling of MLA's rope dims (deepseek-v2); factor 1 = plain
+    yarn_factor: float = 1.0
+    yarn_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+
     # MoE
-    num_experts: int = 0             # routed experts (0 = dense MLP)
+    num_experts: int = 0             # routed experts: the router's width
+    experts_held: int = 0            # routed experts held here (0 = all)
+    first_expert: int = 0            # index of the first held expert
     num_shared_experts: int = 0
     top_k: int = 0
+    norm_topk_prob: bool = True      # renormalise the top-k gates to sum 1
+    routed_scaling_factor: float = 1.0
     moe_d_ff: int = 0
     first_dense_layers: int = 0      # deepseek: leading dense layers
     moe_every: int = 1               # jamba: MoE layer every k-th layer
-    capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
 
     # SSM (mamba2-style SSD)
@@ -86,6 +97,13 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim is None and self.num_heads:
             self.head_dim = self.d_model // self.num_heads
+        if self.num_experts and not (
+                0 <= self.first_expert
+                and self.first_expert + self.held_experts <= self.num_experts):
+            raise ValueError(
+                f"{self.name}: held experts [{self.first_expert}, "
+                f"{self.first_expert + self.held_experts}) must lie in the "
+                f"router's {self.num_experts}")
 
     def padded_vocab(self, multiple: int = 128) -> int:
         return _pad_to(self.vocab_size, multiple)
@@ -93,6 +111,13 @@ class ModelConfig:
     def padded_heads(self, tp: int) -> int:
         """q heads padded up to a multiple of the tensor-parallel degree."""
         return _pad_to(self.num_heads, tp)
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts whose weights this model holds: experts
+        ``first_expert .. first_expert + held_experts - 1`` of the
+        ``num_experts`` the router scores."""
+        return self.experts_held or self.num_experts
 
     @property
     def is_attention_free(self) -> bool:
@@ -165,9 +190,7 @@ class ModelConfig:
             if mlp == "dense":
                 n += 3 * d * self.d_ff
             elif mlp == "moe":
-                e_all = self.num_experts
-                e_act = self.top_k
-                e = e_act if active_only else e_all
+                e = self.top_k if active_only else self.held_experts
                 n += 3 * d * self.moe_d_ff * e
                 n += 3 * d * self.moe_d_ff * self.num_shared_experts
                 n += d * self.num_experts      # router
@@ -195,11 +218,11 @@ class ModelConfig:
             d_ff=min(self.d_ff, 512) if self.d_ff else 0,
             vocab_size=min(self.vocab_size, 1024),
             num_experts=min(self.num_experts, 4) if self.num_experts else 0,
+            experts_held=0,
+            first_expert=0,
             top_k=min(self.top_k, 2) if self.top_k else 0,
             moe_d_ff=min(self.moe_d_ff, 256) if self.moe_d_ff else 0,
             first_dense_layers=min(self.first_dense_layers, 1),
-            capacity_factor=8.0,   # no token drops: keeps decode == prefill
-
             kv_lora_rank=min(self.kv_lora_rank, 64) if self.kv_lora_rank else 0,
             q_lora_rank=min(self.q_lora_rank, 64) if self.q_lora_rank else 0,
             qk_nope_head_dim=64 if self.qk_nope_head_dim else 0,

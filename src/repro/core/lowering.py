@@ -1230,6 +1230,23 @@ def lower_train_stages(graph: LogicalGraph, plan: Plan,
 # (ServePipelineExecutor).
 # ---------------------------------------------------------------------------
 
+class MoeCounts:
+    """A serve stage's running sum of its programs' expert-layer counts,
+    added on the device (no host sync) until :meth:`take` reads it."""
+
+    def __init__(self):
+        self.total = None
+
+    def add(self, counts) -> None:
+        self.total = counts if self.total is None else self.total + counts
+
+    def take(self):
+        """The sum since the last take (``None`` if nothing was added),
+        and a fresh start."""
+        total, self.total = self.total, None
+        return total
+
+
 @dataclasses.dataclass
 class ServeStage:
     """One lowered decode/prefill pipeline stage.
@@ -1254,6 +1271,12 @@ class ServeStage:
     output's last entry is the decode output at the chunk's final position,
     so the final chunk's logits feed first-token sampling through the same
     head math as ``decode``.
+
+    With ``moe`` (the slice holds an expert layer) each of the three
+    programs also returns, last, the int32 counts
+    :data:`repro.models.mlp.MOE_COUNTERS` of the fire, summed over the
+    slice's layers and the mesh; :func:`split_moe_counts` takes them off
+    into ``moe_counts``.
     """
 
     index: int
@@ -1267,6 +1290,34 @@ class ServeStage:
     last: bool
     mesh: object = None
     chunk: Callable = None
+    moe: bool = False
+    moe_counts: MoeCounts = dataclasses.field(default_factory=MoeCounts)
+
+
+def split_moe_counts(stage: ServeStage, out):
+    """A stage program's outputs without its expert-layer counts, which are
+    added to ``stage.moe_counts`` (programs of stages without an expert
+    layer return none)."""
+    if not stage.moe:
+        return out
+    stage.moe_counts.add(out[-1])
+    return out[:-1]
+
+
+def take_moe_counts(stages) -> Optional[Dict[str, int]]:
+    """The expert-layer counts of ``stages`` since the last take, summed
+    and read to the host once, by :data:`repro.models.mlp.MOE_COUNTERS`
+    name; ``None`` where none were added in this process (stages fired in
+    worker processes count there)."""
+    import numpy as np
+
+    from repro.models.mlp import MOE_COUNTERS
+
+    parts = [st.moe_counts.take() for st in stages]
+    parts = [np.asarray(x, np.int64) for x in parts if x is not None]
+    if not parts:
+        return None
+    return dict(zip(MOE_COUNTERS, (int(v) for v in sum(parts))))
 
 
 class ServeStagedProgram:
@@ -1328,32 +1379,9 @@ def _serve_subtree(tree, lo: int, hi: int, n_pro: int, slice_periods: bool):
     return {"prologue": pro, "body": body}
 
 
-def _slice_body(body, period_bounds, consume: bool):
-    """Per-stage slices ``[plo, phi)`` of the stacked body params, built one
-    leaf at a time. A stage that spans a whole leaf shares it uncopied.
-    With ``consume``, each full leaf is deleted as soon as its slices
-    exist, so the full tree and all the slices are never resident at once
-    (the caller owns ``body`` and drops it)."""
-    leaves, treedef = jax.tree.flatten(body)
-    per_stage: List[List[Any]] = [[] for _ in period_bounds]
-    for a in leaves:
-        shared = False
-        for out, (plo, phi) in zip(per_stage, period_bounds):
-            if phi <= plo:
-                continue
-            whole = (plo, phi) == (0, a.shape[0])
-            shared |= whole
-            out.append(a if whole else a[plo:phi])
-        if consume and not shared:
-            a.delete()
-    return [treedef.unflatten(out) if phi > plo else []
-            for out, (plo, phi) in zip(per_stage, period_bounds)]
-
-
 def lower_serve_stages(cfg, mesh, params: Dict[str, Any], num_stages: int,
                        cache_len: int, max_prompt_len: int, group_size: int,
-                       sliding_window: int = 0,
-                       consume_params: bool = False) -> ServeStagedProgram:
+                       sliding_window: int = 0) -> ServeStagedProgram:
     """Cut the decode step of a :class:`repro.configs.base.ModelConfig`
     model into ``num_stages`` jitted stage programs (stage = contiguous
     slice of the layer stack; tensor parallelism via shard_map *inside*
@@ -1361,10 +1389,10 @@ def lower_serve_stages(cfg, mesh, params: Dict[str, Any], num_stages: int,
 
     ``params`` are the full model params (as built by
     ``repro.models.model_zoo.build_model(cfg, plan).init``); each stage gets
-    its slice, plus the embedding on the first stage and the final norm +
-    unembedding head on the last. ``consume_params=True`` hands the body
-    params to the lowering: each stacked leaf is freed once its stage
-    slices exist, so peak device memory stays near one copy of the model.
+    its prologue blocks, plus the embedding on the first stage and the final
+    norm + unembedding head on the last. The stacked body is placed on the
+    mesh once and shared whole: a stage's programs scan its own range of
+    periods, so the stages together hold one copy of the model.
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -1392,6 +1420,12 @@ def lower_serve_stages(cfg, mesh, params: Dict[str, Any], num_stages: int,
         raise ValueError(f"group_size={group_size} must be divisible by the "
                          f"data-parallel degree {plan.dp}")
 
+    def _mesh_sum(counts, axes):
+        for ax in axes:
+            if plan.axis_size(ax) > 1:
+                counts = jax.lax.psum(counts, ax)
+        return counts
+
     lay = T.stack_layout(cfg)
     n_pro = len(lay.prologue)
     n_units = n_pro + lay.n_periods
@@ -1415,17 +1449,24 @@ def lower_serve_stages(cfg, mesh, params: Dict[str, Any], num_stages: int,
         bounds.append((lo, lo + sz))
         lo += sz
 
-    bodies = _slice_body(params["body"], [
-        (max(lo - n_pro, 0), max(hi - n_pro, 0)) for lo, hi in bounds],
-        consume_params)
+    def place(tree, specs):
+        # on the mesh, once (free where the arrays already sit there), so
+        # no call reshards them
+        return jax.device_put(tree, jax.tree.map(
+            lambda spec: NamedSharding(mesh, spec), specs,
+            is_leaf=lambda x: isinstance(x, P)))
+
+    # every stage that runs a period shares this one placed body
+    body = place(params["body"], pspecs_full["body"])
 
     stages: List[ServeStage] = []
     for s, (lo, hi) in enumerate(bounds):
         first, last = s == 0, s == num_stages - 1
         pro_kinds = lay.prologue[lo:min(hi, n_pro)]
-        sparams = {"prologue": list(params["prologue"][lo:min(hi, n_pro)]),
-                   "body": bodies[s]}
-        bodies[s] = None
+        periods = (max(lo - n_pro, 0), max(hi - n_pro, 0))
+        sparams = {"prologue": list(params["prologue"][lo:min(hi, n_pro)])}
+        moe = any(m == "moe" for _, m in pro_kinds) or (
+            hi > n_pro and any(m == "moe" for _, m in lay.period_slots))
         sspecs = _serve_subtree(pspecs_full, lo, hi, n_pro, False)
         grp_cspecs = _serve_subtree(cspecs_grp, lo, hi, n_pro, False)
         one_cspecs = _serve_subtree(cspecs_one, lo, hi, n_pro, False)
@@ -1436,20 +1477,17 @@ def lower_serve_stages(cfg, mesh, params: Dict[str, Any], num_stages: int,
             for k in ("final_norm", "unembed"):
                 sparams[k] = params[k]
                 sspecs[k] = pspecs_full[k]
-        # place the stage's params on its mesh once (free where they already
-        # sit there), so no call reshards them
-        sparams = jax.device_put(sparams, jax.tree.map(
-            lambda spec: NamedSharding(mesh, spec), sspecs,
-            is_leaf=lambda x: isinstance(x, P)))
+        sparams = place(sparams, {k: sspecs[k] for k in sparams})
+        sparams["body"] = body if periods[1] > periods[0] else []
 
         def local_decode(p, caches, xin, pos, _first=first, _last=last,
-                         _kinds=pro_kinds):
+                         _kinds=pro_kinds, _periods=periods, _moe=moe):
             if _first:
                 x = T.embed_tokens(p["embed"], xin[:, None], plan).astype(adt)
             else:
                 x = xin
-            x, new_caches = T.decode_stack_slice(
-                p, caches, x, pos, cfg, plan, _kinds,
+            x, new_caches, counts = T.decode_stack_slice(
+                p, caches, x, pos, cfg, plan, _kinds, _periods,
                 sliding_window=sliding_window)
             if _last:
                 x = T.rms_norm(x, p["final_norm"].astype(x.dtype),
@@ -1457,6 +1495,8 @@ def lower_serve_stages(cfg, mesh, params: Dict[str, Any], num_stages: int,
                 out = x[:, 0] @ p["unembed"].astype(x.dtype)
             else:
                 out = x
+            if _moe:      # every device's tokens and held experts
+                return out, new_caches, _mesh_sum(counts, plan.axis_names)
             return out, new_caches
 
         # stage-distinct program names, so a device trace tells stage 0's
@@ -1465,20 +1505,21 @@ def lower_serve_stages(cfg, mesh, params: Dict[str, Any], num_stages: int,
 
         xin_spec = P(dp)                 # token ids (B,) or hidden (B, 1, d)
         xout_spec = P(dp, mx) if last else P(dp)
+        counts_spec = (P(),) if moe else ()
         decode = jax.jit(shard_map(
             local_decode, mesh=mesh,
             in_specs=(sspecs, grp_cspecs, xin_spec, P(dp)),
-            out_specs=(xout_spec, grp_cspecs), check=False))
+            out_specs=(xout_spec, grp_cspecs) + counts_spec, check=False))
 
         def local_prefill(p, xin, last_index, _first=first, _last=last,
-                          _kinds=pro_kinds):
+                          _kinds=pro_kinds, _periods=periods, _moe=moe):
             if _first:
                 x = T.embed_tokens(p["embed"], xin, plan).astype(adt)
             else:
                 x = xin
             positions = jnp.arange(x.shape[1])
-            x, caches = T.prefill_stack_slice(
-                p, x, positions, cfg, plan, _kinds, cache_len,
+            x, caches, counts = T.prefill_stack_slice(
+                p, x, positions, cfg, plan, _kinds, _periods, cache_len,
                 sliding_window=sliding_window)
             if _last:
                 x = T.rms_norm(x, p["final_norm"].astype(x.dtype),
@@ -1489,6 +1530,8 @@ def lower_serve_stages(cfg, mesh, params: Dict[str, Any], num_stages: int,
                 out = h[:, 0] @ p["unembed"].astype(x.dtype)
             else:
                 out = x
+            if _moe:      # the prompt is replicated over the data axes
+                return out, caches, _mesh_sum(counts, (mx,))
             return out, caches
 
         local_prefill.__name__ = f"local_prefill_s{s}"
@@ -1497,19 +1540,23 @@ def lower_serve_stages(cfg, mesh, params: Dict[str, Any], num_stages: int,
         prefill = jax.jit(shard_map(
             local_prefill, mesh=mesh,
             in_specs=(sspecs, P(), P()),
-            out_specs=(pre_out_spec, one_cspecs), check=False))
+            out_specs=(pre_out_spec, one_cspecs) + counts_spec,
+            check=False))
 
-        def local_chunk(p, caches, xin, pos0, adv, _ld=local_decode):
+        def local_chunk(p, caches, xin, pos0, adv, _ld=local_decode,
+                        _moe=moe):
             # chunked prefill: scan the decode step over the chunk axis —
             # slot b visits pos0[b] + t * adv[b] (parked slots: adv == 0)
             def step(caches, inp):
                 xt, t = inp
-                out, caches = _ld(p, caches, xt, pos0 + t * adv)
-                return caches, out
+                out = _ld(p, caches, xt, pos0 + t * adv)
+                return out[1], (out[0],) + out[2:]
 
             ts = jnp.arange(xin.shape[0], dtype=jnp.int32)
             caches, outs = jax.lax.scan(step, caches, (xin, ts))
-            return outs, caches
+            if _moe:
+                return outs[0], caches, outs[1].sum(0)
+            return outs[0], caches
 
         local_chunk.__name__ = f"local_chunk_s{s}"
 
@@ -1517,7 +1564,8 @@ def lower_serve_stages(cfg, mesh, params: Dict[str, Any], num_stages: int,
         chunk = jax.jit(shard_map(
             local_chunk, mesh=mesh,
             in_specs=(sspecs, grp_cspecs, P(None, dp), P(dp), P(dp)),
-            out_specs=(chunk_out_spec, grp_cspecs), check=False))
+            out_specs=(chunk_out_spec, grp_cspecs) + counts_spec,
+            check=False))
 
         def local_init(tok, _lo=lo, _hi=hi):
             full = make_decode_caches(cfg, plan, tok.shape[0], cache_len)
@@ -1542,6 +1590,6 @@ def lower_serve_stages(cfg, mesh, params: Dict[str, Any], num_stages: int,
             index=s, decode=decode, prefill=prefill,
             init_caches=init_caches, write_slot=write_slot,
             params=sparams, units=(lo, hi), first=first, last=last,
-            mesh=mesh, chunk=chunk))
+            mesh=mesh, chunk=chunk, moe=moe))
     return ServeStagedProgram(cfg, plan, mesh, stages, cache_len,
                               max_prompt_len, group_size)

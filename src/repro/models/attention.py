@@ -26,7 +26,7 @@ from repro.kernels.flash_attention.ref import (flash_attention_ref,
 from repro.kernels.flash_decode.ref import (combine_partials,
                                             flash_decode_partial_ref)
 from repro.models.common import (MeshPlan, apply_rope, dense_init, rms_norm,
-                                 split_keys)
+                                 split_keys, yarn_inv_freq, yarn_mscale)
 
 
 # ---------------------------------------------------------------------------
@@ -327,6 +327,29 @@ def mla_specs(cfg: ModelConfig, plan: MeshPlan) -> Dict:
     return p
 
 
+def _mla_rope(x, positions, cfg: ModelConfig):
+    """RoPE over MLA's rope dims; DeepSeek-V2's YaRN where
+    ``yarn_factor > 1`` (frequencies blended over the correction range,
+    cos and sin scaled by ``mscale(f, yarn_mscale) / mscale(f,
+    yarn_mscale_all_dim)``)."""
+    f = cfg.yarn_factor
+    if f <= 1:
+        return apply_rope(x, positions, 1.0, cfg.rope_theta)
+    inv = yarn_inv_freq(cfg.qk_rope_head_dim, cfg.rope_theta, f,
+                        cfg.yarn_original_max_pos, cfg.yarn_beta_fast,
+                        cfg.yarn_beta_slow)
+    scale = (yarn_mscale(f, cfg.yarn_mscale)
+             / yarn_mscale(f, cfg.yarn_mscale_all_dim))
+    return apply_rope(x, positions, inv_freq=inv, scale=scale)
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """``(qk_nope + qk_rope)^-0.5``, times ``mscale(f,
+    yarn_mscale_all_dim)^2`` under YaRN (1 without it)."""
+    return ((cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+            * yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2)
+
+
 def _mla_q(p, x, cfg, plan, positions):
     B, S = x.shape[:2]
     H_l = cfg.num_heads // plan.tp
@@ -339,16 +362,15 @@ def _mla_q(p, x, cfg, plan, positions):
         q = x @ p["wq"].astype(x.dtype)
     q = q.reshape(B, S, H_l, nope + rope_d)
     q_nope, q_pe = q[..., :nope], q[..., nope:]
-    q_pe = apply_rope(q_pe, positions, 1.0, cfg.rope_theta)
-    return q_nope, q_pe
+    return q_nope, _mla_rope(q_pe, positions, cfg)
 
 
 def _mla_latent(p, x, cfg, positions):
     ckv = x @ p["wkv_a"].astype(x.dtype)                 # (B, S, r + rope)
     c = rms_norm(ckv[..., :cfg.kv_lora_rank],
                  p["kv_norm"].astype(x.dtype), cfg.norm_eps)
-    k_pe = apply_rope(ckv[..., None, cfg.kv_lora_rank:], positions,
-                      1.0, cfg.rope_theta)[..., 0, :]    # (B, S, rope)
+    k_pe = _mla_rope(ckv[..., None, cfg.kv_lora_rank:], positions,
+                     cfg)[..., 0, :]                     # (B, S, rope)
     return c, k_pe
 
 
@@ -367,7 +389,10 @@ def mla_forward(p, x, cfg: ModelConfig, plan: MeshPlan, positions,
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_pe[:, :, None], (B, S, H_l, rope_d))],
         axis=-1)
-    out = flash_attention_triangular(q, k, v, sliding_window=sliding_window)
+    with jax.named_scope("mla.attend"):
+        out = flash_attention_triangular(q, k, v,
+                                         sliding_window=sliding_window,
+                                         sm_scale=mla_softmax_scale(cfg))
     y_partial = out.reshape(B, S, H_l * vd) @ p["wo"].astype(x.dtype)
     return y_partial, (c, k_pe)
 
@@ -384,7 +409,7 @@ def mla_decode(p, x, cache_c, cache_kpe, pos, cfg: ModelConfig, plan: MeshPlan,
     L = cache_c.shape[1]
     H_l = cfg.num_heads // plan.tp
     r = cfg.kv_lora_rank
-    nope, rope_d, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
 
     q_nope, q_pe = _mla_q(p, x, cfg, plan, pos[:, None])
     c_new, kpe_new = _mla_latent(p, x, cfg, pos[:, None])
@@ -394,21 +419,22 @@ def mla_decode(p, x, cache_c, cache_kpe, pos, cfg: ModelConfig, plan: MeshPlan,
     cache_c = upd(cache_c, pos, c_new.astype(cache_c.dtype))
     cache_kpe = upd(cache_kpe, pos, kpe_new.astype(cache_kpe.dtype))
 
-    # absorbed scores: q' = q_nope @ W_uk  (per local head)
-    w_uk = p["w_uk"].astype(x.dtype).reshape(r, H_l, nope)
-    q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_uk)   # (B, H_l, r)
-    s_lat = jnp.einsum("bhr,blr->bhl", q_lat, cache_c.astype(x.dtype))
-    s_pe = jnp.einsum("bhe,ble->bhl", q_pe[:, 0], cache_kpe.astype(x.dtype))
-    scale = 1.0 / ((nope + rope_d) ** 0.5)
-    s = (s_lat + s_pe).astype(jnp.float32) * scale
-    kpos = jnp.arange(L)
-    mask = kpos[None, :] <= pos[:, None]
-    if sliding_window:
-        mask &= kpos[None, :] > (pos[:, None] - sliding_window)
-    s = jnp.where(mask[:, None, :], s, -1e30)
-    pr = jax.nn.softmax(s, axis=-1).astype(x.dtype)
-    out_lat = jnp.einsum("bhl,blr->bhr", pr, cache_c.astype(x.dtype))
-    w_uv = p["w_uv"].astype(x.dtype).reshape(r, H_l, vd)
-    out = jnp.einsum("bhr,rhv->bhv", out_lat, w_uv)          # (B, H_l, vd)
+    with jax.named_scope("mla.attend"):
+        # absorbed scores: q' = q_nope @ W_uk  (per local head)
+        w_uk = p["w_uk"].astype(x.dtype).reshape(r, H_l, nope)
+        q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_uk)  # (B, H_l, r)
+        s_lat = jnp.einsum("bhr,blr->bhl", q_lat, cache_c.astype(x.dtype))
+        s_pe = jnp.einsum("bhe,ble->bhl", q_pe[:, 0],
+                          cache_kpe.astype(x.dtype))
+        s = (s_lat + s_pe).astype(jnp.float32) * mla_softmax_scale(cfg)
+        kpos = jnp.arange(L)
+        mask = kpos[None, :] <= pos[:, None]
+        if sliding_window:
+            mask &= kpos[None, :] > (pos[:, None] - sliding_window)
+        s = jnp.where(mask[:, None, :], s, -1e30)
+        pr = jax.nn.softmax(s, axis=-1).astype(x.dtype)
+        out_lat = jnp.einsum("bhl,blr->bhr", pr, cache_c.astype(x.dtype))
+        w_uv = p["w_uv"].astype(x.dtype).reshape(r, H_l, vd)
+        out = jnp.einsum("bhr,rhv->bhv", out_lat, w_uv)      # (B, H_l, vd)
     y_partial = out.reshape(B, 1, H_l * vd) @ p["wo"].astype(x.dtype)
     return y_partial, cache_c, cache_kpe

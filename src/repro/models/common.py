@@ -165,15 +165,47 @@ def rope_freqs(head_dim: int, rope_fraction: float, theta: float):
     return rot, inv
 
 
-def apply_rope(x, positions, rope_fraction: float = 1.0, theta: float = 1e4):
-    """x: (..., seq, heads, head_dim); positions: (..., seq)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention magnitude factor (DeepSeek-V2's ``yarn_get_mscale``)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float,
+                  original_max_pos: int, beta_fast: float, beta_slow: float):
+    """YaRN rope frequencies over ``dim`` channels (DeepSeek-V2): the
+    plain ``1 / theta**(2i/dim)`` below the correction range (dimensions
+    that turn more than ``beta_fast`` times over the original context), the
+    interpolated ``1 / (factor * theta**(2i/dim))`` above it (fewer than
+    ``beta_slow`` turns), and a linear blend between."""
+    def turns_dim(turns):
+        return (dim * math.log(original_max_pos / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turns_dim(beta_fast)), 0)
+    high = min(math.ceil(turns_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    extra = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
+def apply_rope(x, positions, rope_fraction: float = 1.0, theta: float = 1e4,
+               inv_freq=None, scale: float = 1.0):
+    """x: (..., seq, heads, head_dim); positions: (..., seq). ``inv_freq``
+    (head_dim/2 frequencies, e.g. :func:`yarn_inv_freq`) replaces the plain
+    ones over the whole head; ``scale`` multiplies cos and sin."""
     hd = x.shape[-1]
-    rot, inv = rope_freqs(hd, rope_fraction, theta)
+    if inv_freq is None:
+        rot, inv = rope_freqs(hd, rope_fraction, theta)
+    else:
+        rot, inv = hd, inv_freq
     if rot == 0:
         return x
     ang = positions[..., :, None].astype(jnp.float32) * inv  # (..., seq, rot/2)
-    cos = jnp.cos(ang)[..., None, :]
-    sin = jnp.sin(ang)[..., None, :]
+    cos = jnp.cos(ang)[..., None, :] * scale
+    sin = jnp.sin(ang)[..., None, :] * scale
     x_rot, x_pass = x[..., :rot], x[..., rot:]
     x1, x2 = x_rot[..., 0::2], x_rot[..., 1::2]
     o1 = x1 * cos - x2 * sin

@@ -34,8 +34,9 @@ from repro.models.common import (MeshPlan, certified_pmean, dense_init,
                                  force_vary, rms_norm, split_keys)
 from repro.models.mamba import (
     init_mamba, mamba_decode, mamba_forward, mamba_specs)
-from repro.models.mlp import (dense_mlp_forward, dense_mlp_specs, init_dense_mlp,
-                              init_moe, moe_forward, moe_specs)
+from repro.models.mlp import (MOE_COUNTERS, dense_mlp_forward,
+                              dense_mlp_specs, init_dense_mlp, init_moe,
+                              moe_forward, moe_specs)
 
 
 # ---------------------------------------------------------------------------
@@ -124,7 +125,9 @@ def apply_block(p, x, cfg: ModelConfig, plan: MeshPlan, kind: str,
                 mlp_kind: str, positions, causal: bool = True,
                 sliding_window: int = 0, enc: Optional[jnp.ndarray] = None,
                 want_cache: bool = False, cache_len: int = 0):
-    """Returns (x, aux_loss, cache_or_None). x replicated over model axis.
+    """Returns (x, aux_loss, cache_or_None, moe_counts). x replicated over
+    model axis; ``moe_counts`` are the expert layer's int32
+    :data:`MOE_COUNTERS` (zeros without one).
 
     Branch psum outputs are tagged with ``checkpoint_name('boxed')`` so the
     remat policy can SAVE them: replaying a branch's compute in the backward
@@ -138,6 +141,7 @@ def apply_block(p, x, cfg: ModelConfig, plan: MeshPlan, kind: str,
         def psum(v):
             return v
     aux = jnp.zeros((), jnp.float32)
+    counts = no_moe_counts()
     cache = None
     h = rms_norm(x, p["ln1"].astype(x.dtype), cfg.norm_eps)
     if kind == "attn":
@@ -181,18 +185,19 @@ def apply_block(p, x, cfg: ModelConfig, plan: MeshPlan, kind: str,
         x = x + psum(dense_mlp_forward(p["mlp"], h2))
     elif mlp_kind == "moe":
         h2 = rms_norm(x, p["ln2"].astype(x.dtype), cfg.norm_eps)
-        mo, a_aux = moe_forward(p["moe"], h2, cfg, plan)
+        mo, a_aux, counts = moe_forward(p["moe"], h2, cfg, plan)
         x = x + psum(mo)
         aux = aux + a_aux
-    return x, aux, cache
+    return x, aux, cache, counts
 
 
 def decode_block(p, x, cache, pos, cfg: ModelConfig, plan: MeshPlan,
                  kind: str, mlp_kind: str, sliding_window: int = 0):
-    """Single-token step. Returns (x, new_cache)."""
+    """Single-token step. Returns (x, new_cache, moe_counts)."""
     psum = (lambda v: jax.lax.psum(v, plan.model_axis)) if plan.tp > 1 \
         else (lambda v: v)
     h = rms_norm(x, p["ln1"].astype(x.dtype), cfg.norm_eps)
+    counts = no_moe_counts()
     new_cache = dict(cache)
     if kind == "attn":
         if cfg.use_mla:
@@ -223,9 +228,14 @@ def decode_block(p, x, cache, pos, cfg: ModelConfig, plan: MeshPlan,
         x = x + psum(dense_mlp_forward(p["mlp"], h2))
     elif mlp_kind == "moe":
         h2 = rms_norm(x, p["ln2"].astype(x.dtype), cfg.norm_eps)
-        mo, _ = moe_forward(p["moe"], h2, cfg, plan)
+        mo, _, counts = moe_forward(p["moe"], h2, cfg, plan)
         x = x + psum(mo)
-    return x, new_cache
+    return x, new_cache, counts
+
+
+def no_moe_counts():
+    """The expert-layer counts of a block without one."""
+    return jnp.zeros((len(MOE_COUNTERS),), jnp.int32)
 
 
 def _cross_attn_decode(p, x, xk, xv, cfg, plan):
@@ -366,9 +376,9 @@ def _run_body(params, x, cfg, plan, positions, causal=True, sliding_window=0,
                            plan.axis_names)
     pro_caches = []
     for p_blk, (kind, mlp_kind) in zip(params["prologue"], lay.prologue):
-        x, aux, cache = apply_block(p_blk, x, cfg, plan, kind, mlp_kind,
-                                    positions, causal, sliding_window, enc,
-                                    want_cache, cache_len)
+        x, aux, cache, _ = apply_block(p_blk, x, cfg, plan, kind, mlp_kind,
+                                       positions, causal, sliding_window,
+                                       enc, want_cache, cache_len)
         aux_total += aux
         pro_caches.append(cache)
 
@@ -376,10 +386,10 @@ def _run_body(params, x, cfg, plan, positions, causal=True, sliding_window=0,
         x, aux = carry
         caches = []
         for j, (kind, mlp_kind) in enumerate(lay.period_slots):
-            x, a, cache = apply_block(stacked[j], x, cfg, plan, kind,
-                                      mlp_kind, positions, causal,
-                                      sliding_window, enc, want_cache,
-                                      cache_len)
+            x, a, cache, _ = apply_block(stacked[j], x, cfg, plan, kind,
+                                         mlp_kind, positions, causal,
+                                         sliding_window, enc, want_cache,
+                                         cache_len)
             aux = aux + a
             caches.append(cache)
         return (force_vary(x, plan.axis_names),
@@ -423,8 +433,8 @@ def forward_loss(params, batch, cfg: ModelConfig, plan: MeshPlan,
 
         def enc_period(carry, p_blk):
             h, _ = carry
-            h, _, _ = apply_block(p_blk, h, cfg, plan, "attn", "dense",
-                                  enc_pos, causal=False)
+            h, _, _, _ = apply_block(p_blk, h, cfg, plan, "attn", "dense",
+                                     enc_pos, causal=False)
             return (h, 0.0), None
         fn = jax.checkpoint(enc_period) if remat else enc_period
         (enc, _), _ = jax.lax.scan(fn, (enc, 0.0), params["enc_body"])
@@ -457,8 +467,8 @@ def forward_loss(params, batch, cfg: ModelConfig, plan: MeshPlan,
             hm = jax.lax.psum(hcat @ w_mtp, plan.model_axis)
         else:
             hm = hcat @ w_mtp
-        hm, _, _ = apply_block(params["mtp_block"], hm, cfg, plan, "attn",
-                               "dense", positions)
+        hm, _, _, _ = apply_block(params["mtp_block"], hm, cfg, plan,
+                                  "attn", "dense", positions)
         mtp_labels = jnp.concatenate(
             [labels[:, 1:], labels[:, -1:]], axis=1)
         mtp_w = jnp.concatenate(
@@ -513,8 +523,8 @@ def prefill(params, batch, cfg: ModelConfig, plan: MeshPlan, cache_len: int,
         enc = enc + _sinusoid(enc.shape[1], cfg.d_model, enc.dtype)
         def enc_step(carry, p_blk):
             h = carry
-            h, _, _ = apply_block(p_blk, h, cfg, plan, "attn", "dense",
-                                  jnp.arange(enc.shape[1]), causal=False)
+            h, _, _, _ = apply_block(p_blk, h, cfg, plan, "attn", "dense",
+                                     jnp.arange(enc.shape[1]), causal=False)
             return h, None
         enc, _ = jax.lax.scan(enc_step, enc, params["enc_body"])
         enc = rms_norm(enc, params["enc_norm"].astype(enc.dtype), cfg.norm_eps)
@@ -546,16 +556,16 @@ def decode_step(params, caches, tok, pos, cfg: ModelConfig, plan: MeshPlan,
     for p_blk, cache, (kind, mlp_kind) in zip(params["prologue"],
                                               caches["prologue"],
                                               lay.prologue):
-        x, c = decode_block(p_blk, x, cache, pos, cfg, plan, kind, mlp_kind,
-                            sliding_window)
+        x, c, _ = decode_block(p_blk, x, cache, pos, cfg, plan, kind,
+                               mlp_kind, sliding_window)
         new_pro.append(c)
 
     def one_period(x, stacked):
         p_stk, c_stk = stacked
         new_caches = []
         for j, (kind, mlp_kind) in enumerate(lay.period_slots):
-            x, c = decode_block(p_stk[j], x, c_stk[j], pos, cfg, plan,
-                                kind, mlp_kind, sliding_window)
+            x, c, _ = decode_block(p_stk[j], x, c_stk[j], pos, cfg, plan,
+                                   kind, mlp_kind, sliding_window)
             new_caches.append(c)
         return x, new_caches
 
@@ -571,64 +581,88 @@ def decode_step(params, caches, tok, pos, cfg: ModelConfig, plan: MeshPlan,
 # chunk of the layer stack per pipeline stage, caches stage-local)
 # ---------------------------------------------------------------------------
 
+def _period_params(body, i):
+    """The per-slot params of period ``i`` (traced) of the stacked body."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), body)
+
+
 def decode_stack_slice(params, caches, x, pos, cfg: ModelConfig,
-                       plan: MeshPlan, pro_kinds, sliding_window: int = 0):
+                       plan: MeshPlan, pro_kinds, periods: Tuple[int, int],
+                       sliding_window: int = 0):
     """One decode step over a slice of the stack.
 
     ``params``/``caches`` hold ``"prologue"`` (a list of this slice's
-    unrolled blocks, kinds given by ``pro_kinds``) and ``"body"`` (per-slot
-    trees stacked over this slice's periods — possibly empty). x: (B, 1, d)
-    hidden entering the slice. Returns (x, new_caches); composing the slices
-    in order reproduces :func:`decode_step`'s layer loop exactly.
+    unrolled blocks, kinds given by ``pro_kinds``) and ``"body"``: per-slot
+    trees stacked over periods — the whole model's for ``params``, of which
+    the slice runs ``periods = (lo, hi)``, and the slice's own for
+    ``caches`` (empty when ``hi == lo``). x: (B, 1, d) hidden entering the
+    slice. Returns (x, new_caches, moe_counts), the counts summed over the
+    slice's layers; composing the slices in order reproduces
+    :func:`decode_step`'s layer loop exactly.
     """
     lay = stack_layout(cfg)
+    counts = no_moe_counts()
     new_pro = []
     for p_blk, cache, (kind, mlp_kind) in zip(params["prologue"],
                                               caches["prologue"], pro_kinds):
-        x, c = decode_block(p_blk, x, cache, pos, cfg, plan, kind, mlp_kind,
-                            sliding_window)
+        x, c, n = decode_block(p_blk, x, cache, pos, cfg, plan, kind,
+                               mlp_kind, sliding_window)
+        counts = counts + n
         new_pro.append(c)
     new_body = caches["body"]
-    if params["body"]:
-        def one_period(x, stacked):
-            p_stk, c_stk = stacked
+    if periods[1] > periods[0]:
+        def one_period(carry, xs):
+            x, counts = carry
+            i, c_stk = xs
+            p_stk = _period_params(params["body"], i)
             new_caches = []
             for j, (kind, mlp_kind) in enumerate(lay.period_slots):
-                x, c = decode_block(p_stk[j], x, c_stk[j], pos, cfg, plan,
-                                    kind, mlp_kind, sliding_window)
+                x, c, n = decode_block(p_stk[j], x, c_stk[j], pos, cfg, plan,
+                                       kind, mlp_kind, sliding_window)
+                counts = counts + n
                 new_caches.append(c)
-            return x, new_caches
-        x, new_body = jax.lax.scan(one_period, x,
-                                   (tuple(params["body"]), caches["body"]))
-    return x, {"prologue": new_pro, "body": new_body}
+            return (x, counts), new_caches
+        (x, counts), new_body = jax.lax.scan(
+            one_period, (x, counts),
+            (jnp.arange(*periods), caches["body"]))
+    return x, {"prologue": new_pro, "body": new_body}, counts
 
 
 def prefill_stack_slice(params, x, positions, cfg: ModelConfig,
-                        plan: MeshPlan, pro_kinds, cache_len: int,
-                        sliding_window: int = 0):
+                        plan: MeshPlan, pro_kinds, periods: Tuple[int, int],
+                        cache_len: int, sliding_window: int = 0):
     """Prefill over a slice of the stack (same structure as
     :func:`decode_stack_slice`). x: (B, S, d) hidden entering the slice.
-    Returns (x, caches) with the slice's decode caches ready at position S.
+    Returns (x, caches, moe_counts) with the slice's decode caches ready at
+    position S.
     """
     lay = stack_layout(cfg)
+    counts = force_vary(no_moe_counts(), plan.axis_names)
     pro_caches = []
     for p_blk, (kind, mlp_kind) in zip(params["prologue"], pro_kinds):
-        x, _, cache = apply_block(p_blk, x, cfg, plan, kind, mlp_kind,
-                                  positions, True, sliding_window, None,
-                                  True, cache_len)
+        x, _, cache, n = apply_block(p_blk, x, cfg, plan, kind, mlp_kind,
+                                     positions, True, sliding_window, None,
+                                     True, cache_len)
+        counts = counts + n
         pro_caches.append(cache)
     body_caches = []
-    if params["body"]:
-        def one_period(x, stacked):
+    if periods[1] > periods[0]:
+        def one_period(carry, i):
+            x, counts = carry
+            stacked = _period_params(params["body"], i)
             caches = []
             for j, (kind, mlp_kind) in enumerate(lay.period_slots):
-                x, _, cache = apply_block(stacked[j], x, cfg, plan, kind,
-                                          mlp_kind, positions, True,
-                                          sliding_window, None, True,
-                                          cache_len)
+                x, _, cache, n = apply_block(stacked[j], x, cfg, plan, kind,
+                                             mlp_kind, positions, True,
+                                             sliding_window, None, True,
+                                             cache_len)
+                counts = counts + n
                 caches.append(cache)
-            return force_vary(x, plan.axis_names), caches
-        x, body_caches = jax.lax.scan(one_period,
-                                      force_vary(x, plan.axis_names),
-                                      tuple(params["body"]))
-    return x, {"prologue": pro_caches, "body": body_caches}
+            return (force_vary(x, plan.axis_names),
+                    force_vary(counts, plan.axis_names)), caches
+        (x, counts), body_caches = jax.lax.scan(
+            one_period, (force_vary(x, plan.axis_names),
+                         force_vary(counts, plan.axis_names)),
+            jnp.arange(*periods))
+    return x, {"prologue": pro_caches, "body": body_caches}, counts

@@ -1511,10 +1511,12 @@ class DenseStageCache:
         import jax
         from jax.profiler import TraceAnnotation
 
+        from repro.core.lowering import split_moe_counts
+
         self._ensure(work.group)
         with TraceAnnotation("stage.program", kind="decode"):
-            xout, new_caches = self.stage.decode(
-                self.stage.params, self.caches[work.group], xin, work.pos)
+            xout, new_caches = split_moe_counts(self.stage, self.stage.decode(
+                self.stage.params, self.caches[work.group], xin, work.pos))
         with TraceAnnotation("stage.sync", kind="decode"):
             xout = jax.block_until_ready(xout)
         self.caches[work.group] = new_caches
@@ -1548,6 +1550,8 @@ def serve_stage_apply(stage, cache, work, xin):
     import jax.numpy as jnp
     from jax.profiler import TraceAnnotation
 
+    from repro.core.lowering import split_moe_counts
+
     if isinstance(cache, dict):
         dense = DenseStageCache(stage, 0)
         dense.caches = cache
@@ -1556,7 +1560,8 @@ def serve_stage_apply(stage, cache, work, xin):
     if isinstance(work, PrefillWork):
         li = jnp.full((work.tokens.shape[0],), work.last_index, jnp.int32)
         with TraceAnnotation("stage.program", kind="prefill"):
-            xout, slot_caches = stage.prefill(stage.params, xin, li)
+            xout, slot_caches = split_moe_counts(
+                stage, stage.prefill(stage.params, xin, li))
         with TraceAnnotation("stage.sync", kind="prefill"):
             xout = jax.block_until_ready(xout)
         cache.write_prefill(work, slot_caches)

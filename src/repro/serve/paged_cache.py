@@ -295,12 +295,15 @@ class PagedStageCache:
         import jax
         from jax.profiler import TraceAnnotation
 
+        from repro.core.lowering import split_moe_counts
+
         self._ensure()
         with TraceAnnotation("stage.gather", kind="decode"):
             window = self._fns["gather"](self.slabs, work.rows, work.sids)
         with TraceAnnotation("stage.program", kind="decode"):
-            xout, new_window = self.stage.decode(self.stage.params, window,
-                                                 xin, work.pos)
+            xout, new_window = split_moe_counts(
+                self.stage, self.stage.decode(self.stage.params, window,
+                                              xin, work.pos))
         with TraceAnnotation("stage.sync", kind="decode"):
             xout = jax.block_until_ready(xout)
         with TraceAnnotation("stage.scatter", kind="decode"):
@@ -329,12 +332,15 @@ class PagedStageCache:
         import jax
         from jax.profiler import TraceAnnotation
 
+        from repro.core.lowering import split_moe_counts
+
         self._ensure()
         with TraceAnnotation("stage.gather", kind="chunk"):
             window = self._fns["gather"](self.slabs, work.rows, work.sids_in)
         with TraceAnnotation("stage.program", kind="chunk"):
-            xout, new_window = self.stage.chunk(self.stage.params, window,
-                                                xin, work.pos0, work.adv)
+            xout, new_window = split_moe_counts(
+                self.stage, self.stage.chunk(self.stage.params, window, xin,
+                                             work.pos0, work.adv))
         with TraceAnnotation("stage.sync", kind="chunk"):
             xout = jax.block_until_ready(xout)
         with TraceAnnotation("stage.scatter", kind="chunk"):
@@ -357,7 +363,9 @@ def _build_paged_ops(spec: PagedCacheSpec, group_size: int, cache_len: int):
     ``row`` lives at flat slab index ``row[pos // page_len] * page_len +
     pos % page_len``. Unmapped pages (entry ``-1``) and parked slots
     (``sid < 0``) are redirected to the out-of-bounds sentinel
-    ``num_pages * page_len`` — gathers fill 0 there, scatters drop."""
+    ``num_pages * page_len`` — gathers fill 0 there, scatters drop; the
+    decode scatter, which writes each slot in place, writes back instead
+    the value it reads there."""
     import jax
     import jax.numpy as jnp
 
@@ -394,21 +402,30 @@ def _build_paged_ops(spec: PagedCacheSpec, group_size: int, cache_len: int):
         b = jnp.arange(B)
         page = rows[b, pos // pl]                        # (B,)
         ok = (page >= 0) & (sids >= 0)
-        phys = jnp.where(ok, page * pl + pos % pl, total)
+        page = jnp.where(ok, page, 0)
+        off = pos % pl
         sid_idx = jnp.where(sids >= 0, sids, mr)         # OOB -> drop
+        zero = jnp.zeros((), jnp.int32)
 
         def sc(k, slab, win, stacked):
             if k in POSITIONAL_KEYS:
-                flat = _flat(slab, stacked)
-                if stacked:
-                    val = win[:, b, pos]                 # (P, B, *f)
-                    flat = flat.at[:, phys].set(val.astype(slab.dtype),
-                                                mode="drop")
-                else:
-                    val = win[b, pos]                    # (B, *f)
-                    flat = flat.at[phys].set(val.astype(slab.dtype),
-                                             mode="drop")
-                return flat.reshape(slab.shape)
+                # each slot's position written in place on the slab itself:
+                # a scatter into a flattened view lets the compiler relayout
+                # (copy) a slab whose feature width is no multiple of 128;
+                # parked and unmapped slots write back what is there
+                lead = (zero,) if stacked else ()
+                feat = (zero,) * (slab.ndim - len(lead) - 2)
+                size = slab.shape[:len(lead)] + (1, 1) + slab.shape[
+                    len(lead) + 2:]
+                for i in range(B):
+                    start = lead + (page[i], off[i]) + feat
+                    cur = jax.lax.dynamic_slice(slab, start, size)
+                    new = jax.lax.dynamic_slice(
+                        win, lead + (jnp.int32(i), pos[i]) + feat, size)
+                    slab = jax.lax.dynamic_update_slice(
+                        slab, jnp.where(ok[i], new.astype(slab.dtype), cur),
+                        start)
+                return slab
             if stacked:
                 return slab.at[:, sid_idx].set(win.astype(slab.dtype),
                                                mode="drop")

@@ -1,5 +1,6 @@
-"""Compile the four Pallas kernels, and the paged cache's scatter programs,
-for a described TPU v5e (no chip needed).
+"""Compile the four Pallas kernels, the paged cache's scatter programs,
+serve stage programs and the grouped expert form for a described TPU v5e
+(no chip needed).
 
 The TPU compiler is installed with jax; ``get_topology_desc`` describes a
 ``v5e:2x2`` host without attaching it, so ``lower(...).compile()`` raises
@@ -126,3 +127,143 @@ def test_paged_scatter_updates_slabs_in_place(one_chip, kind):
     assert "{1}: (1, {}" in text
     slab_copy = r"bf16\[%s\][^=]*copy\(" % ",".join(map(str, slab_shape))
     assert not re.search(slab_copy, text)
+
+
+def test_deepseek_stage_decode_compiles_at_published_widths(one_chip,
+                                                            monkeypatch):
+    """DeepSeek-V2-Lite at published widths with 16 of its 64 routed
+    experts held (the benchmark cell's cut: 2 stages, 2 x 8 slots, cache
+    1936): both stage decode programs compile for a v5e chip, and the
+    paged decode scatter aliases the donated latent slabs (``c``, ``kpe``)
+    and copies none."""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.configs.registry import get_config
+    from repro.core.lowering import lower_serve_stages
+    from repro.models.model_zoo import build_model
+    from repro.models.common import MeshPlan
+    from repro.serve.paged_cache import PagedCacheSpec, _build_paged_ops
+
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite-16b"),
+                              experts_held=16)
+    B, L = 8, 1936
+    mesh = Mesh(np.array(list(one_chip.device_set)).reshape(1, 1),
+                ("data", "model"))
+    rep = NamedSharding(mesh, P())
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16),
+        jax.eval_shape(build_model(cfg, MeshPlan.single_device()).init,
+                       jax.random.PRNGKey(0)))
+    # a described device holds no arrays: placing the params gives shapes
+    monkeypatch.setattr(jax, "device_put", lambda x, s: jax.tree.map(
+        lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        x, s))
+    sstaged = lower_serve_stages(cfg, mesh, params, num_stages=2,
+                                 cache_len=L, max_prompt_len=1536,
+                                 group_size=B)
+    spec = PagedCacheSpec(page_len=16, num_pages=2 * B * L // 16,
+                          max_requests=2 * B, pages_per_req=L // 16)
+    fns = _build_paged_ops(spec, B, L)
+    tok = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=rep)
+    for st in sstaged.stages:
+        assert st.moe
+        window = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+            jax.eval_shape(st.init_caches, tok))
+        xin = tok if st.first else jax.ShapeDtypeStruct(
+            (B, 1, cfg.d_model), jnp.bfloat16, sharding=rep)
+        st.decode.lower(st.params, window, xin, tok).compile()
+        slabs = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(
+                (a.shape[0], spec.num_pages, 16) + a.shape[3:]
+                if a.ndim == 4 else (spec.num_pages, 16) + a.shape[2:],
+                a.dtype, sharding=rep), window)
+        rows = jax.ShapeDtypeStruct((B, spec.pages_per_req), jnp.int32,
+                                    sharding=rep)
+        text = fns["scatter_decode"].lower(slabs, rows, tok, tok,
+                                           window).compile().as_text()
+        for i, slab in enumerate(jax.tree.leaves(slabs)):
+            assert "{%d}: (%d, {}" % (i, i) in text
+            shape = ",".join(map(str, slab.shape))
+            assert not re.search(r"bf16\[%s\][^=]*copy\(" % shape, text)
+
+
+def test_serve_stages_share_one_placed_body(one_chip, monkeypatch):
+    """On a v5e:2x2 mesh (2-way data, 2-way model) the serve stages of a
+    DeepSeek-shaped stack (MLA, experts split over the model axis) share
+    one placement of the stacked body: it is placed once, both stages
+    hold the very same leaves, and both stage decode programs compile
+    against them."""
+    import dataclasses
+
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.configs.registry import get_config
+    from repro.core.lowering import lower_serve_stages
+    from repro.models import transformer as T
+    from repro.models.common import MeshPlan
+    from repro.models.model_zoo import build_model
+
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite-16b").reduced(),
+                              num_layers=5)
+    devices = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices
+    mesh = Mesh(np.array(devices).reshape(2, 2), ("data", "model"))
+    params = jax.eval_shape(build_model(cfg, MeshPlan.single_device()).init,
+                            jax.random.PRNGKey(0))
+    placed = []
+
+    def put(x, s):
+        out = jax.tree.map(
+            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+            x, s)
+        placed.append(jax.tree.leaves(out))
+        return out
+
+    monkeypatch.setattr(jax, "device_put", put)
+    B, L = 4, 64
+    sstaged = lower_serve_stages(cfg, mesh, params, num_stages=2,
+                                 cache_len=L, max_prompt_len=32,
+                                 group_size=B)
+    st0, st1 = sstaged.stages
+    body = jax.tree.leaves(st0.params["body"])
+    assert body and st0.units[1] > 1 and st1.units[0] > 1   # both run periods
+    assert all(a is b for a, b in zip(body,
+                                      jax.tree.leaves(st1.params["body"])))
+    assert sum(any(a is body[0] for a in out) for out in placed) == 1
+    specs = jax.tree.leaves(T.model_specs(cfg, MeshPlan(("data", "model"),
+                                                        (2, 2)))["body"],
+                            is_leaf=lambda x: isinstance(x, P))
+    assert [a.sharding for a in body] == [NamedSharding(mesh, s)
+                                         for s in specs]
+    tok = jax.ShapeDtypeStruct((B,), jnp.int32,
+                               sharding=NamedSharding(mesh, P("data")))
+    for st in sstaged.stages:
+        window = jax.eval_shape(st.init_caches, tok)
+        xin = tok if st.first else jax.ShapeDtypeStruct(
+            (B, 1, cfg.d_model), jnp.float32,
+            sharding=NamedSharding(mesh, P("data")))
+        st.decode.lower(st.params, window, xin, tok).compile()
+
+
+def test_grouped_expert_form_compiles(one_chip):
+    """The grouped expert form (``jax.lax.ragged_dot``), which the expert
+    layer picks on the TPU for large batches, compiles for a v5e chip at
+    DeepSeek-V2-Lite's widths with all 64 experts held, over 2048 tokens
+    of 6 pairs each."""
+    from repro.models import mlp
+
+    T, K, n, d, ff = 2048, 6, 64, 2048, 1408
+    assert mlp.expert_form(n, T, K, "tpu") == "grouped"
+    p = {k: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+         for k, s in (("w_gate", (n, d, ff)), ("w_up", (n, d, ff)),
+                      ("w_down", (n, ff, d)))}
+    args = (jax.ShapeDtypeStruct((T, d), jnp.bfloat16, sharding=one_chip),
+            jax.ShapeDtypeStruct((T * K,), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((T * K,), jnp.float32, sharding=one_chip))
+    jax.jit(mlp._grouped_form).lower(p, *args).compile()
