@@ -12,6 +12,11 @@ non-sum reduction:
 :func:`flash_decode_partial_ref` computes one shard's contribution;
 :func:`combine_partials` is the logical reduction (what the boxing op
 ``P -> B`` performs, here as pmax/psum pairs).
+
+Grouped-query attention is contracted against the cache as stored: the
+``H`` query heads are viewed as ``(KV, G)`` groups, ``G = H // KV``, so query
+head ``h`` reads KV head ``h // G`` (the order ``jnp.repeat`` would give) and
+no ``G``-fold copy of the K/V window is ever made.
 """
 from __future__ import annotations
 
@@ -35,15 +40,21 @@ def flash_decode_partial_ref(q, k, v, *, k_offset: int = 0,
     ``k_positions``: (B, L) explicit absolute position per slot (ring-buffer
     sliding-window caches; -1 = empty slot), overrides ``k_offset``.
     Returns (m, l, acc): (B,H), (B,H), (B,H,D) partials.
+
+    Query head ``h`` attends over KV head ``h // G`` (``G = H // KV``): q is
+    viewed as ``(B, KV, G, D)`` and both products contract against the
+    unexpanded ``(B, L, KV, D)`` cache. Scores are q x k at the inputs'
+    dtype, cast to float32 before scaling; the accumulator is the float32
+    softmax weights against V, which the dot promotes to float32 itself.
     """
     B, H, D = q.shape
     _, L, KV, _ = k.shape
     G = H // KV
     if sm_scale is None:
         sm_scale = 1.0 / (D ** 0.5)
-    k = jnp.repeat(k, G, axis=2)
-    v = jnp.repeat(v, G, axis=2)
-    s = jnp.einsum("bhd,bkhd->bhk", q, k).astype(jnp.float32) * sm_scale
+    qg = q.reshape(B, KV, G, D)
+    s = jnp.einsum("bngd,bknd->bngk", qg, k).astype(jnp.float32) * sm_scale
+    s = s.reshape(B, H, L)
     if k_positions is not None:
         kpos = k_positions                                   # (B, L)
         mask = kpos >= 0
@@ -60,8 +71,8 @@ def flash_decode_partial_ref(q, k, v, *, k_offset: int = 0,
     # fully-masked shards: m = -inf -> p = exp(-inf - -inf); force 0
     p = jnp.where(jnp.isfinite(m)[..., None], p, 0.0)
     l = p.sum(axis=-1)                             # (B, H)  P(sum) after rescale
-    acc = jnp.einsum("bhk,bkhd->bhd", p, v.astype(jnp.float32))
-    return m, l, acc
+    acc = jnp.einsum("bngk,bknd->bngd", p.reshape(B, KV, G, L), v)
+    return m, l, acc.reshape(B, H, v.shape[-1])
 
 
 def combine_partials(m, l, acc, axis_name: Optional[str] = None):

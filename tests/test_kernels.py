@@ -10,7 +10,7 @@ from repro.kernels.flash_attention.ref import (attention_dense_ref,
                                                flash_attention_ref)
 from repro.kernels.flash_decode.kernel import flash_decode_pallas
 from repro.kernels.flash_decode.ref import (
-    combine_partials, decode_attention_ref)
+    NEG_INF, combine_partials, decode_attention_ref, flash_decode_partial_ref)
 from repro.kernels.softmax_xent.kernel import xent_local_stats_pallas
 from repro.kernels.softmax_xent.ref import (combine_stats, local_stats_ref,
                                             softmax_xent_ref)
@@ -111,6 +111,96 @@ def test_flash_decode_shard_combine():
     got = combine_partials(m, l, a)
     want = decode_attention_ref(q, k, v, cur)
     assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-5)
+
+
+def _repeat_partial_ref(q, k, v, *, k_offset=0, cur_pos=None,
+                        sliding_window=0, k_positions=None):
+    """Decode partials with K/V repeated to the query heads and V cast to
+    float32 before P.V: the expanded form the grouped oracle must equal."""
+    B, H, D = q.shape
+    L, G = k.shape[1], H // k.shape[2]
+    k = jnp.repeat(k, G, axis=2)
+    v = jnp.repeat(v, G, axis=2)
+    s = jnp.einsum("bhd,bkhd->bhk", q, k).astype(jnp.float32) * D ** -0.5
+    if k_positions is not None:
+        kpos, mask = k_positions, k_positions >= 0
+    else:
+        kpos = jnp.broadcast_to(k_offset + jnp.arange(L), (B, L))
+        mask = jnp.ones((B, L), bool)
+    if cur_pos is not None:
+        mask &= kpos <= cur_pos[:, None]
+        if sliding_window:
+            mask &= kpos > cur_pos[:, None] - sliding_window
+    s = jnp.where(mask[:, None, :], s, NEG_INF)
+    m = s.max(axis=-1)
+    p = jnp.exp(s - m[..., None])
+    p = jnp.where(jnp.isfinite(m)[..., None], p, 0.0)
+    return m, p.sum(axis=-1), jnp.einsum("bhk,bkhd->bhd", p,
+                                         v.astype(jnp.float32))
+
+
+def _grouped_inputs(G, KV=2, B=3, L=40, D=16):
+    """Three slots of G query heads per KV head; ``_mask_kwargs`` gives
+    each slot its own decode position."""
+    q = jnp.asarray(RNG.normal(size=(B, G * KV, D)), jnp.float32)
+    k = jnp.asarray(RNG.normal(size=(B, L, KV, D)), jnp.float32)
+    v = jnp.asarray(RNG.normal(size=(B, L, KV, D)), jnp.float32)
+    return q, k, v
+
+
+def _mask_kwargs(mode, B, L):
+    cur = jnp.asarray([5, 21, L - 1], jnp.int32)
+    if mode == "full":
+        return {}
+    if mode == "cur_pos":
+        return dict(cur_pos=cur)
+    if mode == "sliding_window":
+        return dict(cur_pos=cur, sliding_window=9)
+    if mode == "k_positions":                     # ring buffer, -1 = empty
+        kpos = RNG.integers(0, 3 * L, size=(B, L))
+        kpos[RNG.random(size=(B, L)) < 0.3] = -1
+        return dict(cur_pos=jnp.asarray([2 * L, 3 * L, L], jnp.int32),
+                    sliding_window=L, k_positions=jnp.asarray(kpos, jnp.int32))
+    if mode == "k_offset":
+        return dict(cur_pos=cur + 30, k_offset=30)
+    if mode == "masked_shard":                    # every key after cur_pos
+        return dict(cur_pos=cur, k_offset=L)
+    raise ValueError(mode)
+
+
+@pytest.mark.parametrize("mode", ["full", "cur_pos", "sliding_window",
+                                  "k_positions", "k_offset", "masked_shard"])
+@pytest.mark.parametrize("G", [1, 2, 4, 8])
+def test_grouped_decode_partials_equal_repeat_form(G, mode):
+    """The grouped-query oracle (query head h reads KV head h // G, no
+    expanded window, V promoted inside the dot) gives the repeat-and-float32
+    form's (m, l, acc) and combined output to float32 rounding, in every
+    mask mode."""
+    q, k, v = _grouped_inputs(G)
+    kw = _mask_kwargs(mode, q.shape[0], k.shape[1])
+    got = flash_decode_partial_ref(q, k, v, **kw)
+    want = _repeat_partial_ref(q, k, v, **kw)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype == jnp.float32
+        assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-5, atol=1e-5)
+    assert_allclose(np.asarray(combine_partials(*(a[None] for a in got))),
+                    np.asarray(combine_partials(*(a[None] for a in want))),
+                    rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("G", [1, 2, 4, 8])
+def test_grouped_decode_sequence_shards_combine(G):
+    """Grouped partials of two sequence shards (``S(seq)``; the second wholly
+    past row 0's position) combine to the repeat form's whole-cache output."""
+    q, k, v = _grouped_inputs(G)
+    cur = jnp.asarray([5, 21, 39], jnp.int32)
+    parts = [flash_decode_partial_ref(q, k[:, i:i + 20], v[:, i:i + 20],
+                                      k_offset=i, cur_pos=cur)
+             for i in (0, 20)]
+    got = combine_partials(*(jnp.stack(a) for a in zip(*parts)))
+    want = combine_partials(*(a[None] for a in _repeat_partial_ref(
+        q, k, v, cur_pos=cur)))
+    assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6)
 
 
 # ---------------------------------------------------------------------------

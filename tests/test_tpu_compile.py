@@ -191,6 +191,53 @@ def test_deepseek_stage_decode_compiles_at_published_widths(one_chip,
             assert not re.search(r"bf16\[%s\][^=]*copy\(" % shape, text)
 
 
+def test_qwen3_stage_decode_reads_the_unexpanded_window(one_chip,
+                                                        monkeypatch):
+    """qwen3-1.7b at the benchmark cell's sizes (2 stages, 4 slots, cache
+    2448; 16 query heads over 8 KV heads of 128): both stage decode
+    programs compile for a v5e chip, and grouped-query decode attention
+    contracts against the cache as stored. No KV-head-expanded window
+    (``[4,2448,8,2,128]`` at any dtype) and no float32 copy of a window
+    appears anywhere in the optimized programs."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.configs.registry import get_config
+    from repro.core.lowering import lower_serve_stages
+    from repro.models.common import MeshPlan
+    from repro.models.model_zoo import build_model
+
+    cfg = get_config("qwen3-1.7b")
+    B, L = 4, 2448
+    mesh = Mesh(np.array(list(one_chip.device_set)).reshape(1, 1),
+                ("data", "model"))
+    rep = NamedSharding(mesh, P())
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16),
+        jax.eval_shape(build_model(cfg, MeshPlan.single_device()).init,
+                       jax.random.PRNGKey(0)))
+    # a described device holds no arrays: placing the params gives shapes
+    monkeypatch.setattr(jax, "device_put", lambda x, s: jax.tree.map(
+        lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        x, s))
+    sstaged = lower_serve_stages(cfg, mesh, params, num_stages=2,
+                                 cache_len=L, max_prompt_len=2048,
+                                 group_size=B)
+    tok = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=rep)
+    expanded = re.compile(r"\w+\[4,2448,8,2,128\]|f32\[4,2448,(8|16),128\]")
+    for st in sstaged.stages:
+        window = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+            jax.eval_shape(st.init_caches, tok))
+        assert {a.shape[1:] for a in jax.tree.leaves(window)
+                if a.ndim == 5} == {(B, L, 8, 128)}
+        xin = tok if st.first else jax.ShapeDtypeStruct(
+            (B, 1, cfg.d_model), jnp.bfloat16, sharding=rep)
+        text = st.decode.lower(st.params, window, xin,
+                               tok).compile().as_text()
+        assert not expanded.search(text), expanded.search(text).group(0)
+
+
 def test_serve_stages_share_one_placed_body(one_chip, monkeypatch):
     """On a v5e:2x2 mesh (2-way data, 2-way model) the serve stages of a
     DeepSeek-shaped stack (MLA, experts split over the model axis) share
