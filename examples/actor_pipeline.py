@@ -84,8 +84,8 @@ def main():
             print(f"    stage{s}: {len(hist)} fires, busy {busy * 1e3:6.1f} ms, "
                   f"first fire at {hist[0][0] * 1e3:6.1f} ms")
     print("(stage compute here is sub-ms host work, so the two schedules can "
-          "tie on a small CPU; benchmarks/bench_actor_pipeline.py emulates "
-          "per-stage device latency and shows the quota-driven speedup)")
+          "tie on a small CPU; the quota-driven overlap shows once each "
+          "stage's fire is device-bound)")
 
 
 if __name__ == "__main__":

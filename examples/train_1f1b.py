@@ -73,8 +73,7 @@ def main():
               f"bit-identical to monolithic: {bool(bit)}")
     print("(loss falls, the pipeline and the monolithic step agree bitwise; "
           "api.assert_sessions_match(pipe, mono, data, steps=N) is the "
-          "one-liner form; benchmarks/bench_1f1b_train.py adds emulated "
-          "device latency and shows the 1F1B speedup over serialized)")
+          "one-liner form)")
 
 
 if __name__ == "__main__":

@@ -110,7 +110,7 @@ def _default_regs(num_stages: int) -> list:
 def run_session_checks(sess: Any) -> StaticReport:
     """Run the static passes over a compiled ``api`` session (duck-typed:
     works on :class:`repro.api.Session` and :class:`repro.api.ServeSession`
-    across every mode × backend × runtime)."""
+    across every mode × backend)."""
     if getattr(sess, "mode", None) == "serve":
         return _serve_session_checks(sess)
     return _graph_session_checks(sess)
@@ -121,7 +121,7 @@ def _graph_session_checks(sess: Any) -> StaticReport:
     boundary_sbp = None
     memory: Optional[Dict[str, int]] = None
     if sess.backend == "actors":
-        specs, _ = sess._engine._make_builder()()
+        specs, _ = sess._engine._specs()
         staged = getattr(sess._engine, "tstaged",
                          getattr(sess._engine, "staged", None))
         if staged is not None:
@@ -149,7 +149,7 @@ def _serve_session_checks(sess: Any) -> StaticReport:
     num_stages = sess.sstaged.num_stages
     regs = sess.regs if sess.regs is not None else _default_regs(num_stages)
     if sess.backend == "actors":
-        specs, _ = sess._engine._make_builder()()
+        specs, _ = sess._engine._specs()
         # serve specs are open-ended (max_fires=0, bounded per round); the
         # static pass analyzes one representative full round instead
         round_items = max(1, int(sess.num_groups))

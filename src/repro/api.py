@@ -27,11 +27,9 @@ resource planning, §2.3), ``microbatch_inputs`` as the non-param graph
 inputs in train mode.
 
 ``backend="actors"`` runs stages as actors (1F1B emerging from register
-quotas, §4.3/§6.5) on a runtime chosen by ``runtime=``: ``"threads"`` drives
-every actor on OS threads in this process, ``"processes"`` gives each
-pipeline stage its own worker process (paper Fig 7/8 — the node field of the
-64-bit actor address becomes a real OS process) with payloads crossing
-stages over a real transport. ``backend="monolithic"`` runs the same
+quotas, §4.3/§6.5), each driven on an OS thread of this process
+(:class:`repro.runtime.threaded.ThreadedRuntime`). ``backend="monolithic"``
+runs the same
 :class:`Session` surface over whole-graph jitted programs (``lower_plan`` /
 ``lower_train_plan``) with identical microbatch chunking, so
 pipeline-vs-monolithic bit-identity checks are one-liners
@@ -50,13 +48,10 @@ from repro.core.lowering import (OptimizerSpec, PrecisionPolicy, lower_plan,
                                  reassemble_sinks, split_microbatches)
 from repro.core.planner import Plan, plan as plan_sbp
 from repro.launch.mesh import as_auto
-from repro.runtime.base import RUNTIME_KINDS
 from repro.runtime.pipeline import (
     ActorPipelineExecutor, InlineServeEngine, PipelinePlan,
     ServePipelineExecutor, TrainPipelineExecutor, check_run_inputs,
     plan_registers)
-from repro.runtime.recipes import (InferRecipe, MeshSpec, ServeRecipe,
-                                   TrainRecipe)
 
 MODES = ("infer", "train", "serve")
 BACKENDS = ("actors", "monolithic")
@@ -358,11 +353,10 @@ class Session:
                  regs: Optional[List[int]], reg_plan: Optional[PipelinePlan],
                  optimizer: Optional[OptimizerSpec],
                  microbatch_inputs: List[str], num_microbatches: int,
-                 timeout: float = 300.0, runtime: Optional[str] = None):
+                 timeout: float = 300.0):
         self.graph = graph
         self.mode = mode
         self.backend = backend
-        self.runtime = runtime        # "threads"/"processes"; None: monolithic
         self.plan = plan
         self.partition = partition
         self.regs = regs
@@ -436,8 +430,8 @@ class Session:
                                 step=step)
 
     def close(self) -> None:
-        """Release the engine's workers (actor threads or worker processes).
-        Monolithic engines have none; the call is a no-op there."""
+        """Release the engine's actor threads. Monolithic engines have
+        none; the call is a no-op there."""
         close = getattr(self._engine, "close", None)
         if close is not None:
             close()
@@ -499,9 +493,8 @@ class Session:
         """Human-readable report of the compiled artifact: graph shape, SBP
         plan, stage partition + register quotas, optimizer."""
         g = self.graph
-        rt = f" runtime={self.runtime}" if self.runtime is not None else ""
         lines = [f"=== repro.api session: mode={self.mode} "
-                 f"backend={self.backend}{rt} ===",
+                 f"backend={self.backend} ===",
                  f"graph: {len(g.ops)} ops, "
                  f"inputs {[t.name for t in g.inputs]}, "
                  f"sinks {[t.name for t in self._sinks]}",
@@ -592,7 +585,7 @@ class ServeSession:
                  num_groups: int, group_size: int, cache_len: int,
                  max_prompt_len: int, max_new_tokens: int,
                  regs: Optional[List[int]], timeout: float = 300.0,
-                 runtime: Optional[str] = None, cache: str = "dense",
+                 cache: str = "dense",
                  cache_spec=None, sampling=None,
                  prefill_chunk: Optional[int] = None,
                  share_prefix: bool = False):
@@ -600,7 +593,6 @@ class ServeSession:
         self.mesh = mesh
         self.mode = "serve"
         self.backend = backend
-        self.runtime = runtime        # "threads"/"processes"; None: monolithic
         self.sstaged = sstaged
         self.num_groups = num_groups
         self.group_size = group_size
@@ -771,9 +763,8 @@ class ServeSession:
     def describe(self) -> str:
         """Human-readable report of the compiled serving artifact."""
         cfg = self.cfg
-        rt = f" runtime={self.runtime}" if self.runtime is not None else ""
         lines = [f"=== repro.api session: mode=serve "
-                 f"backend={self.backend}{rt} ===",
+                 f"backend={self.backend} ===",
                  f"model: {cfg.name} ({cfg.num_layers} layers, "
                  f"d_model={cfg.d_model}, vocab={cfg.vocab_size} "
                  f"padded to {cfg.padded_vocab()})",
@@ -895,7 +886,7 @@ def _compile_serve(cfg, *, backend: str, stages: Optional[int], regs,
                    group_size: Optional[int], cache_len: Optional[int],
                    max_prompt_len: Optional[int],
                    max_new_tokens: Optional[int],
-                   runtime: str = "threads", cache: Optional[str] = None,
+                   cache: Optional[str] = None,
                    page_len: Optional[int] = None,
                    num_pages: Optional[int] = None, sampling=None,
                    prefill_chunk: Optional[int] = None,
@@ -944,10 +935,6 @@ def _compile_serve(cfg, *, backend: str, stages: Optional[int], regs,
     if params is None:
         params = build_model(cfg, plan_from_mesh(mesh)).init(
             jax.random.PRNGKey(0))
-    host_params = None
-    if backend != "monolithic" and runtime == "processes":
-        # workers re-lower from data: ship host copies of the params
-        host_params = jax.device_get(params)
     sstaged = lower_serve_stages(cfg, mesh, params, num_stages=stages,
                                  cache_len=cache_len,
                                  max_prompt_len=max_prompt_len,
@@ -966,18 +953,8 @@ def _compile_serve(cfg, *, backend: str, stages: Optional[int], regs,
         engine = InlineServeEngine(sstaged, cache_spec=cache_spec,
                                    sampling=sampling)
         regs = None
-        runtime = None
     else:
-        recipe = None
-        if runtime == "processes":
-            # the mesh travels as device ids (repro.runtime.recipes)
-            recipe = ServeRecipe(cfg, host_params,
-                                 num_stages=stages, cache_len=cache_len,
-                                 max_prompt_len=max_prompt_len,
-                                 group_size=group_size,
-                                 mesh=MeshSpec.capture(mesh))
         engine = ServePipelineExecutor(sstaged, regs=regs, fn_wrap=fn_wrap,
-                                       runtime=runtime, recipe=recipe,
                                        cache_spec=cache_spec,
                                        sampling=sampling)
         regs = engine.regs if engine.regs is not None else \
@@ -987,7 +964,7 @@ def _compile_serve(cfg, *, backend: str, stages: Optional[int], regs,
                         group_size=group_size, cache_len=cache_len,
                         max_prompt_len=max_prompt_len,
                         max_new_tokens=max_new_tokens, regs=regs,
-                        timeout=timeout, runtime=runtime, cache=cache,
+                        timeout=timeout, cache=cache,
                         cache_spec=cache_spec, sampling=sampling,
                         prefill_chunk=prefill_chunk,
                         share_prefix=share_prefix)
@@ -1140,7 +1117,7 @@ def _apply_restore(sess: "Session", restore) -> "Session":
 
 
 def compile(graph, *, mode: str = "infer",
-            backend: str = "actors", runtime: Optional[str] = None,
+            backend: str = "actors",
             plan: Optional[Plan] = None,
             partition: Optional[StagePartition] = None,
             stages: Optional[int] = None, num_microbatches: int = 1,
@@ -1193,14 +1170,6 @@ def compile(graph, *, mode: str = "infer",
       actors with register-quota back-pressure (§4.3); ``"monolithic"`` —
       one whole-graph jitted program with identical microbatch semantics
       (the bit-identity reference).
-    * ``runtime`` (actors backend only): ``"threads"`` (default) drives the
-      actors on OS threads in this process; ``"processes"`` spawns one
-      worker process per pipeline stage — stage state (placed params,
-      optimizer state, serve caches) lives in the owning worker, payloads
-      cross stages as serialized host arrays, and each worker re-lowers its
-      stages from a picklable recipe (:mod:`repro.runtime.recipes`). With
-      ``"processes"``, ``fn_wrap`` and a schedule-callable ``lr`` must be
-      picklable (module-level, not lambdas/closures).
     * ``plan``: an SBP :class:`~repro.core.planner.Plan`; default
       :func:`repro.core.planner.plan` (Table-2 boxing-cost minimization).
     * ``partition`` / ``stages``: an explicit
@@ -1223,8 +1192,9 @@ def compile(graph, *, mode: str = "infer",
     * ``mesh`` / ``stage_meshes``: one shared device mesh (default
       ``graph.placement.to_mesh()``) or one mesh per stage — the paper's
       MPMD placement (actors backend only).
-    * ``fn_wrap``: optional stage-body decorator (benchmarks use it to
-      emulate device latency; actors backend only).
+    * ``fn_wrap`` (serve + actors only): ``fn_wrap(stage_index, fn)``
+      wraps each stage actor's body — a hook on every stage fire (the
+      benchmark's decode recorder uses it).
     * ``snapshot_dir`` / ``snapshot_every`` (train + actors only): write an
       async snapshot every N steps — one ``snap{s}`` actor per parameterized
       stage serializes that stage's post-update params + optimizer state off
@@ -1260,8 +1230,7 @@ def compile(graph, *, mode: str = "infer",
 
     The monolithic backend accepts but does not use the schedule hints
     ``partition``/``stages``/``regs`` (so one kwargs dict can sweep both
-    backends); ``stage_meshes`` and ``fn_wrap`` would change its execution
-    and are rejected.
+    backends); ``stage_meshes`` would change its execution and is rejected.
 
     ``check="static"`` (the default) runs the :mod:`repro.analysis` plan
     verifier over the compiled artifacts before returning — deadlock
@@ -1279,26 +1248,6 @@ def compile(graph, *, mode: str = "infer",
     if backend not in BACKENDS:
         raise ValueError(
             f"unknown backend {backend!r}; expected one of {BACKENDS}")
-    if runtime is not None and runtime not in RUNTIME_KINDS:
-        raise ValueError(
-            f"unknown runtime {runtime!r}; expected one of {RUNTIME_KINDS}")
-    if backend == "monolithic" and runtime is not None:
-        raise ValueError(
-            "runtime= requires backend='actors' (the monolithic backend "
-            "runs one jitted program in-process, there is no actor runtime "
-            "to choose)")
-    if runtime is None and backend == "actors":
-        runtime = "threads"
-    if runtime == "processes":
-        import jax
-
-        if jax.default_backend() != "cpu":
-            raise ValueError(
-                "runtime='processes' runs one JAX client per pipeline node "
-                "and needs the CPU backend; on "
-                f"{jax.default_backend()!r} this process already holds the "
-                "accelerator, which allows one process per chip — use "
-                "runtime='threads'")
     # every mesh the lowering sees has Auto axes (repro.launch.mesh)
     mesh = as_auto(mesh)
     if stage_meshes is not None:
@@ -1351,14 +1300,15 @@ def compile(graph, *, mode: str = "infer",
             mesh=mesh, fn_wrap=fn_wrap, timeout=timeout,
             num_groups=num_groups, group_size=group_size,
             cache_len=cache_len, max_prompt_len=max_prompt_len,
-            max_new_tokens=max_new_tokens, runtime=runtime, cache=cache,
+            max_new_tokens=max_new_tokens, cache=cache,
             page_len=page_len, num_pages=num_pages, sampling=sampling,
             prefill_chunk=prefill_chunk, check=check)
     serve_only = {"num_groups": num_groups, "group_size": group_size,
                   "cache_len": cache_len, "max_prompt_len": max_prompt_len,
                   "max_new_tokens": max_new_tokens, "cache": cache,
                   "page_len": page_len, "num_pages": num_pages,
-                  "sampling": sampling, "prefill_chunk": prefill_chunk}
+                  "sampling": sampling, "prefill_chunk": prefill_chunk,
+                  "fn_wrap": fn_wrap}
     bad = [k for k, v in serve_only.items() if v is not None]
     if bad:
         raise ValueError(
@@ -1411,14 +1361,11 @@ def compile(graph, *, mode: str = "infer",
 
     if backend == "monolithic":
         # partition/stages/regs are schedule *hints* — harmless to accept so
-        # a backend sweep can reuse one kwargs dict — but fn_wrap and
-        # stage_meshes change execution and cannot be honored here
+        # a backend sweep can reuse one kwargs dict — but stage_meshes
+        # changes execution and cannot be honored here
         if stage_meshes is not None:
             raise ValueError("stage_meshes requires backend='actors' "
                              "(the monolithic program runs on one mesh)")
-        if fn_wrap is not None:
-            raise ValueError("fn_wrap requires backend='actors' "
-                             "(there are no stage bodies to wrap)")
         if mesh is None:
             mesh = graph.placement.to_mesh()
         if mode == "infer":
@@ -1440,47 +1387,28 @@ def compile(graph, *, mode: str = "infer",
 
     part = _resolve_partition(graph, partition, stages)
     regs, reg_plan = _resolve_regs(regs, part, num_microbatches, mode)
-    # the recipe captures the *user's* mesh choice (None -> each worker
-    # defaults to graph.placement.to_mesh() itself, device-table agnostic)
-    mesh_spec = MeshSpec.capture(mesh)
-    stage_mesh_specs = (None if stage_meshes is None else
-                        tuple(MeshSpec.capture(m) for m in stage_meshes))
     if mesh is None and stage_meshes is None:
         mesh = graph.placement.to_mesh()
     if mode == "infer":
         staged = lower_stages(graph, plan, part, mesh=mesh,
                               stage_meshes=stage_meshes)
-        recipe = None
-        if runtime == "processes":
-            recipe = InferRecipe(graph, plan, part, mesh=mesh_spec,
-                                 stage_meshes=stage_mesh_specs)
         engine = ActorPipelineExecutor(staged, microbatch_inputs,
-                                       num_microbatches, regs=regs,
-                                       fn_wrap=fn_wrap, runtime=runtime,
-                                       recipe=recipe)
+                                       num_microbatches, regs=regs)
     else:
         tstaged = lower_train_stages(graph, plan, part, list(params),
                                      loss=loss, mesh=mesh,
                                      stage_meshes=stage_meshes,
                                      optimizer=optimizer)
-        recipe = None
-        if runtime == "processes":
-            recipe = TrainRecipe(graph, plan, part, list(params), loss=loss,
-                                 mesh=mesh_spec,
-                                 stage_meshes=stage_mesh_specs,
-                                 optimizer=optimizer)
         engine = TrainPipelineExecutor(tstaged, params, microbatch_inputs,
                                        num_microbatches, lr=lr, regs=regs,
-                                       fn_wrap=fn_wrap, optimizer=optimizer,
-                                       runtime=runtime, recipe=recipe,
+                                       optimizer=optimizer,
                                        snapshot_dir=snapshot_dir,
                                        snapshot_every=snapshot_every,
                                        faults=faults)
     sess = Session(graph=graph, mode=mode, backend=backend, engine=engine,
                    plan=plan, partition=part, regs=regs, reg_plan=reg_plan,
                    optimizer=optimizer, microbatch_inputs=microbatch_inputs,
-                   num_microbatches=num_microbatches, timeout=timeout,
-                   runtime=runtime)
+                   num_microbatches=num_microbatches, timeout=timeout)
     sess = _attach_static_report(sess, check)
     return _apply_restore(sess, restore)
 

@@ -1307,8 +1307,7 @@ def split_moe_counts(stage: ServeStage, out):
 def take_moe_counts(stages) -> Optional[Dict[str, int]]:
     """The expert-layer counts of ``stages`` since the last take, summed
     and read to the host once, by :data:`repro.models.mlp.MOE_COUNTERS`
-    name; ``None`` where none were added in this process (stages fired in
-    worker processes count there)."""
+    name; ``None`` where none were added."""
     import numpy as np
 
     from repro.models.mlp import MOE_COUNTERS

@@ -39,22 +39,12 @@ class OpDef:
     flops: Optional[Callable[["OpSpec", Sequence[Tuple[int, ...]]], float]] = None
 
 
-def _opspec_from_registry(name: str, attrs: Dict) -> "OpSpec":
-    """Pickle reconstructor for :class:`OpSpec` — resolve the op definition
-    from :data:`REGISTRY` by name (op defs carry lambdas and cannot cross a
-    process boundary; the registry contents are identical in every worker)."""
-    return OpSpec(REGISTRY[name], attrs)
-
-
 @dataclasses.dataclass
 class OpSpec:
     """An op instance: definition + static attributes (axes, shapes...)."""
 
     opdef: OpDef
     attrs: Dict = dataclasses.field(default_factory=dict)
-
-    def __reduce__(self):
-        return (_opspec_from_registry, (self.opdef.name, self.attrs))
 
     @property
     def name(self):

@@ -1,21 +1,20 @@
-"""Deterministic fault injection on the Runtime seam (elastic-training gate).
+"""Deterministic fault injection into the actor runtime (elastic-training gate).
 
 The paper's actor model claims the register/counter protocol — not timing
 luck — carries correctness: every dependency (data, resources, movement) is
 an explicit counter, so a delayed, duplicated or reordered message must
 never change *what* is computed, only *when*. This module turns that claim
-into exercised code: a picklable :class:`FaultPlan` rides into either
-runtime through ``make_runtime(kind, builder, faults=...)`` and a
-:class:`FaultInjector` applies the faults deterministically:
+into exercised code: a :class:`FaultPlan` rides into the runtime through
+``ThreadedRuntime(specs, faults=...)`` and a :class:`FaultInjector` applies
+the faults deterministically:
 
-* :class:`KillWorker` — raise :class:`WorkerKilled` (threads) or hard-exit
-  the worker process (processes, ``os._exit``) immediately before the named
-  actor's Nth fire. Exercises the PR-6 ``WorkerError``/dead-worker/Mattern
-  machinery and the snapshot-restore path end to end.
+* :class:`KillWorker` — raise :class:`WorkerKilled` immediately before the
+  named actor's Nth fire. Exercises the dead-worker path of the runtime
+  and the snapshot-restore path end to end.
 * :class:`DelayEdge` — deliver one ``Req`` on a named edge late. Sound by
   construction: the producer's register stays referenced until the consumer
-  acks, so the epoch cannot conclude under a delayed message (the Mattern
-  probe sees ``live > 0`` / unbalanced counters).
+  acks, so the epoch cannot conclude under a delayed message (the engine's
+  ``live`` counter stays above zero).
 * :class:`DuplicateReq` — deliver one ``Req`` twice. The consumer-side
   per-channel resequencer (:meth:`repro.runtime.actor.Actor.on_req`) drops
   the second copy *without* acking it, so the producer's refcount stays
@@ -25,9 +24,7 @@ runtime through ``make_runtime(kind, builder, faults=...)`` and a
   runtime's ``TimeoutError`` naming the stuck actor — a *detected* fault,
   never silent corruption.
 
-Faults are one-shot: each entry triggers at most once per injector (per
-worker process under ``runtime="processes"`` — routing happens only at the
-sending engine, so a fault still applies exactly once per edge).
+Faults are one-shot: each entry triggers at most once per injector.
 
 Delayed delivery runs on a daemon ``threading.Timer``. A timer that
 outlives its epoch (possible only after the epoch was already abandoned by
@@ -37,24 +34,17 @@ timer captures the epoch counter and the epoch's own mailbox table.
 from __future__ import annotations
 
 import dataclasses
-import os
 from typing import Optional, Tuple, Union
 
-from repro.runtime.base import WorkerError
 from repro.runtime.messages import Req
 
-#: Exit code a process worker dies with under :class:`KillWorker` — the
-#: driver's liveness probe reports it in the ``WorkerError`` message.
-KILL_EXIT_CODE = 57
+
+class WorkerError(RuntimeError):
+    """An actor's worker died mid-epoch; kill-and-resume callers catch it."""
 
 
 class WorkerKilled(WorkerError):
-    """A :class:`KillWorker` fault fired under ``runtime="threads"``.
-
-    Subclasses :class:`WorkerError` so kill-and-resume callers catch one
-    exception type for both runtimes (process workers die for real and
-    surface as the ordinary dead-worker ``WorkerError``).
-    """
+    """A :class:`KillWorker` fault fired: the actor's worker is dead."""
 
 
 @dataclasses.dataclass(frozen=True)
@@ -102,7 +92,7 @@ Fault = Union[KillWorker, DelayEdge, DuplicateReq, DropAck]
 
 @dataclasses.dataclass(frozen=True)
 class FaultPlan:
-    """A deterministic, picklable set of faults to inject into one run."""
+    """A deterministic set of faults to inject into one run."""
 
     faults: Tuple[Fault, ...] = ()
 
@@ -119,19 +109,15 @@ class FaultPlan:
 
 
 class FaultInjector:
-    """Applies a :class:`FaultPlan` inside one ``_LocalEngine``.
+    """Applies a :class:`FaultPlan` inside one ``_Engine``.
 
     The engine calls :meth:`before_fire` under the firing actor's thread and
     :meth:`route` for every outgoing message; both are cheap no-ops once
-    every fault has triggered. One injector per engine — under
-    ``runtime="processes"`` each worker builds its own from the shipped
-    plan, and a fault naming a remote actor/edge simply never matches
-    there.
+    every fault has triggered. One injector per engine.
     """
 
-    def __init__(self, plan: FaultPlan, process_mode: bool = False):
+    def __init__(self, plan: FaultPlan):
         self.plan = plan
-        self.process_mode = process_mode
         self._fired = {}        # actor name -> cumulative fire attempts
         self._done = set()      # indices of consumed (one-shot) faults
         self._armed = len(plan.faults) > 0
@@ -147,7 +133,8 @@ class FaultInjector:
 
     # -- fire-path faults --------------------------------------------------------
     def before_fire(self, name: str) -> None:
-        """Called immediately before actor ``name`` fires; may not return."""
+        """Called immediately before actor ``name`` fires; raises
+        :class:`WorkerKilled` at a :class:`KillWorker` fault."""
         if not self._armed:
             return
         n = self._fired.get(name, 0) + 1
@@ -157,13 +144,8 @@ class FaultInjector:
                 continue
             if f.actor == name and f.fire == n:
                 self._done.add(i)
-                if self.process_mode:
-                    # a real worker death: no unwind, no goodbye — the
-                    # driver's liveness probe must catch it
-                    os._exit(KILL_EXIT_CODE)
                 raise WorkerKilled(
-                    f"fault injection: killed worker at {name} fire {n}",
-                    node=None)
+                    f"fault injection: killed worker at {name} fire {n}")
 
     # -- message-path faults -----------------------------------------------------
     def route(self, msg, src_name: str, dst_name: str):

@@ -31,17 +31,15 @@ Three executors then run *real compiled programs* under that protocol:
 * :func:`serve_stage_actor_specs` / :class:`ServePipelineExecutor` —
   continuous-batching decode with per-stage caches as actor-local state.
 
-Every executor builds its actor graph ONCE (a picklable *spec builder*) and
-drives it through the :class:`repro.runtime.base.Runtime` seam: actors are
-resettable state machines, each run/step/round is one *epoch* over the same
-graph, with per-epoch inputs delivered via ``ctx`` (routed to
-``ActorSpec.on_epoch`` hooks) and per-epoch fire bounds via ``fires``.
-Persistent per-stage state — placed params, optimizer state, serve caches —
-lives in the actor closures, resident wherever the actor runs. Stage ``s``
-is addressed at node ``s + 1`` (data/admit/norm at node 0), so under
-``runtime="processes"`` each stage owns a real worker process and payloads
-cross stages as serialized host arrays (:func:`repro.runtime.base
-.encode_payload`) while same-node registers stay zero-copy.
+Every executor builds its actor graph ONCE and drives it on a
+:class:`repro.runtime.threaded.ThreadedRuntime`: actors are resettable state
+machines, each run/step/round is one *epoch* over the same graph, with
+per-epoch inputs delivered via ``ctx`` (routed to ``ActorSpec.on_epoch``
+hooks) and per-epoch fire bounds via ``fires``. Persistent per-stage state —
+placed params, optimizer state, serve caches — lives in the actor closures
+and never round-trips through the driver. Stage ``s`` is addressed at node
+``s + 1`` (data/admit/norm at node 0), and payloads pass between stages by
+reference.
 """
 from __future__ import annotations
 
@@ -50,8 +48,8 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from repro.runtime.actor import ActorSpec
-from repro.runtime.base import RUNTIME_KINDS, make_runtime
 from repro.runtime.scheduler import CommModel, simulate
+from repro.runtime.threaded import ThreadedRuntime
 
 
 def _validate_regs(regs: Sequence[int], num_stages: int,
@@ -206,58 +204,23 @@ def check_run_inputs(provided, expected, what: str = "input",
             f"expected {what}s: {sorted(expected)}")
 
 
-class _SpecBuilderBase:
-    """Base of the picklable spec builders the executors hand to
-    :func:`repro.runtime.base.make_runtime`.
-
-    Carries either an already-lowered program (``staged``, process-local —
-    what ``runtime="threads"`` uses directly) or a lowering recipe
-    (:mod:`repro.runtime.recipes`, pure data). Pickling for a worker process
-    drops the lowered program and ships the recipe; the worker re-lowers on
-    arrival and jit-compiles only the stages it fires.
-    """
-
-    def __init__(self, staged=None, recipe=None):
-        if staged is None and recipe is None:
-            raise ValueError("spec builder needs a lowered program or a "
-                             "lowering recipe")
-        self._staged = staged
-        self.recipe = recipe
-
-    @property
-    def staged(self):
-        if self._staged is None:
-            self._staged = self.recipe.lower()
-        return self._staged
-
-    def __getstate__(self):
-        if self.recipe is None:
-            raise ValueError(
-                "this spec builder carries only a process-local lowered "
-                "program; runtime='processes' needs a lowering recipe "
-                "(repro.runtime.recipes) — compile through repro.api")
-        state = dict(self.__dict__)
-        state["_staged"] = None      # workers re-lower from the recipe
-        return state
-
-
 class _StagedExecutorBase:
     """Shared machinery of the stage-pipeline executors.
 
     Construction-time validation (microbatch count, register-quota length,
-    microbatch input names, runtime kind), run-time input validation
+    microbatch input names), run-time input validation
     (:func:`check_run_inputs`), and the persistent runtime underneath: the
-    executor builds ONE :class:`repro.runtime.base.Runtime` from its spec
-    builder on first use and re-runs it per step/round (one epoch each),
-    with per-epoch values delivered through ``ctx``/``fires``. Per-run
-    instrumentation (``last_makespan``, ``last_history``, ``last_peak_regs``,
-    ``last_edge_bytes``) snapshots the most recent epoch.
+    executor builds ONE :class:`repro.runtime.threaded.ThreadedRuntime`
+    over the actor graph of :meth:`_specs` on first use and re-runs it per
+    step/round (one epoch each), with per-epoch values delivered through
+    ``ctx``/``fires``. Per-run instrumentation (``last_makespan``,
+    ``last_history``, ``last_peak_regs``, ``last_edge_bytes``) snapshots
+    the most recent epoch.
     """
 
     def __init__(self, program, microbatch_inputs: Sequence[str],
                  num_microbatches: int, regs: Optional[Sequence[int]],
-                 fn_wrap: Optional[Callable] = None,
-                 runtime: str = "threads", recipe=None, faults=None):
+                 faults=None):
         if num_microbatches < 1:
             raise ValueError(
                 f"num_microbatches must be >= 1, got {num_microbatches}")
@@ -266,24 +229,12 @@ class _StagedExecutorBase:
         for n in microbatch_inputs:
             if n not in program.input_names:
                 raise ValueError(f"{n} is not a graph input")
-        if runtime not in RUNTIME_KINDS:
-            raise ValueError(
-                f"unknown runtime {runtime!r}; expected one of "
-                f"{RUNTIME_KINDS}")
-        if runtime == "processes" and recipe is None:
-            raise ValueError(
-                "runtime='processes' needs a picklable lowering recipe "
-                "(repro.runtime.recipes) — compile through repro.api, or "
-                "pass recipe=")
         self.microbatch_inputs = list(microbatch_inputs)
         self.num_microbatches = num_microbatches
         self.regs = regs
-        self.fn_wrap = fn_wrap
-        self.runtime_kind = runtime
-        self.recipe = recipe
         self.faults = faults          # optional chaos FaultPlan (tests/CI)
         # optional repro.analysis.trace.TraceRecorder — set before the first
-        # run; the threads runtime logs every Req delivery into it so
+        # run; the runtime logs every Req delivery into it so
         # repro.analysis.trace.check_trace can certify the resequencer
         self.trace = None
         self._rt = None
@@ -292,16 +243,17 @@ class _StagedExecutorBase:
         self.last_peak_regs: Dict[str, int] = {}
         self.last_edge_bytes: Dict[Tuple[str, str], int] = {}
 
-    def _make_builder(self):
+    def _specs(self):
+        """``(actor specs, collect_outputs_of)`` of this executor's graph."""
         raise NotImplementedError
 
     @property
-    def runtime(self):
-        """The persistent :class:`repro.runtime.base.Runtime` underneath
-        (built on first use)."""
+    def runtime(self) -> ThreadedRuntime:
+        """The persistent runtime underneath (built on first use)."""
         if self._rt is None:
-            self._rt = make_runtime(self.runtime_kind, self._make_builder(),
-                                    faults=self.faults, trace=self.trace)
+            specs, collect = self._specs()
+            self._rt = ThreadedRuntime(specs, collect_outputs_of=collect,
+                                       faults=self.faults, trace=self.trace)
         return self._rt
 
     def _run_rt(self, ctx, fires, timeout: float):
@@ -318,8 +270,8 @@ class _StagedExecutorBase:
         return outs
 
     def close(self) -> None:
-        """Release the runtime's workers (threads or processes). The
-        executor rebuilds it lazily if used again."""
+        """Release the runtime's worker threads. The executor rebuilds it
+        lazily if used again."""
         if self._rt is not None:
             self._rt.close()
             self._rt = None
@@ -363,8 +315,8 @@ def _place_incoming(input_names, bound: Dict[str, Any],
 def _stage_binding(stage):
     """Persistent bound-input state for one stage actor: a ``bound`` dict
     the closures read at fire time and an ``on_epoch`` hook that (re)binds
-    the values the driver sent in ``ctx`` — placed on the stage's mesh in
-    the worker that OWNS the stage, so weights live device-resident where
+    the values the driver sent in ``ctx`` — placed on the stage's mesh by
+    the actor that OWNS the stage, so weights live device-resident where
     they are used and never round-trip through the driver between epochs."""
     bound: Dict[str, Any] = {}
     shard_of = ({} if stage.in_shardings is None
@@ -398,7 +350,6 @@ def _payload_source_spec(name: str, max_fires: int) -> ActorSpec:
 def stage_actor_specs(staged, microbatch_inputs: Sequence[str],
                       num_microbatches: int,
                       regs: Optional[Sequence[int]] = None,
-                      fn_wrap: Optional[Callable[[int, Callable], Callable]] = None,
                       ) -> Tuple[List[ActorSpec], str]:
     """Build the persistent actor graph executing ``staged`` over
     microbatches.
@@ -408,13 +359,10 @@ def stage_actor_specs(staged, microbatch_inputs: Sequence[str],
     ``ctx["data"]`` is the pre-split microbatch payload list (one dict per
     version, :func:`repro.core.lowering.split_microbatches`), and
     ``ctx[f"stage{s}"]`` the stage's non-streamed graph inputs (weights),
-    which the owning worker places on the stage mesh at epoch start.
+    which the owning actor places on the stage mesh at epoch start.
     ``regs[s]`` is stage s's out-register quota (default: 1F1B,
-    ``num_stages - s``). ``fn_wrap(stage_index, fn)`` optionally decorates
-    each stage body (benchmarks use it to emulate device latency).
-
-    Stage ``s`` lives at node ``s + 1`` (the data source at node 0), so the
-    process runtime gives each stage its own worker.
+    ``num_stages - s``). Stage ``s`` lives at node ``s + 1`` (the data
+    source at node 0).
 
     Returns ``(specs, final_stage_name)`` — collect the final stage's
     outputs to reassemble the sinks.
@@ -458,32 +406,12 @@ def stage_actor_specs(staged, microbatch_inputs: Sequence[str],
 
     for s, stage in enumerate(staged.stages):
         fn, on_epoch = make_stage_fn(stage)
-        if fn_wrap is not None:
-            fn = fn_wrap(s, fn)
         specs.append(ActorSpec(
             name=f"stage{s}", fn=fn,
             inputs=("data",) if s == 0 else (f"stage{s-1}",),
             out_regs=regs[s], node=s + 1, thread=0,
             max_fires=num_microbatches, on_epoch=on_epoch))
     return specs, f"stage{S - 1}"
-
-
-class InferSpecBuilder(_SpecBuilderBase):
-    """Picklable builder of the forward-pipeline actor graph."""
-
-    def __init__(self, microbatch_inputs: Sequence[str],
-                 num_microbatches: int, regs=None, fn_wrap=None,
-                 staged=None, recipe=None):
-        super().__init__(staged=staged, recipe=recipe)
-        self.microbatch_inputs = list(microbatch_inputs)
-        self.num_microbatches = num_microbatches
-        self.regs = None if regs is None else list(regs)
-        self.fn_wrap = fn_wrap
-
-    def __call__(self):
-        return stage_actor_specs(self.staged, self.microbatch_inputs,
-                                 self.num_microbatches, regs=self.regs,
-                                 fn_wrap=self.fn_wrap)
 
 
 class ActorPipelineExecutor(_StagedExecutorBase):
@@ -498,17 +426,13 @@ class ActorPipelineExecutor(_StagedExecutorBase):
     """
 
     def __init__(self, staged, microbatch_inputs: Sequence[str],
-                 num_microbatches: int, regs: Optional[Sequence[int]] = None,
-                 fn_wrap: Optional[Callable] = None,
-                 runtime: str = "threads", recipe=None):
-        super().__init__(staged, microbatch_inputs, num_microbatches, regs,
-                         fn_wrap, runtime=runtime, recipe=recipe)
+                 num_microbatches: int, regs: Optional[Sequence[int]] = None):
+        super().__init__(staged, microbatch_inputs, num_microbatches, regs)
         self.staged = staged
 
-    def _make_builder(self):
-        return InferSpecBuilder(self.microbatch_inputs, self.num_microbatches,
-                                regs=self.regs, fn_wrap=self.fn_wrap,
-                                staged=self.staged, recipe=self.recipe)
+    def _specs(self):
+        return stage_actor_specs(self.staged, self.microbatch_inputs,
+                                 self.num_microbatches, regs=self.regs)
 
     def run(self, inputs: Dict[str, Any], timeout: float = 300.0) -> Tuple:
         check_run_inputs(inputs, self.staged.input_names)
@@ -547,10 +471,9 @@ class ActorPipelineExecutor(_StagedExecutorBase):
 #
 # Stage s's actors (f, b, acc, opt, state) all live at node s+1, one worker
 # mailbox — so the stage's params, optimizer state and gradient accumulator
-# are node-local closure state, updated in place by the opt actor and never
+# are stage-local closure state, updated in place by the opt actor and never
 # shipped between steps. "__"-prefixed payload keys (the vjp closure, the
-# grad stream) are same-node contracts: repro.runtime.base.encode_payload
-# strips them at node boundaries.
+# grad stream) are same-stage contracts.
 # ---------------------------------------------------------------------------
 
 _VJP_KEY = "__vjp__"
@@ -579,8 +502,8 @@ def _train_collect_names(tstaged, snapshot: bool = False,
 def train_stage_actor_specs(tstaged, microbatch_inputs: Sequence[str],
                             num_microbatches: int, lr: float = 1e-2,
                             regs: Optional[Sequence[int]] = None,
-                            fn_wrap: Optional[Callable] = None,
-                            optimizer=None, snapshot=None,
+                            optimizer=None,
+                            snapshot_dir: Optional[str] = None,
                             ) -> Tuple[List[ActorSpec], List[str]]:
     """Build the persistent fwd/bwd/opt actor graph for training steps.
 
@@ -598,13 +521,11 @@ def train_stage_actor_specs(tstaged, microbatch_inputs: Sequence[str],
       plain int or as ``{"step": int, "load_state": AdamWState-or-None}``
       after a ``load_state`` restore (the restored moments replace the
       worker-resident state before the epoch's first fire);
-    * ``ctx[f"snap{s}"]`` — with ``snapshot`` set, ``{"step": int,
+    * ``ctx[f"snap{s}"]`` — with ``snapshot_dir`` set, ``{"step": int,
       "write": bool}`` controlling this epoch's checkpoint write.
 
     ``regs[s]`` is forward stage s's out-register quota (default 1F1B,
     ``num_stages - s``); backward/acc/opt actors need no tuning.
-    ``fn_wrap(kind, stage_index, fn)`` with kind in ``{"fwd", "bwd"}``
-    optionally decorates the stage bodies.
 
     The optimizer subsystem (paper §3.3 partial-value + §4.3 actors):
 
@@ -620,7 +541,7 @@ def train_stage_actor_specs(tstaged, microbatch_inputs: Sequence[str],
       consumes — the second register stream. The state lives in the stage's
       worker across steps (initialized on the first step); the updated copy
       also rides the opt actor's output payload so the driver can mirror it.
-    * With ``snapshot`` (a :class:`repro.runtime.snapshot.SnapshotSpec`), a
+    * With ``snapshot_dir`` (a directory), a
       ``snap{s}`` actor per parameterized stage consumes ``opt{s}``'s
       output register — the stream already carrying the post-update params
       and fresh optimizer state — and serializes the stage's slice to disk
@@ -763,9 +684,8 @@ def train_stage_actor_specs(tstaged, microbatch_inputs: Sequence[str],
             out = {"cots": out_cots, _GRADS_KEY: grads}
             if stage.index == loss_stage:
                 # reduce to a scalar HERE, on the stage's own mesh: summing
-                # driver-side would re-partition the reduction after the
-                # tensor crossed a process boundary as a gathered numpy
-                # array, changing the f32 rounding vs the threaded path
+                # driver-side over a gathered host array would re-partition
+                # the reduction and change its f32 rounding
                 out["loss"] = jnp.sum(f_payload[loss_name])
             return out
         return run_bwd, on_epoch
@@ -937,7 +857,7 @@ def train_stage_actor_specs(tstaged, microbatch_inputs: Sequence[str],
                     # on the opt actor's node, so it sees the raw stream);
                     # load_snapshot gathers them partition-agnostically
                     write_stage_snapshot(
-                        snapshot.dir, cell["step"], stage.index,
+                        snapshot_dir, cell["step"], stage.index,
                         dict(zero_meta["masters"]),
                         opt_state=zero_meta["state"],
                         zero={"dp": zero_meta["dp"],
@@ -945,7 +865,7 @@ def train_stage_actor_specs(tstaged, microbatch_inputs: Sequence[str],
                                          zero_meta["shapes"].items()}})
                 else:
                     write_stage_snapshot(
-                        snapshot.dir, cell["step"], stage.index,
+                        snapshot_dir, cell["step"], stage.index,
                         {n: opt_payload["params"][n]
                          for n in stage.param_names},
                         opt_state=opt_payload.get("state"))
@@ -953,14 +873,12 @@ def train_stage_actor_specs(tstaged, microbatch_inputs: Sequence[str],
                     "written": write}
         return run_snap, on_epoch
 
-    collect = _train_collect_names(tstaged, snapshot=snapshot is not None,
+    collect = _train_collect_names(tstaged,
+                                   snapshot=snapshot_dir is not None,
                                    dynamic=dynamic)
     for s, stage in enumerate(tstaged.stages):
         fwd_fn, bound, raw_cell, fwd_on_epoch = make_fwd_fn(stage)
         bwd_fn, bwd_on_epoch = make_bwd_fn(stage)
-        if fn_wrap is not None:
-            fwd_fn = fn_wrap("fwd", s, fwd_fn)
-            bwd_fn = fn_wrap("bwd", s, bwd_fn)
         specs.append(ActorSpec(
             name=f"f{s}", fn=fwd_fn,
             inputs=("data",) if s == 0 else (f"f{s-1}",),
@@ -1004,7 +922,7 @@ def train_stage_actor_specs(tstaged, microbatch_inputs: Sequence[str],
                 name=f"opt{s}", fn=opt_fn,
                 inputs=opt_inputs, out_regs=1, node=s + 1, thread=0,
                 max_fires=1, on_epoch=opt_on_epoch))
-            if snapshot is not None:
+            if snapshot_dir is not None:
                 # async checkpointing as one more register-stream consumer:
                 # snap{s} subscribes to opt{s}'s output (post-update params
                 # + fresh optimizer state) on the stage node's thread 1 —
@@ -1063,41 +981,15 @@ def train_stage_actor_specs(tstaged, microbatch_inputs: Sequence[str],
     return specs, collect
 
 
-class TrainSpecBuilder(_SpecBuilderBase):
-    """Picklable builder of the fwd/bwd/opt training actor graph."""
-
-    def __init__(self, microbatch_inputs: Sequence[str],
-                 num_microbatches: int, lr: float = 1e-2, regs=None,
-                 fn_wrap=None, optimizer=None, staged=None, recipe=None,
-                 snapshot=None):
-        super().__init__(staged=staged, recipe=recipe)
-        self.microbatch_inputs = list(microbatch_inputs)
-        self.num_microbatches = num_microbatches
-        self.lr = lr
-        self.regs = None if regs is None else list(regs)
-        self.fn_wrap = fn_wrap
-        self.optimizer = optimizer
-        self.snapshot = snapshot      # SnapshotSpec (plain data — picklable)
-
-    def __call__(self):
-        return train_stage_actor_specs(self.staged, self.microbatch_inputs,
-                                       self.num_microbatches, lr=self.lr,
-                                       regs=self.regs, fn_wrap=self.fn_wrap,
-                                       optimizer=self.optimizer,
-                                       snapshot=self.snapshot)
-
-
 class TrainPipelineExecutor(_StagedExecutorBase):
     """Run a :class:`TrainStagedProgram` as a 1F1B training pipeline.
 
     The fwd/bwd/opt actor graph is built once; each :meth:`step` is one
     epoch over it. Per-stage persistent state — placed params, the AdamW
     state, the fp32 gradient accumulator — lives in the stage's actor
-    closures, resident in whichever worker owns the stage (its OS thread
-    under ``runtime="threads"``, its worker process under
-    ``runtime="processes"``): the opt actor updates the stage's bound
-    params and optimizer state in place, so nothing round-trips through the
-    driver between steps. The driver keeps a mirror (``params``,
+    closures: the opt actor updates the stage's bound params and optimizer
+    state in place, so nothing round-trips through the driver between
+    steps. The driver keeps a mirror (``params``,
     ``opt_states``) refreshed from the opt actors' collected outputs, and
     returns ``(loss, grads, params)`` bit-identical to the monolithic
     reference (:func:`repro.train.steps.make_graph_train_step` with the
@@ -1117,14 +1009,11 @@ class TrainPipelineExecutor(_StagedExecutorBase):
     def __init__(self, tstaged, params: Dict[str, Any],
                  microbatch_inputs: Sequence[str], num_microbatches: int,
                  lr: float = 1e-2, regs: Optional[Sequence[int]] = None,
-                 fn_wrap: Optional[Callable] = None, optimizer=None,
-                 runtime: str = "threads", recipe=None,
-                 snapshot_dir: Optional[str] = None, snapshot_every: int = 1,
-                 faults=None):
+                 optimizer=None, snapshot_dir: Optional[str] = None,
+                 snapshot_every: int = 1, faults=None):
         from repro.core.lowering import OptimizerSpec
 
         super().__init__(tstaged, microbatch_inputs, num_microbatches, regs,
-                         fn_wrap, runtime=runtime, recipe=recipe,
                          faults=faults)
         self.tstaged = tstaged
         self.lr = lr
@@ -1134,10 +1023,8 @@ class TrainPipelineExecutor(_StagedExecutorBase):
         if snapshot_every < 1:
             raise ValueError(
                 f"snapshot_every must be >= 1, got {snapshot_every}")
-        self._snapshot = None
-        if snapshot_dir is not None:
-            from repro.runtime.snapshot import SnapshotSpec
-            self._snapshot = SnapshotSpec(str(snapshot_dir))
+        self._snapshot_dir = (None if snapshot_dir is None
+                              else str(snapshot_dir))
         self.snapshot_every = snapshot_every
         self._state_dirty = False
         self.params: Dict[str, Any] = {}
@@ -1171,13 +1058,12 @@ class TrainPipelineExecutor(_StagedExecutorBase):
             p = self.optimizer.shard_masters(p)
         return self.optimizer.init_state(p)
 
-    def _make_builder(self):
-        return TrainSpecBuilder(self.microbatch_inputs, self.num_microbatches,
-                                lr=self.lr, regs=self.regs,
-                                fn_wrap=self.fn_wrap,
-                                optimizer=self.optimizer,
-                                staged=self.tstaged, recipe=self.recipe,
-                                snapshot=self._snapshot)
+    def _specs(self):
+        return train_stage_actor_specs(self.tstaged, self.microbatch_inputs,
+                                       self.num_microbatches, lr=self.lr,
+                                       regs=self.regs,
+                                       optimizer=self.optimizer,
+                                       snapshot_dir=self._snapshot_dir)
 
     def load_params(self, params: Dict[str, Any]) -> None:
         """Replace the executor-owned params (e.g. a checkpoint restore).
@@ -1261,7 +1147,7 @@ class TrainPipelineExecutor(_StagedExecutorBase):
             "data": split_microbatches(data_inputs, self.microbatch_inputs,
                                        self.num_microbatches)}
         snap_step = self.step_count + 1   # the state after THIS step lands
-        write = (self._snapshot is not None
+        write = (self._snapshot_dir is not None
                  and snap_step % self.snapshot_every == 0)
         if self._scaling:
             # seed the loss stage's backward with the scale, the acc actors
@@ -1289,7 +1175,7 @@ class TrainPipelineExecutor(_StagedExecutorBase):
                         "load_state": self.opt_states[st.index]}
                 else:
                     ctx[f"opt{st.index}"] = self.step_count
-                if self._snapshot is not None:
+                if self._snapshot_dir is not None:
                     ctx[f"snap{st.index}"] = {"step": snap_step,
                                               "write": write}
         outs = self._run_rt(ctx, None, timeout)
@@ -1297,7 +1183,7 @@ class TrainPipelineExecutor(_StagedExecutorBase):
         self._state_dirty = False
 
         collect = _train_collect_names(
-            self.tstaged, snapshot=self._snapshot is not None,
+            self.tstaged, snapshot=self._snapshot_dir is not None,
             dynamic=self.optimizer.dynamic_scaling)
         # the loss-bearing backward actor fires in version order in one
         # worker, so the collected loss stream is microbatch-ordered
@@ -1367,7 +1253,7 @@ class TrainPipelineExecutor(_StagedExecutorBase):
             # the scale to RESUME with (already advanced past this step)
             meta["loss_scale"] = float(self.loss_scale)
             meta["scale_good_steps"] = int(self.scale_good_steps)
-        write_manifest(self._snapshot.dir, snap_step, receipts, meta=meta)
+        write_manifest(self._snapshot_dir, snap_step, receipts, meta=meta)
 
     def opt_state_bytes(self) -> Dict[int, int]:
         """Per-stage bytes of worker-resident optimizer-held fp32 state.
@@ -1410,10 +1296,10 @@ class TrainPipelineExecutor(_StagedExecutorBase):
 # scatters its caches into the group cache. The stage's KV/SSM caches never
 # ride the payload — they are persistent stage-local state in the stage
 # actor's closure (the same pattern as the AdamW state stream in training),
-# resident in whichever worker owns the stage, so the only tensors crossing
-# stages are the (B, 1, d) hidden and the final logits. Overlap across
-# groups emerges from the stage out-register quotas alone (§4.3): while
-# stage 1 decodes group 0, stage 0 already decodes group 1.
+# so the only tensors crossing stages are the (B, 1, d) hidden and the final
+# logits. Overlap across groups emerges from the stage out-register quotas
+# alone (§4.3): while stage 1 decodes group 0, stage 0 already decodes
+# group 1.
 # ---------------------------------------------------------------------------
 
 @dataclasses.dataclass
@@ -1644,7 +1530,8 @@ def serve_stage_actor_specs(sstaged, regs: Optional[Sequence[int]] = None,
     set to the round's work count) and one ``stage{s}`` actor per model
     shard at node ``s + 1``, each owning its per-group KV/SSM caches as
     closure state — allocated lazily the first time a group reaches the
-    stage, resident in the owning worker across rounds.
+    stage, resident in the stage actor across rounds. ``fn_wrap(s, fn)``
+    optionally wraps stage ``s``'s body.
 
     Returns ``(specs, final_stage_name)``."""
     S = sstaged.num_stages
@@ -1699,39 +1586,19 @@ def serve_stage_actor_specs(sstaged, regs: Optional[Sequence[int]] = None,
     return specs, f"stage{S - 1}"
 
 
-class ServeSpecBuilder(_SpecBuilderBase):
-    """Picklable builder of the continuous-batching serve actor graph.
-    ``cache_spec``/``sampling`` are frozen dataclasses, so the paged-pool
-    geometry and the sampler seed ride the pickle into process workers."""
-
-    def __init__(self, regs=None, fn_wrap=None, staged=None, recipe=None,
-                 cache_spec=None, sampling=None):
-        super().__init__(staged=staged, recipe=recipe)
-        self.regs = None if regs is None else list(regs)
-        self.fn_wrap = fn_wrap
-        self.cache_spec = cache_spec
-        self.sampling = sampling
-
-    def __call__(self):
-        return serve_stage_actor_specs(self.staged, regs=self.regs,
-                                       fn_wrap=self.fn_wrap,
-                                       cache_spec=self.cache_spec,
-                                       sampling=self.sampling)
-
-
 class ServePipelineExecutor(_StagedExecutorBase):
     """Run a :class:`repro.core.lowering.ServeStagedProgram` as a pipelined
     continuous-batching decode engine.
 
     The actor graph persists across rounds; per-stage, per-group caches are
-    closure state inside each ``stage{s}`` actor, resident in the worker
-    that owns the stage (under ``runtime="processes"``, a real process —
-    the caches never cross a process boundary). Each :meth:`run_round` is
+    closure state inside each ``stage{s}`` actor. Each :meth:`run_round` is
     one epoch: the round's work items travel in ``ctx``, the per-actor fire
     bound is the round's work count, and the last stage's logits are
     collected in emission order. ``regs[s]`` is stage s's out-register
     quota (default ``max(1, S - s)``, the forward-pipeline schedule);
     quota back-pressure alone bounds how many groups are in flight.
+    ``fn_wrap(stage_index, fn)`` optionally wraps each stage body (a hook
+    on every fire; the benchmark's decode recorder uses it).
 
     Instrumentation mirrors the other executors (``last_makespan``,
     ``last_history``, ``last_peak_regs``, ``last_edge_bytes``) plus
@@ -1740,20 +1607,19 @@ class ServePipelineExecutor(_StagedExecutorBase):
 
     def __init__(self, sstaged, regs: Optional[Sequence[int]] = None,
                  fn_wrap: Optional[Callable] = None,
-                 runtime: str = "threads", recipe=None,
                  cache_spec=None, sampling=None):
-        super().__init__(sstaged, [], 1, regs, fn_wrap,
-                         runtime=runtime, recipe=recipe)
+        super().__init__(sstaged, [], 1, regs)
         self.sstaged = sstaged
+        self.fn_wrap = fn_wrap
         self.cache_spec = cache_spec
         self.sampling = sampling
         self.rounds = 0
 
-    def _make_builder(self):
-        return ServeSpecBuilder(regs=self.regs, fn_wrap=self.fn_wrap,
-                                staged=self.sstaged, recipe=self.recipe,
-                                cache_spec=self.cache_spec,
-                                sampling=self.sampling)
+    def _specs(self):
+        return serve_stage_actor_specs(self.sstaged, regs=self.regs,
+                                       fn_wrap=self.fn_wrap,
+                                       cache_spec=self.cache_spec,
+                                       sampling=self.sampling)
 
     def run_round(self, work: Sequence, timeout: float = 300.0) -> List:
         """Stream ``work`` (PrefillWork/PrefillChunkWork/DecodeWork items)

@@ -7,8 +7,7 @@ further: a ``snap{s}`` actor per parameterized stage subscribes to
 post-update params *and* the fresh ``AdamWState`` — and serializes it to
 disk from its **own** mailbox thread (``thread=1`` on the stage's node),
 with its own out-register quota. The 1F1B schedule on thread 0 never waits
-on serialization; under ``runtime="processes"`` each stage writes from its
-own worker, in parallel across stages.
+on serialization, and the stages write in parallel.
 
 On-disk layout (all under the session's ``snapshot_dir``)::
 
@@ -35,7 +34,6 @@ restores onto a 2-stage (or monolithic) session.
 """
 from __future__ import annotations
 
-import dataclasses
 import json
 import os
 import pathlib
@@ -44,15 +42,6 @@ from typing import Any, Dict, List, Optional, Tuple
 
 MANIFEST_NAME = "MANIFEST.json"
 _STEP_DIR_RE = re.compile(r"^step-(\d+)$")
-
-
-@dataclasses.dataclass(frozen=True)
-class SnapshotSpec:
-    """Picklable snapshot config carried by the train spec builders into
-    worker processes (the directory is the only cross-process field; the
-    per-epoch step/write decision travels through ``ctx``)."""
-
-    dir: str
 
 
 def step_dir(root: str, step: int) -> pathlib.Path:
@@ -142,6 +131,8 @@ def _load_stage(d: pathlib.Path):
     operation, so the round-trip is bitwise. The caller never sees shards."""
     import numpy as np
 
+    from repro.train.checkpoint import load_leaf
+
     manifest = json.loads((d / "manifest.json").read_text())
     meta = manifest.get("meta") or {}
     names = meta.get("param_names", [])
@@ -152,7 +143,7 @@ def _load_stage(d: pathlib.Path):
     def load(key, shape=None):
         if key not in leaves:
             raise KeyError(f"stage snapshot {d} missing leaf {key!r}")
-        arr = np.load(d / leaves[key]["file"])
+        arr = load_leaf(d / leaves[key]["file"], leaves[key]["dtype"])
         if shape is not None:
             n = int(np.prod(shape)) if shape else 1
             arr = arr.reshape(-1)[:n].reshape(shape)

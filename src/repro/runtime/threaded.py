@@ -1,4 +1,4 @@
-"""Real threaded actor runtime — actors on OS threads with FIFO mailboxes.
+"""The actor runtime — actors on OS threads with FIFO mailboxes.
 
 This is the paper's Fig 7 implementation for the *host side* of the JAX
 program: data loading, preprocessing, host-to-device staging and step issue
@@ -9,24 +9,23 @@ unboundedly (§4.3) — this is what `repro.data.pipeline` builds on.
 
 Two pieces live here:
 
-* :class:`_LocalEngine` — drives the *local subset* of an actor graph on OS
-  threads. With every key local it IS the threaded runtime's engine; each
-  :class:`repro.runtime.process.ProcessRuntime` worker runs one over its own
-  node's keys, with cross-node messages diverted through ``send_remote``.
-* :class:`ThreadedRuntime` — the :class:`repro.runtime.base.Runtime`
-  implementation executors use in-process. Persistent: one instance runs
-  many epochs (steps/rounds); actors reset at the *start* of each run so
-  their counters stay inspectable afterwards.
+* :class:`_Engine` — drives an actor graph on OS threads, one worker
+  thread and mailbox per ``(node, thread)`` key.
+* :class:`ThreadedRuntime` — what the executors run on. Persistent: one
+  instance runs many epochs (steps/rounds) over the same actor graph;
+  actors reset at the *start* of each run so their counters stay
+  inspectable afterwards. Per-epoch inputs arrive through ``ctx``
+  (``{actor name: value}``, applied by each actor's ``ActorSpec.on_epoch``
+  hook before any fire) and per-epoch fire bounds through ``fires``
+  (``{actor name: count}``, overriding ``ActorSpec.max_fires``).
 
-Completion is event-driven, not polled. Each engine keeps two lock-protected
-counters: ``pending`` (remaining fires of local bounded actors) and ``live``
-(local out-register instances not yet fully acked). Both are updated
-*before* any ack/req from a fire is posted, so "both zero" (quiescence) can
-never be observed while a local actor still owes the graph a message: an
-unsent ack means the producer's register is still refcounted, which keeps
-the producer's ``live`` non-zero. When every key is local, quiescence is
-exactly completion; across processes it feeds the termination protocol in
-:mod:`repro.runtime.process`.
+Completion is event-driven, not polled. The engine keeps two lock-protected
+counters: ``pending`` (remaining fires of bounded actors) and ``live``
+(out-register instances not yet fully acked). Both are updated *before* any
+ack/req from a fire is posted, so "both zero" (quiescence) can never be
+observed while an actor still owes the graph a message: an unsent ack means
+the producer's register is still refcounted, which keeps the producer's
+``live`` non-zero. Quiescence is exactly completion.
 
 Each epoch's start and end (``runtime.epoch_start``, ``runtime.epoch_end``)
 and every fire (``actor.fire``, with ``actor``, ``epoch`` and ``fire``, the
@@ -43,56 +42,46 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 from repro.runtime.actor import Actor, ActorSpec, build_actors
-from repro.runtime.base import Runtime, _check_epoch_names
 from repro.runtime.messages import Req, node_of, thread_of
 
 
-def _no_remote(msg) -> None:
-    raise RuntimeError(
-        f"message for non-local actor {msg.dst:#x} but no remote transport "
-        "is attached (send_remote hook unset)")
+def _check_epoch_names(specs, ctx, fires) -> None:
+    known = {s.name for s in specs}
+    for what, d in (("ctx", ctx), ("fires", fires)):
+        for name in (d or {}):
+            if name not in known:
+                raise ValueError(
+                    f"{what} names unknown actor {name!r}; "
+                    f"actors: {sorted(known)}")
 
 
-class _LocalEngine:
-    """Drive the local (node, thread) keys of an actor graph on OS threads.
+class _Engine:
+    """Drive the (node, thread) keys of an actor graph on OS threads.
 
-    All actors are *built* (IDs and consumer wiring need the whole graph)
-    but only those on ``local_keys`` are run; a message addressed off-node
-    goes through the ``send_remote`` hook. Owners attach:
+    Owners attach:
 
-    * ``send_remote(msg)`` — deliver a Req/Ack to a non-local key
     * ``on_output(name, value, version)`` — a collected actor emitted
-    * ``on_quiescence(flag)`` — local quiescence changed (called under the
+    * ``on_quiescence(flag)`` — quiescence changed (called under the
       counter lock, so reports are emitted in transition order)
     * ``on_error(exc, key)`` — a worker thread raised
     """
 
-    def __init__(self, specs: Sequence[ActorSpec],
-                 local_keys: Optional[Sequence[Tuple[int, int]]] = None):
+    def __init__(self, specs: Sequence[ActorSpec]):
         self.specs = list(specs)
         self.by_name, self.by_id = build_actors(self.specs)
-        all_keys = sorted({(s.node, s.thread) for s in self.specs})
-        if local_keys is None:
-            self.local_keys = all_keys
-        else:
-            wanted = set(local_keys)
-            self.local_keys = [k for k in all_keys if k in wanted]
-        local = set(self.local_keys)
-        self.local_actors: List[Actor] = [
-            a for a in self.by_name.values()
-            if (a.spec.node, a.spec.thread) in local]
+        self.keys = sorted({(s.node, s.thread) for s in self.specs})
+        self.actors: List[Actor] = list(self.by_name.values())
         self.actors_on: Dict[Tuple[int, int], List[Actor]] = \
             collections.defaultdict(list)
-        for a in self.local_actors:
+        for a in self.actors:
             self.actors_on[(a.spec.node, a.spec.thread)].append(a)
         # hooks
-        self.send_remote: Callable[[Any], None] = _no_remote
         self.on_output: Optional[Callable[[str, Any, int], None]] = None
         self.on_quiescence: Optional[Callable[[bool], None]] = None
         self.on_error: Optional[Callable[[BaseException, Tuple[int, int]], None]] = None
         self.collect_names: Set[str] = set()
         # optional chaos layer (repro.runtime.chaos.FaultInjector): consulted
-        # before every local fire and for every outgoing message
+        # before every fire and for every outgoing message
         self.fault_injector = None
         # optional repro.analysis.trace.TraceRecorder: records every Req
         # delivery (version + what the resequencer released) so the static
@@ -112,7 +101,7 @@ class _LocalEngine:
     # -- epoch lifecycle ---------------------------------------------------------
     def start_epoch(self, ctx: Optional[Dict[str, Any]] = None,
                     fires: Optional[Dict[str, int]] = None) -> None:
-        """Reset local actors and launch one worker thread per local key.
+        """Reset the actors and launch one worker thread per key.
 
         ``fires`` overrides per-actor fire bounds for this epoch only;
         ``ctx`` is routed to each actor's ``on_epoch`` hook (hooks with no
@@ -128,18 +117,18 @@ class _LocalEngine:
                 # checks canonical order per (epoch, consumer, channel)
                 self.trace_recorder.current_epoch = self._epoch
             self._stopping = False
-            for a in self.local_actors:
+            for a in self.actors:
                 a.reset(max_fires=fires.get(a.spec.name))
             # hooks run after every reset: an on_epoch that seeds an upstream
             # cell must not race a half-reset consumer
-            for a in self.local_actors:
+            for a in self.actors:
                 if a.spec.on_epoch is not None:
                     a.spec.on_epoch(ctx.get(a.spec.name))
-            pending = sum(a.max_fires - a.fired for a in self.local_actors
+            pending = sum(a.max_fires - a.fired for a in self.actors
                           if a.max_fires is not None)
             # fresh mailboxes per epoch: anything a previous (timed-out) epoch
             # left queued is unreachable garbage, not a poisoned message
-            self._mailboxes = {k: queue.Queue() for k in self.local_keys}
+            self._mailboxes = {k: queue.Queue() for k in self.keys}
             self._t0 = time.perf_counter()
             with self._lock:
                 self._pending = pending
@@ -149,7 +138,7 @@ class _LocalEngine:
                     self.on_quiescence(self._quiescent)
             self._threads = []
             epoch = self._epoch
-            for key in self.local_keys:
+            for key in self.keys:
                 t = threading.Thread(target=self._worker, args=(key, epoch),
                                      daemon=True)
                 t.start()
@@ -165,22 +154,18 @@ class _LocalEngine:
             t.join(timeout=timeout)
 
     def snapshot(self):
-        """(history, peak_regs, edge_bytes, fired) of the local actors."""
-        hist = {a.spec.name: list(a.history) for a in self.local_actors}
-        peaks = {a.spec.name: a.peak_regs_in_use for a in self.local_actors}
-        edges = {(a.spec.name, cname): n for a in self.local_actors
+        """(history, peak_regs, edge_bytes, fired) of the actors."""
+        hist = {a.spec.name: list(a.history) for a in self.actors}
+        peaks = {a.spec.name: a.peak_regs_in_use for a in self.actors}
+        edges = {(a.spec.name, cname): n for a in self.actors
                  for cname, n in a.edge_bytes.items()}
-        fired = {a.spec.name: a.fired for a in self.local_actors}
+        fired = {a.spec.name: a.fired for a in self.actors}
         return hist, peaks, edges, fired
 
     # -- message routing ---------------------------------------------------------
     def post(self, msg) -> None:
-        """Route an *originating* message (the sending engine's side).
-
-        With a fault injector attached the message may be delayed,
-        duplicated or dropped here; :meth:`deliver` is the fault-free path
-        used for messages arriving from another process (already routed at
-        their sender)."""
+        """Route a message to its consumer's mailbox. With a fault injector
+        attached the message may be delayed, duplicated or dropped here."""
         inj = self.fault_injector
         if inj is None:
             self.deliver(msg)
@@ -198,11 +183,7 @@ class _LocalEngine:
                 self.deliver(m)
 
     def deliver(self, msg) -> None:
-        box = self._mailboxes.get((node_of(msg.dst), thread_of(msg.dst)))
-        if box is not None:
-            box.put(msg)
-        else:
-            self.send_remote(msg)
+        self._mailboxes[(node_of(msg.dst), thread_of(msg.dst))].put(msg)
 
     def _deliver_late(self, msg, epoch: int, boxes) -> None:
         """Timer callback for a delayed message. A pending delayed Req/Ack
@@ -212,11 +193,7 @@ class _LocalEngine:
         stale epoch's boxes are unreachable garbage, never poison."""
         if self._epoch != epoch or self._stopping:
             return
-        box = boxes.get((node_of(msg.dst), thread_of(msg.dst)))
-        if box is not None:
-            box.put(msg)
-        else:
-            self.send_remote(msg)
+        boxes[(node_of(msg.dst), thread_of(msg.dst))].put(msg)
 
     # -- counters ----------------------------------------------------------------
     def _bump(self, dpending: int, dlive: int) -> None:
@@ -291,8 +268,7 @@ class _LocalEngine:
                 while (actor.ready() and not self._stopping
                        and self._epoch == epoch):
                     if self.fault_injector is not None:
-                        # may raise WorkerKilled (threads) or hard-exit the
-                        # process (a KillWorker fault)
+                        # raises WorkerKilled at a KillWorker fault
                         self.fault_injector.before_fire(actor.spec.name)
                     start = time.perf_counter() - self._t0
                     with TraceAnnotation("actor.fire", actor=actor.spec.name,
@@ -323,7 +299,7 @@ class _LocalEngine:
                     progressed = True
 
 
-class ThreadedRuntime(Runtime):
+class ThreadedRuntime:
     """Drive a graph of :class:`ActorSpec`s on OS threads, in-process.
 
     ``collect_outputs_of`` names the actor(s) whose outputs :meth:`run`
@@ -334,12 +310,22 @@ class ThreadedRuntime(Runtime):
     Persistent: one instance serves many :meth:`run` epochs. Actors reset at
     the *start* of the next run, so ``by_name`` counters (fired, out_counter,
     peak_regs_in_use) remain inspectable after a run — the zero-consumer and
-    data-pipeline tests rely on that.
+    data-pipeline tests rely on that. After each run the epoch's
+    instrumentation is in ``last_history`` (per-actor action intervals),
+    ``last_peak_regs`` (per-actor peak out-registers in use),
+    ``last_edge_bytes`` (``{(producer, consumer): bytes}``) and
+    ``last_fired`` (per-actor fire counts).
+
+    ``faults`` is an optional :class:`repro.runtime.chaos.FaultPlan`
+    injected deterministically (kill-at-fire, delayed/duplicated Reqs,
+    dropped Acks); ``trace`` an optional
+    :class:`repro.analysis.trace.TraceRecorder` capturing every Req
+    delivery (and applied fault) for the trace sanitizer.
     """
 
     def __init__(self, specs: Sequence[ActorSpec],
                  collect_outputs_of=None, faults=None, trace=None):
-        self._engine = _LocalEngine(specs)
+        self._engine = _Engine(specs)
         if faults is not None:
             from repro.runtime.chaos import FaultInjector
             self._engine.fault_injector = FaultInjector(faults)
@@ -431,7 +417,7 @@ class ThreadedRuntime(Runtime):
                              f"(node={key[0]}, thread={key[1]})")
             # re-raise with the worker thread's original traceback attached
             raise exc
-        bounded = [a for a in self._engine.local_actors
+        bounded = [a for a in self._engine.actors
                    if a.max_fires is not None]
         if not all(a.exhausted for a in bounded):
             raise TimeoutError(
@@ -443,3 +429,10 @@ class ThreadedRuntime(Runtime):
     def close(self) -> None:
         self._engine.stop_workers()
         self._engine.join_workers(0.5)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.close()
+        return False

@@ -12,8 +12,8 @@ reservation with the paper's register discipline applied to serving state:
   are jitted fixed-shape programs, shared-prefix pages are refcounted.
 * :mod:`repro.serve.sampler` — temperature/top-k/top-p sampling as an
   actor-borne RNG register stream (keys split per sampled work item), so
-  sampled decode is reproducible and identical across
-  actors/monolithic x threads/processes.
+  sampled decode is reproducible and identical across the actors and
+  monolithic backends.
 * :mod:`repro.serve.admission` — the continuous-batching admission
   scheduler, including chunked prefill: long prompts become bounded work
   items interleaved with decode rounds.

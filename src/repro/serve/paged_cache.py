@@ -49,8 +49,7 @@ POSITIONAL_KEYS = frozenset({"k", "v", "c", "kpe", "pos"})
 
 @dataclasses.dataclass(frozen=True)
 class PagedCacheSpec:
-    """The paged-pool geometry, picklable so it rides spec builders into
-    ``runtime="processes"`` workers."""
+    """The paged-pool geometry."""
 
     page_len: int
     num_pages: int

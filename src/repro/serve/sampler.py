@@ -6,11 +6,10 @@ inline engine under ``backend="monolithic"``) — the same persistent-state
 pattern as the AdamW moments in training pipelines. Every work item that
 produces tokens (a prefill, a decode round, the *final* chunk of a chunked
 prefill) splits the stream exactly once, and slots inside the item fold
-their slot index into the subkey. Because every backend/runtime drives the
+their slot index into the subkey. Because both backends drive the
 identical round structure and the last stage fires in FIFO submission
-order, a fixed seed yields token-identical streams across
-actors/monolithic x threads/processes — sampled decode is as reproducible
-as greedy.
+order, a fixed seed yields token-identical streams across actors and
+monolithic — sampled decode is as reproducible as greedy.
 
 ``temperature == 0`` delegates to the existing
 :func:`repro.train.steps.greedy_from_logits` verbatim, so greedy sampling
@@ -84,8 +83,8 @@ def _build_sample_fn(spec: SamplingSpec, vocab_size: int):
 
 class SamplerStream:
     """The persistent sampler register: a key split once per sampled work
-    item. Lives in the last stage actor's closure (resident in that stage's
-    worker under ``runtime="processes"``) or in the inline engine."""
+    item. Lives in the last stage actor's closure or in the inline
+    engine."""
 
     def __init__(self, spec: SamplingSpec, vocab_size: int):
         import jax

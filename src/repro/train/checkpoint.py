@@ -50,6 +50,16 @@ def save_checkpoint(ckpt_dir: str, tree: Any, step: int = 0,
         {"leaves": leaves, "step": step, "meta": meta or {}}, indent=2))
 
 
+def load_leaf(path, dtype: str) -> np.ndarray:
+    """Load one leaf file with the dtype its manifest recorded. ``np.save``
+    writes an ml_dtypes array (``bfloat16``) as raw void records, which
+    ``np.load`` returns as ``|V2``; viewing them back is bitwise."""
+    arr = np.load(path)
+    if arr.dtype.kind == "V":
+        arr = arr.view(np.dtype(dtype))
+    return arr
+
+
 def load_checkpoint(ckpt_dir: str, like: Any, shardings: Any = None):
     """Restore into the structure of ``like`` (a pytree of arrays or
     ShapeDtypeStructs). Returns (tree, step)."""
@@ -63,7 +73,8 @@ def load_checkpoint(ckpt_dir: str, like: Any, shardings: Any = None):
         key = _key_str(path)
         if key not in manifest["leaves"]:
             raise KeyError(f"checkpoint missing leaf {key}")
-        arr = np.load(d / manifest["leaves"][key]["file"])
+        entry = manifest["leaves"][key]
+        arr = load_leaf(d / entry["file"], entry["dtype"])
         if tuple(arr.shape) != tuple(leaf.shape):
             raise ValueError(f"{key}: shape {arr.shape} != {leaf.shape}")
         if sh is not None:

@@ -453,7 +453,7 @@ def make_pipeline_train_step(graph, init_params: Dict[str, Any],
                              num_stages: Optional[int] = None, mesh=None,
                              stage_meshes=None, lr: float = 1e-2,
                              regs=None, loss=None, graph_plan=None,
-                             fn_wrap=None, optimizer=None):
+                             optimizer=None):
     """DEPRECATED: use ``repro.api.compile(graph, mode="train",
     backend="actors", ...)`` — this shim compiles a session and returns its
     backing :class:`repro.runtime.pipeline.TrainPipelineExecutor` to
@@ -483,6 +483,6 @@ def make_pipeline_train_step(graph, init_params: Dict[str, Any],
         # preserve this shim's historical default schedule (1F1B) rather
         # than compile()'s simulated register planning
         regs=regs if regs is not None else "1f1b",
-        loss=loss, mesh=mesh, stage_meshes=stage_meshes, fn_wrap=fn_wrap,
+        loss=loss, mesh=mesh, stage_meshes=stage_meshes,
         optimizer=optimizer)
     return sess.executor

@@ -7,10 +7,6 @@ the paper's MPMD placement) that is killed by fault injection mid-training
 must resume — from its own per-stage snapshot files — onto a *2-stage*
 partition over different device groups, and finish the trajectory the
 uninterrupted reference follows.
-
-Kill mechanics are the threads runtime here (the processes runtime is
-covered by tests/test_fault_tolerance.py; worker processes cannot share
-the forced 8-device host platform of this suite cleanly).
 """
 import os
 

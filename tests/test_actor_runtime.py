@@ -256,6 +256,181 @@ class TestThreadedRuntime:
             gate.set()
 
 
+def _chain_specs(n=4, mid=None):
+    """src(node 0) -> mid(node 1) -> sink(node 2): base+v -> x+1 -> x*2,
+    each payload a float32 vector of 8, so every edge carries bytes;
+    ``src`` takes a per-epoch base through ``ctx``; ``mid`` replaces the
+    middle actor's body."""
+    base = [0.0]
+
+    def set_base(v):
+        if v is not None:
+            base[0] = float(v)
+
+    return [
+        ActorSpec("src", lambda version: {"x": np.full((8,), base[0] + version,
+                                                       np.float32)},
+                  (), out_regs=2, max_fires=n, node=0, thread=0,
+                  wants_version=True, on_epoch=set_base),
+        ActorSpec("mid", mid or (lambda p: {"x": p["x"] + 1.0}), ("src",),
+                  out_regs=2, node=1, thread=0),
+        ActorSpec("sink", lambda p: p["x"] * 2.0, ("mid",), out_regs=2,
+                  node=2, thread=0),
+    ]
+
+
+def _tiny_graph():
+    from repro.core.graph import LogicalGraph
+    from repro.core.placement import Placement
+
+    g = LogicalGraph(Placement(("d",), (1,), device_kind="cpu"))
+    h = g.input("x", (4, 8))
+    for i in range(2):
+        h = g.matmul(h, g.input(f"w{i}", (8, 8)), name=f"mm{i}")
+    return g
+
+
+def _tiny_inputs():
+    rng = np.random.default_rng(0)
+    return {n: rng.normal(size=s).astype(np.float32)
+            for n, s in (("x", (4, 8)), ("w0", (8, 8)), ("w1", (8, 8)))}
+
+
+class _Bare:
+    """A bare runtime over the 3-node chain, driven like a session."""
+
+    def __init__(self, mid=None):
+        self.rt = ThreadedRuntime(_chain_specs(mid=mid),
+                                  collect_outputs_of="sink")
+
+    def runtime(self):
+        return self.rt
+
+    def drive(self):
+        return self.rt.run(timeout=30.0)
+
+    def close(self):
+        self.rt.close()
+
+
+class _Infer:
+    def __init__(self):
+        from repro import api
+
+        self.sess = api.compile(_tiny_graph(), mode="infer", stages=2)
+
+    def runtime(self):
+        return self.sess.executor.runtime
+
+    def drive(self):
+        return self.sess.run(**_tiny_inputs())
+
+    def close(self):
+        self.sess.close()
+
+
+class _Serve:
+    def __init__(self):
+        from repro import api
+        from repro.configs.registry import get_config
+
+        cfg = get_config("qwen3-1.7b").reduced()
+        self.vocab = cfg.vocab_size
+        self.sess = api.compile(cfg, mode="serve", stages=2, num_groups=1,
+                                group_size=2, max_prompt_len=8,
+                                max_new_tokens=2, cache_len=16)
+
+    def runtime(self):
+        return self.sess.executor.runtime
+
+    def drive(self):
+        toks = np.arange(5, dtype=np.int32) % self.vocab
+        return self.sess.generate([(toks, 2)])
+
+    def close(self):
+        self.sess.close()
+
+
+_OWNERS = {"runtime": _Bare, "infer-session": _Infer,
+           "serve-session": _Serve}
+
+
+def _assert_no_live_worker(threads):
+    assert threads, "the epoch started no worker thread"
+    alive = [t.name for t in threads if t.is_alive()]
+    assert not alive, f"worker threads still alive: {alive}"
+
+
+class TestThreadedRuntimeLifecycle:
+    """The one actor runtime across epochs, at close() and after a fault:
+    results and instrumentation per epoch, and no worker thread left
+    behind, for a bare runtime and for the sessions built on it."""
+
+    def test_three_node_chain_reuse_fires_and_edges(self):
+        """One persistent runtime over a 3-node chain: correct results,
+        epoch reuse, per-epoch ctx and fires overrides, per-edge byte
+        accounting."""
+        with ThreadedRuntime(_chain_specs(n=4),
+                             collect_outputs_of="sink") as rt:
+            outs = rt.run(timeout=30.0)
+            assert [float(o[0]) for o in outs] == [
+                (v + 1.0) * 2.0 for v in range(4)]
+            assert all(o.shape == (8,) for o in outs)
+            assert rt.last_fired == {"src": 4, "mid": 4, "sink": 4}
+            # each hop carried 4 fires x 8 float32
+            for edge in (("src", "mid"), ("mid", "sink")):
+                assert rt.last_edge_bytes[edge] == 4 * 8 * 4
+            outs = rt.run(ctx={"src": 100.0}, fires={"src": 2}, timeout=30.0)
+            assert [float(o[0]) for o in outs] == [202.0, 204.0]
+            assert rt.last_fired["src"] == 2
+            assert rt.last_edge_bytes[("src", "mid")] == 2 * 8 * 4
+            with pytest.raises(ValueError, match="unknown actor"):
+                rt.run(ctx={"nope": 1}, fires={"src": 1})
+
+    @pytest.mark.parametrize("owner", sorted(_OWNERS))
+    def test_close_is_idempotent_and_leaves_no_worker(self, owner):
+        o = _OWNERS[owner]()
+        o.drive()
+        threads = list(o.runtime()._engine._threads)
+        o.close()
+        o.close()    # second close: no-op, no error
+        _assert_no_live_worker(threads)
+
+    @pytest.mark.parametrize("owner", sorted(_OWNERS))
+    def test_actor_error_surfaces_and_leaves_no_worker(self, owner,
+                                                       monkeypatch):
+        from repro.runtime import pipeline
+
+        def boom(*a, **k):
+            raise RuntimeError("kaboom in a stage body")
+
+        if owner == "runtime":
+            o = _Bare(mid=boom)
+        else:
+            # the stage actors look these up at fire time
+            monkeypatch.setattr(pipeline, "_place_incoming", boom)
+            monkeypatch.setattr(pipeline, "serve_stage_apply", boom)
+            o = _OWNERS[owner]()
+        with pytest.raises(RuntimeError, match="kaboom in a stage body"):
+            o.drive()
+        _assert_no_live_worker(list(o.runtime()._engine._threads))
+        o.close()    # closing a failed runtime stays safe
+
+    def test_kill_worker_surfaces_and_leaves_no_worker(self):
+        from repro.runtime.chaos import (FaultPlan, KillWorker, WorkerError,
+                                         WorkerKilled)
+
+        rt = ThreadedRuntime(_chain_specs(n=4), collect_outputs_of="sink",
+                             faults=FaultPlan([KillWorker("mid", fire=2)]))
+        with pytest.raises(WorkerKilled, match="mid fire 2") as ei:
+            rt.run(timeout=30.0)
+        assert isinstance(ei.value, WorkerError)
+        assert rt.last_fired["mid"] == 1
+        _assert_no_live_worker(list(rt._engine._threads))
+        rt.close()
+        rt.close()
+
+
 class TestPipelineSchedules:
     def test_1f1b_memory_vs_gpipe(self):
         """1F1B quota (=stages) matches GPipe throughput at a fraction of the

@@ -243,6 +243,19 @@ class TestCompileValidation:
                            stages=S, regs="1f1b")
         assert sess.partition is None and sess.regs is None
 
+    @pytest.mark.parametrize("mode", ["infer", "train"])
+    def test_graph_modes_reject_fn_wrap(self, mode):
+        """fn_wrap wraps serve stage bodies only: a graph session on
+        either backend names it in its refusal."""
+        g = _graph(with_loss=mode == "train")
+        kw = {}
+        if mode == "train":
+            kw["params"] = dict(_params_and_data(g)[0])
+        for backend in ("actors", "monolithic"):
+            with pytest.raises(ValueError, match="fn_wrap"):
+                api.compile(g, mode=mode, backend=backend, stages=S,
+                            fn_wrap=lambda s, f: f, **kw)
+
     def test_run_step_mode_mismatch(self):
         g = _graph()
         params, data = _params_and_data(g)

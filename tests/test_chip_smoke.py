@@ -88,21 +88,6 @@ def test_compile_rebuilds_explicit_mesh_as_auto():
         sess.close()
 
 
-def test_processes_runtime_refused_off_cpu(monkeypatch):
-    """runtime='processes' opens one JAX client per node: on an accelerator
-    backend it is refused at compile time, before anything is spawned."""
-    from repro.runtime import process
-
-    def no_spawn(*a, **k):
-        raise AssertionError("a worker was spawned")
-
-    monkeypatch.setattr(process.ProcessRuntime, "__init__", no_spawn)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = get_config("qwen3-1.7b").reduced()
-    with pytest.raises(ValueError, match="runtime='processes'"):
-        api.compile(cfg, mode="serve", runtime="processes", **SMALL_SERVE)
-
-
 def test_contract_line_shape(smoke, monkeypatch, capsys):
     """With every phase stubbed out, main's last line is exactly the
     contract object, naming the device as JAX reports it."""

@@ -7,9 +7,8 @@ The contract under test, end to end:
   driver-side MANIFEST — so ``latest_snapshot(dir)`` always equals the
   number of *completed* steps, even when a fault kills the run mid-step.
 * ``compile(faults=FaultPlan([KillWorker(actor, fire=k)]))`` kills the
-  named actor's worker at its k-th cumulative fire: an exception on the
-  threads runtime, a hard ``os._exit`` of the stage's worker process on
-  the processes runtime. Both surface as ``WorkerError`` on the driver.
+  named actor's worker at its k-th cumulative fire; the driver sees a
+  ``WorkerError``.
 * ``compile(restore=dir)`` resumes from the newest completed snapshot —
   params, Adam moments, and the step counter the lr schedule indexes.
 
@@ -28,8 +27,8 @@ from repro import api
 from repro.core.graph import LogicalGraph
 from repro.core.lowering import OptimizerSpec
 from repro.core.placement import Placement
-from repro.runtime.base import WorkerError
-from repro.runtime.chaos import FaultPlan, KillWorker, WorkerKilled
+from repro.runtime.chaos import (FaultPlan, KillWorker, WorkerError,
+                                 WorkerKilled)
 from repro.runtime.snapshot import (latest_snapshot, list_snapshots,
                                     load_snapshot)
 
@@ -60,7 +59,6 @@ def _params_and_data(seed=0):
 
 
 def _lr_schedule(s):
-    # module-level so the processes runtime can pickle it into workers
     return 1e-3 * 0.9 ** s
 
 
@@ -95,10 +93,10 @@ def _assert_matches_ref(ref, losses, params, opt_state):
                               np.asarray(rs.nu[n])), n
 
 
-def _kill_and_resume(ref, runtime, actor, fire):
+def _kill_and_resume(ref, actor, fire):
     params, data = ref["params0"], ref["data"]
     with tempfile.TemporaryDirectory() as d:
-        kw = dict(mode="train", backend="actors", stages=S, runtime=runtime,
+        kw = dict(mode="train", backend="actors", stages=S,
                   params=dict(params), optimizer=_opt(), num_microbatches=M)
         sess = api.compile(_graph(), snapshot_dir=d,
                            faults=FaultPlan([KillWorker(actor, fire=fire)]),
@@ -143,21 +141,10 @@ class TestKillAndResumeThreads:
     @pytest.mark.parametrize("actor,fire", _THREAD_CASES,
                              ids=[f"{a}-fire{k}" for a, k in _THREAD_CASES])
     def test_bit_identical(self, ref, actor, fire):
-        _kill_and_resume(ref, "threads", actor, fire)
+        _kill_and_resume(ref, actor, fire)
 
     def test_worker_killed_is_a_worker_error(self):
         assert issubclass(WorkerKilled, WorkerError)
-
-
-class TestKillAndResumeProcesses:
-    """Same contract when the kill is a real ``os._exit`` of a worker
-    process — the driver sees the death via exit code, not an exception."""
-
-    @pytest.mark.parametrize("actor,fire",
-                             [("f0", 3), ("b1", 4), ("opt1", 2)],
-                             ids=["f0-fire3", "b1-fire4", "opt1-fire2"])
-    def test_bit_identical(self, ref, actor, fire):
-        _kill_and_resume(ref, "processes", actor, fire)
 
 
 class TestSnapshotRestoreSurface:
@@ -276,6 +263,19 @@ def _zero_kw(params, **extra):
     return kw
 
 
+# one kill per actor of the 4-stage ZeRO pipeline: every f{s} at fire 3
+# (step 2, first microbatch), every b{s} at fire 4 (step 2, second
+# microbatch), every opt{s} and snap{s} at fire 2 (step 2); b3 also at
+# fire 3
+_ZERO_CASES = (
+    [("opt2", 2), ("b3", 3)]
+    + [(f"f{s}", 3) for s in range(S4)]
+    + [(f"b{s}", 4) for s in range(S4)]
+    + [(f"opt{s}", 2) for s in range(S4) if s != 2]
+    + [(f"snap{s}", 2) for s in range(S4)]
+)
+
+
 class TestZeroKillAndResume:
     """zero=True precision='bf16': kill mid-step, resume from the *sharded*
     snapshot — onto the same cut, onto a different cut, and onto the
@@ -290,12 +290,11 @@ class TestZeroKillAndResume:
         return {"params0": params, "data": data, "losses": losses,
                 "final_params": sess.params, "opt_state": sess.opt_state}
 
-    def _run_killed(self, zref, d, actor, fire, runtime="threads"):
+    def _run_killed(self, zref, d, actor, fire):
         params, data = zref["params0"], zref["data"]
         sess = api.compile(_zero_graph(), snapshot_dir=d,
                            faults=FaultPlan([KillWorker(actor, fire=fire)]),
-                           backend="actors", stages=S4, runtime=runtime,
-                           **_zero_kw(params))
+                           backend="actors", stages=S4, **_zero_kw(params))
         losses, killed = [], False
         try:
             for _ in range(STEPS):
@@ -309,17 +308,14 @@ class TestZeroKillAndResume:
         assert n == len(losses) < STEPS
         return losses, n
 
-    @pytest.mark.parametrize("actor,fire,runtime",
-                             [("opt2", 2, "threads"), ("b3", 3, "threads"),
-                              ("f1", 3, "processes")],
-                             ids=["opt2-fire2", "b3-fire3", "f1-fire3-proc"])
-    def test_resume_same_partition(self, zref, actor, fire, runtime):
+    @pytest.mark.parametrize("actor,fire", _ZERO_CASES,
+                             ids=[f"{a}-fire{k}" for a, k in _ZERO_CASES])
+    def test_resume_same_partition(self, zref, actor, fire):
         params, data = zref["params0"], zref["data"]
         with tempfile.TemporaryDirectory() as d:
-            losses, n = self._run_killed(zref, d, actor, fire, runtime)
+            losses, n = self._run_killed(zref, d, actor, fire)
             with api.compile(_zero_graph(), restore=d, backend="actors",
-                             stages=S4, runtime=runtime,
-                             **_zero_kw(params)) as res:
+                             stages=S4, **_zero_kw(params)) as res:
                 assert res.step_count == n
                 losses += [float(res.step(**data).loss)
                            for _ in range(STEPS - n)]

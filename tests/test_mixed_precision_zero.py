@@ -3,8 +3,8 @@
 The contract: ``api.compile(graph, mode="train", zero=True,
 precision="bf16", loss_scale=...)`` runs forward/backward in bfloat16 over
 flat fp32 master shards held by the opt actors, and is **bit-identical**
-across every backend — actors/threads, actors/processes, monolithic — and
-across the zero/dense layouts (the flat ``(dp, 1, chunk)`` shard is a pure
+across both backends — actors and monolithic — and across the zero/dense
+layouts (the flat ``(dp, 1, chunk)`` shard is a pure
 relayout of the dense fp32 master, and AdamW's math is elementwise).
 
 Also covered here:
@@ -13,13 +13,12 @@ Also covered here:
   scaling via the ``scale`` actor — growth after ``growth_interval`` good
   steps, skip + backoff on a non-finite gradient norm, with the skipped
   step leaving params/moments/step-count untouched on every backend;
-* bf16 payloads crossing node boundaries: ``encode_payload`` -> pickle ->
-  decode must preserve ``bfloat16`` bitwise, including inside NamedTuples
-  (``ZeroState``) — the processes runtime's wire format;
+* bf16 leaves through the snapshot files: ``write_stage_snapshot`` ->
+  ``load_snapshot`` must preserve ``bfloat16`` bitwise, in the plain and
+  in the ZeRO flat-shard layout;
 * option validation and ``describe()``/``opt_state_bytes()`` surfacing;
 * snapshot/restore carrying the loss-scale trajectory.
 """
-import pickle
 import tempfile
 
 import numpy as np
@@ -32,8 +31,9 @@ from repro import api
 from repro.core.graph import LogicalGraph
 from repro.core.lowering import OptimizerSpec, PrecisionPolicy
 from repro.core.placement import Placement
-from repro.optim.zero import ZeroState
-from repro.runtime.base import encode_payload
+from repro.optim.adamw import AdamWState
+from repro.runtime.snapshot import (load_snapshot, write_manifest,
+                                    write_stage_snapshot)
 
 B, W, S, M, STEPS = 8, 8, 2, 2, 3
 
@@ -62,7 +62,6 @@ def _params_and_data(seed=0):
 
 
 def _lr_schedule(s):
-    # module-level so the processes runtime can pickle it into workers
     return 1e-3 * 0.9 ** s
 
 
@@ -80,24 +79,16 @@ def _mp_kwargs(params, **extra):
 
 class TestFourWayBitIdentity:
     """zero=True precision='bf16' loss_scale=1024: losses, fp32 masters and
-    AdamW moments bitwise across all backend/runtime/layout combinations
-    over STEPS scheduled-lr steps."""
+    AdamW moments bitwise across all backend/layout combinations over
+    STEPS scheduled-lr steps."""
 
     def test_actors_threads_vs_monolithic(self):
         params, data = _params_and_data()
         mono = api.compile(_graph(), backend="monolithic",
                            **_mp_kwargs(params))
         with api.compile(_graph(), backend="actors", stages=S,
-                         runtime="threads", **_mp_kwargs(params)) as thr:
+                         **_mp_kwargs(params)) as thr:
             api.assert_sessions_match(thr, mono, data, steps=STEPS)
-
-    def test_actors_processes_vs_monolithic(self):
-        params, data = _params_and_data()
-        mono = api.compile(_graph(), backend="monolithic",
-                           **_mp_kwargs(params))
-        with api.compile(_graph(), backend="actors", stages=S,
-                         runtime="processes", **_mp_kwargs(params)) as prc:
-            api.assert_sessions_match(prc, mono, data, steps=STEPS)
 
     def test_zero_layout_matches_dense_masters(self):
         """The flat shard layout is pure bookkeeping: zero=True must equal
@@ -167,10 +158,9 @@ class TestLossScaling:
             assert mono.executor.loss_scale == 2.0 ** 6
             assert thr.executor.loss_scale == 2.0 ** 6
 
-    @pytest.mark.parametrize("backend,runtime",
-                             [("monolithic", None), ("actors", "threads"),
-                              ("actors", "processes")])
-    def test_nonfinite_step_skips_and_backs_off(self, backend, runtime):
+    @pytest.mark.parametrize("backend", ["monolithic", "actors"],
+                             ids=["monolithic-None", "actors-threads"])
+    def test_nonfinite_step_skips_and_backs_off(self, backend):
         """An inf batch in bf16 produces a non-finite grad norm: the step
         must be skipped — params, moments and step counter untouched — and
         the scale halved, identically on every backend."""
@@ -180,7 +170,7 @@ class TestLossScaling:
         kw = _mp_kwargs(params, precision=self._dynamic_policy(),
                         loss_scale=None)
         if backend == "actors":
-            kw.update(stages=S, runtime=runtime)
+            kw.update(stages=S)
         with api.compile(_graph(), backend=backend, **kw) as sess:
             r0 = sess.step(**data)          # good step
             p_before = {n: np.asarray(v) for n, v in sess.params.items()}
@@ -218,40 +208,54 @@ class TestLossScaling:
                                               np.asarray(v), err_msg=n)
 
 
-class TestBf16WireFormat:
-    """Satellite: bf16 arrays must survive the processes runtime's wire
-    format — ``encode_payload`` -> pickle -> unpickle — bitwise, with the
-    ``bfloat16`` dtype intact (ml_dtypes must not degrade to fp32/fp16)."""
+class TestBf16Snapshot:
+    """bf16 leaves written by ``write_stage_snapshot`` come back from
+    ``load_snapshot`` bitwise, with the ``bfloat16`` dtype intact (``np.save``
+    stores an ml_dtypes array as raw 2-byte records; the manifest's dtype
+    must restore it)."""
 
-    def _roundtrip(self, payload):
-        return pickle.loads(pickle.dumps(encode_payload(payload)))
+    @staticmethod
+    def _bits(x):
+        return np.asarray(x).view(np.uint16)
 
-    def test_bf16_jax_array_roundtrips_bitwise(self):
-        x = jnp.asarray(np.random.default_rng(0).normal(size=(7, 3)),
-                        jnp.bfloat16)
-        out = self._roundtrip({"x": x})["x"]
-        assert out.dtype == np.asarray(x).dtype       # still bfloat16
-        np.testing.assert_array_equal(
-            out.view(np.uint16), np.asarray(x).view(np.uint16))
+    def test_plain_layout_roundtrips_bitwise(self):
+        rng = np.random.default_rng(0)
+        w = jnp.asarray(rng.normal(size=(7, 3)), jnp.bfloat16)
+        mu = jnp.asarray(rng.normal(size=(7, 3)), jnp.bfloat16)
+        nu = jnp.asarray(rng.normal(size=(7, 3)) ** 2, jnp.float32)
+        with tempfile.TemporaryDirectory() as d:
+            write_stage_snapshot(
+                d, 1, 0, {"w": w},
+                opt_state=AdamWState(jnp.asarray(1, jnp.int32),
+                                     {"w": mu}, {"w": nu}))
+            write_manifest(d, 1, [0])
+            params, opt, step, _ = load_snapshot(d)
+        assert step == 1
+        assert params["w"].dtype == jnp.bfloat16
+        np.testing.assert_array_equal(self._bits(params["w"]), self._bits(w))
+        assert opt.mu["w"].dtype == jnp.bfloat16
+        np.testing.assert_array_equal(self._bits(opt.mu["w"]),
+                                      self._bits(mu))
+        assert opt.nu["w"].dtype == np.float32
+        np.testing.assert_array_equal(opt.nu["w"], np.asarray(nu))
 
-    def test_bf16_inside_zero_state_namedtuple(self):
-        mk = lambda: jnp.asarray(  # noqa: E731
-            np.random.default_rng(1).normal(size=(2, 1, 5)), jnp.float32)
-        st = ZeroState(jnp.asarray(3, jnp.int32),
-                       {"w": mk().astype(jnp.bfloat16)}, {"w": mk()})
-        out = self._roundtrip({"state": st})["state"]
-        assert isinstance(out, ZeroState)
-        assert out.mu["w"].dtype == jnp.bfloat16
-        np.testing.assert_array_equal(
-            np.asarray(out.mu["w"]).view(np.uint16),
-            np.asarray(st.mu["w"]).view(np.uint16))
-        np.testing.assert_array_equal(np.asarray(out.nu["w"]),
-                                      np.asarray(st.nu["w"]))
-        assert int(out.step) == 3
-
-    def test_private_keys_still_stripped(self):
-        out = self._roundtrip({"__vjp__": object, "loss": 1.0})
-        assert "__vjp__" not in out and out["loss"] == 1.0
+    def test_zero_flat_shards_roundtrip_bitwise(self):
+        """A (dp, 1, chunk) bf16 shard is gathered back to the logical
+        shape with its dtype and bits intact."""
+        dp, shape = 2, (3, 5)
+        full = jnp.asarray(np.random.default_rng(1).normal(size=shape),
+                           jnp.bfloat16)
+        chunk = -(-full.size // dp)
+        flat = jnp.pad(full.reshape(-1), (0, dp * chunk - full.size))
+        with tempfile.TemporaryDirectory() as d:
+            write_stage_snapshot(d, 2, 0, {"w": flat.reshape(dp, 1, chunk)},
+                                 zero={"dp": dp, "shapes": {"w": shape}})
+            write_manifest(d, 2, [0])
+            params, opt, step, _ = load_snapshot(d)
+        assert step == 2 and opt is None
+        got = params["w"]
+        assert got.shape == shape and got.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(self._bits(got), self._bits(full))
 
 
 class TestOptionValidation:

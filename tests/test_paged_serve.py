@@ -140,19 +140,6 @@ class TestPagedTokenIdentity:
         for i, (got, ref) in enumerate(zip(outs, dense_ref)):
             assert np.array_equal(got, ref), f"request {i}: {got} != {ref}"
 
-    def test_process_runtime_paged_matches_dense(self, serve_env, dense_ref):
-        """The page-table rows ride the work items and the slabs live in
-        the stage worker processes — the pool itself never crosses a
-        process boundary."""
-        cfg, mesh, params, prompts = serve_env
-        with api.compile(cfg, mode="serve", backend="actors", stages=2,
-                         runtime="processes", cache="paged",
-                         page_len=PAGE_LEN, num_pages=NUM_PAGES,
-                         **_kw(params, mesh)) as sess:
-            outs = sess.generate(list(zip(prompts, GENS)))
-        for i, (got, ref) in enumerate(zip(outs, dense_ref)):
-            assert np.array_equal(got, ref), f"request {i}: {got} != {ref}"
-
     def test_ssm_paged_matches_dense(self):
         """Recurrent state (SSM h, conv tails) lives in the per-request row
         pool, not the page slabs; paged serving must still match dense."""

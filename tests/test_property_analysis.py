@@ -138,7 +138,7 @@ class TestTraceSanitizerProperty:
             sess.executor.trace = rec
             sess.step(**data)
             sess.step(**data)
-            specs, _ = sess._engine._make_builder()()
+            specs, _ = sess._engine._specs()
             violations, stats = check_trace(rec, specs)
         finally:
             sess.close()

@@ -348,21 +348,6 @@ class TestSamplerStream:
                               sampling=self._spec(seed=8)).generate(reqs)
         assert any(not np.array_equal(x, y) for x, y in zip(want, other))
 
-    def test_fixed_seed_threads_match_processes(self, serve_env):
-        """The sampler key lives in the last stage's worker; thread and
-        process runtimes must emit identical streams for the same seed."""
-        cfg, mesh, params, prompts = serve_env
-        reqs = list(zip(prompts, GENS))
-        with self._session(serve_env, backend="actors", stages=2,
-                           sampling=self._spec()) as thr:
-            a = thr.generate(reqs)
-        with self._session(serve_env, backend="actors", stages=2,
-                           runtime="processes",
-                           sampling=self._spec()) as proc:
-            b = proc.generate(reqs)
-        for i, (x, y) in enumerate(zip(a, b)):
-            assert np.array_equal(x, y), f"request {i}: {x} != {y}"
-
     def test_sampling_spec_validation(self, serve_env):
         from repro.serve import SamplingSpec
         with pytest.raises(ValueError, match="temperature"):

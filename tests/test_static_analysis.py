@@ -259,7 +259,7 @@ class TestSkeletonParity:
         sess = api.compile(g, mode="infer", backend="actors", stages=S,
                            num_microbatches=4, microbatch_inputs=["x"])
         try:
-            real, _ = sess._engine._make_builder()()
+            real, _ = sess._engine._specs()
             skel = infer_spec_skeleton(S, 4, sess.regs)
             assert sorted(map(self._key, real)) \
                 == sorted(map(self._key, skel))
@@ -272,7 +272,7 @@ class TestSkeletonParity:
                            params=dict(_train_params()), optimizer=opt,
                            num_microbatches=M)
         try:
-            real, _ = sess._engine._make_builder()()
+            real, _ = sess._engine._specs()
             skel = train_spec_skeleton(S, M, sess.regs, clip=True,
                                        stateful=True)
             assert sorted(map(self._key, real)) \
@@ -340,7 +340,7 @@ class TestTraceSanitizer:
             data = _train_data()
             sess.step(**data)
             sess.step(**data)
-            specs, _ = sess._engine._make_builder()()
+            specs, _ = sess._engine._specs()
             violations, stats = check_trace(rec, specs)
             assert violations == []
             assert stats.deliveries > 0 and stats.duplicates_dropped == 0
@@ -357,7 +357,7 @@ class TestTraceSanitizer:
         try:
             sess.executor.trace = rec
             sess.step(**_train_data())
-            specs, _ = sess._engine._make_builder()()
+            specs, _ = sess._engine._specs()
             violations, stats = check_trace(rec, specs)
             assert violations == []
             assert stats.duplicates_dropped == 1
@@ -375,12 +375,6 @@ class TestTraceSanitizer:
         violations, _ = check_trace(rec, specs)
         assert any("canonical stride-1 order" in v.message
                    for v in violations)
-
-    def test_trace_requires_threads_runtime(self):
-        from repro.runtime.base import make_runtime
-        with pytest.raises(ValueError, match="requires runtime='threads'"):
-            make_runtime("processes", lambda: ([], None),
-                         trace=TraceRecorder())
 
 
 class TestCLI:
