@@ -8,13 +8,17 @@
 
 Phases (one process; a failed check raises and the script exits non-zero):
 
-* kernels — the four Pallas kernels compiled (``interpret=False``) at real
+* kernels — the Pallas kernels compiled (``interpret=False``) at real
   widths, each checked against its ``ref.py`` oracle (computed at full fp32
   matmul precision) with the tolerances of ``tests/test_kernels.py``.
 * serve — qwen3-1.7b at its published widths (28 layers, random weights from
   ``PRNGKey(0)``) through ``api.compile(mode="serve", backend="actors")``:
   8 requests from a seed, dense cache, then the same requests with
-  ``cache="paged"``; the token ids must be identical.
+  ``cache="paged"``. A paged decode that gathers a dense window runs the
+  dense decode program: its token ids must be identical. On the TPU the
+  paged decode reads the page slabs in place instead; fed the gathering
+  session's tokens, its logits must stay close to that session's (see
+  :func:`phase_serve`).
 * train — the 1F1B actor pipeline on an 8-layer matmul+relu graph at width
   2048 with a softmax-xent head and AdamW, 3 steps against
   ``backend="monolithic"`` (see :func:`compare_train`).
@@ -29,6 +33,7 @@ reduced size on the CPU.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import gc
 import json
 import pathlib
@@ -43,13 +48,16 @@ import numpy as np  # noqa: E402
 
 from repro import api  # noqa: E402
 from repro.configs.registry import get_config  # noqa: E402
+from repro.core import lowering  # noqa: E402
 from repro.core.graph import LogicalGraph  # noqa: E402
 from repro.core.lowering import OptimizerSpec  # noqa: E402
 from repro.core.placement import Placement  # noqa: E402
 from repro.kernels.flash_attention.kernel import flash_attention_pallas  # noqa: E402
 from repro.kernels.flash_attention.ref import attention_dense_ref  # noqa: E402
-from repro.kernels.flash_decode.kernel import flash_decode_pallas  # noqa: E402
-from repro.kernels.flash_decode.ref import decode_attention_ref  # noqa: E402
+from repro.kernels.flash_decode.kernel import (  # noqa: E402
+    flash_decode_paged_pallas, flash_decode_pallas)
+from repro.kernels.flash_decode.ref import (  # noqa: E402
+    decode_attention_ref, flash_decode_paged_ref)
 from repro.kernels.softmax_xent.kernel import xent_local_stats_pallas  # noqa: E402
 from repro.kernels.softmax_xent.ref import local_stats_ref  # noqa: E402
 from repro.kernels.ssd_scan.kernel import ssd_scan_pallas  # noqa: E402
@@ -60,13 +68,17 @@ from repro.launch.xla_env import enable_compile_cache  # noqa: E402
 #: bf16 tolerance of tests/test_kernels.py (every kernel input here is bf16)
 KERNEL_TOL = dict(rtol=2e-2, atol=2e-2)
 
-#: real widths: qwen3-1.7b attention (16 q / 8 kv heads x 128), a quarter
-#: of its 151 936 vocab, mamba2-370m's SSD (32 heads x 64, state 128)
+#: real widths: qwen3-1.7b attention (16 q / 8 kv heads x 128; the paged
+#: decode over 4 layers of the serve cell's slabs, 16-position pages of a
+#: 2448 window), a quarter of its 151 936 vocab, mamba2-370m's SSD (32
+#: heads x 64, state 128)
 KERNEL_WIDTHS = {
     "flash_attention": dict(batch=1, seq=2048, heads=16, kv_heads=8,
                             head_dim=128),
     "flash_decode": dict(batch=4, cache=4096, heads=16, kv_heads=8,
                          head_dim=128),
+    "flash_decode_paged": dict(cache=2448, page_len=16, layers=4, heads=16,
+                               kv_heads=8, head_dim=128),
     "softmax_xent": dict(rows=1024, vocab=37984),
     "ssd_scan": dict(batch=1, seq=2048, heads=32, head_dim=64, state=128,
                      chunk=128),
@@ -158,6 +170,24 @@ def phase_kernels(widths=None, *, interpret: bool = False, seed: int = 0):
     want = _ref(decode_attention_ref, q, k, v, cur)
     _check_close("flash_decode", got, want, KERNEL_TOL)
 
+    w = widths["flash_decode_paged"]
+    L, page_len = w["cache"], w["page_len"]
+    R = L // page_len
+    rows, lengths = paged_table(L, page_len, seed)
+    slab = (w["layers"], rows.size, page_len, w["kv_heads"], w["head_dim"])
+    k, v = normal(slab), normal(slab)
+    q = normal((len(lengths), w["heads"], w["head_dim"]))
+    layer = w["layers"] - 1
+    (m, l, acc), *times["flash_decode_paged"] = _timed(
+        lambda q, k, v, r, n: flash_decode_paged_pallas(
+            q, k, v, r, n, layer, interpret=interpret), q, k, v, rows, lengths)
+    assert not np.asarray(l)[np.asarray(lengths) == 0].any()  # parked
+    _, l_ref, acc_ref = _ref(lambda q, k, v, r, n: flash_decode_paged_ref(
+        q, k, v, r, n, layer), q, k, v, rows, lengths)
+    _check_close("flash_decode_paged",
+                 acc / jnp.maximum(l, 1e-30)[..., None],
+                 acc_ref / jnp.maximum(l_ref, 1e-30)[..., None], KERNEL_TOL)
+
     w = widths["softmax_xent"]
     N, Vl = w["rows"], w["vocab"]
     logits = normal((N, Vl), scale=3.0)
@@ -193,6 +223,22 @@ def phase_kernels(widths=None, *, interpret: bool = False, seed: int = 0):
     return times
 
 
+def paged_table(cache_len: int, page_len: int, seed: int = 0):
+    """Four slots' page table rows over a shuffled pool of four windows'
+    pages, and their lengths: slot 0 fills the window but its last
+    position, slot 1 shares slot 0's first half and ends on a page
+    boundary, slot 2 has its second half unmapped (``-1``) inside its
+    length, slot 3 is parked (length 0)."""
+    R = cache_len // page_len
+    rows = np.random.default_rng(seed).permutation(4 * R).astype(
+        np.int32).reshape(4, R)
+    rows[1, :R // 2] = rows[0, :R // 2]
+    rows[2, R // 2:] = -1
+    lengths = np.array([cache_len - 1, page_len * (R // 2), cache_len - 1,
+                        0], np.int32)
+    return jnp.asarray(rows), jnp.asarray(lengths)
+
+
 # ---------------------------------------------------------------------------
 # serve
 # ---------------------------------------------------------------------------
@@ -212,10 +258,13 @@ def make_requests(vocab: int, *, n: int = 8, min_prompt: int = 64,
 
 
 def _serve_once(cfg, requests, *, mesh=None, cache="dense",
-                shape=None, timeout: float = 900.0, logits_of=None):
+                shape=None, timeout: float = 900.0, logits_of=None,
+                feed=None):
     """Compile one serve session, generate twice (the second call runs
-    warm), free the session. Returns the tokens, and the first-token logits
-    of ``logits_of`` (a prompt) when given."""
+    warm; each fed ``feed`` when given, see :func:`_generate`), free the
+    session. Returns the tokens, the first-token logits of ``logits_of`` (a
+    prompt) when given, and the second call's ``last_stats`` and kept
+    rounds."""
     shape = SERVE_SHAPE if shape is None else shape
     t0 = time.perf_counter()
     sess = api.compile(cfg, mode="serve", backend="actors", mesh=mesh,
@@ -223,13 +272,14 @@ def _serve_once(cfg, requests, *, mesh=None, cache="dense",
                        **({"cache": "paged"} if cache == "paged" else {}),
                        **shape)
     t1 = time.perf_counter()
-    outs = sess.generate(requests)
+    outs, _ = _generate(sess, requests, feed)
     t2 = time.perf_counter()
-    again = sess.generate(requests)
+    again, rounds = _generate(sess, requests, feed)
     t3 = time.perf_counter()
     logits = None
     if logits_of is not None:
         logits = first_token_logits(sess, logits_of)
+    stats = dict(sess.last_stats)
     sess.close()
     del sess
     gc.collect()
@@ -241,7 +291,29 @@ def _serve_once(cfg, requests, *, mesh=None, cache="dense",
     for o, (_, gen) in zip(outs, requests):
         assert len(o) == gen, (len(o), gen)
         assert ((o >= 0) & (o < cfg.vocab_size)).all()
-    return outs, logits
+    return outs, logits, stats, rounds
+
+
+def _generate(sess, requests, feed=None):
+    """``sess.generate(requests)`` keeping every round's work and results
+    (host arrays). With ``feed``, an earlier session's kept rounds, the
+    serve loop is handed that session's results instead of its own, so it
+    picks that session's tokens: the session follows the other's token
+    streams (teacher forcing) while computing, and keeping, its own
+    results. Returns the tokens and the kept rounds."""
+    engine = sess.executor
+    run_round = engine.run_round
+    kept = []
+
+    def kept_round(work, **kw):
+        out = [jax.tree.map(np.asarray, r) for r in run_round(work, **kw)]
+        kept.append((list(work), out))
+        return out if feed is None else feed[len(kept) - 1][1]
+    engine.run_round = kept_round
+    try:
+        return sess.generate(requests), kept
+    finally:
+        del engine.run_round
 
 
 def first_token_logits(sess, prompt):
@@ -254,16 +326,90 @@ def first_token_logits(sess, prompt):
     return np.asarray(x, np.float32)[0, :sess.cfg.vocab_size]
 
 
+@contextlib.contextmanager
+def _gathering():
+    """Sessions compiled inside gather a dense window for every paged
+    decode, as on a platform without the paged flash-decode."""
+    kernel = lowering.paged_decode_kernel
+    lowering.paged_decode_kernel = lambda platform: None
+    try:
+        yield
+    finally:
+        lowering.paged_decode_kernel = kernel
+
+
+#: Largest relative L2 distance between a live slot's logits in a paged
+#: session that reads the page slabs in place and in one that gathers,
+#: fed the same tokens (:func:`phase_serve`). Readings on a v5e chip
+#: (qwen3-1.7b at published widths, bfloat16, the smoke's requests from
+#: seeds 0-2): the kernel as it is, 0.01717-0.01722 (with its softmax
+#: weights kept in float32, 0.01664-0.01693: any attention that rounds
+#: apart from the dense program's moves the bfloat16 activations by an
+#: ulp here and there); with its softmax weights rounded to float8's three
+#: mantissa bits before P.V, 0.0368-0.0399.
+IN_PLACE_LOGITS_REL_L2 = 0.025
+
+
+def logits_rel_l2(rounds, ref, vocab: int) -> float:
+    """Largest relative L2 distance, over every round's items, between
+    the logits of ``rounds`` and of ``ref`` (both kept by
+    :func:`_generate` over the same work): a prefill's row, a decode's
+    live slots (a parked slot's logits are never read)."""
+    from repro.runtime.pipeline import DecodeWork
+
+    assert len(rounds) == len(ref), (len(rounds), len(ref))
+    worst = 0.0
+    for (work, outs), (_, want) in zip(rounds, ref):
+        for w, o, r in zip(work, outs, want):
+            live = (np.asarray(w.sids) >= 0 if isinstance(w, DecodeWork)
+                    else slice(None))
+            o = np.asarray(o, np.float64)[live, ..., :vocab]
+            r = np.asarray(r, np.float64)[live, ..., :vocab]
+            num = np.linalg.norm(o - r, axis=-1)
+            worst = max(worst, float(np.max(
+                num / np.maximum(np.linalg.norm(r, axis=-1), 1e-30),
+                initial=0.0)))
+    return worst
+
+
 def phase_serve(cfg, requests, *, shape=None, timeout: float = 900.0):
     """Dense, then paged, over the same requests on the default (one-chip)
-    mesh; the token ids must be identical."""
-    dense, _ = _serve_once(cfg, requests, shape=shape, timeout=timeout)
-    paged, _ = _serve_once(cfg, requests, cache="paged", shape=shape,
-                           timeout=timeout)
+    mesh.
+
+    A paged session whose decode gathers a dense window runs the dense
+    stage programs: its token ids must be identical. Where the paged
+    stages read the page slabs in place instead (the TPU: the paged
+    flash-decode of :func:`lowering.paged_decode_kernel`, checked against
+    its oracle in :func:`phase_kernels`), its blocked softmax rounds apart
+    from the dense program's and greedy tokens part at near ties; so the
+    gathering session is compiled as well, and an in-place session, fed
+    its tokens (:func:`_generate`), must keep every live slot's logits of
+    every round within :data:`IN_PLACE_LOGITS_REL_L2` of it, with every
+    decode fire in ``decode_fires_paged``."""
+    dense, _, _, _ = _serve_once(cfg, requests, shape=shape,
+                                 timeout=timeout)
+    in_place = lowering.paged_decode_kernel(
+        jax.devices()[0].platform) is not None
+    with _gathering() if in_place else contextlib.nullcontext():
+        paged, _, stats, rounds = _serve_once(
+            cfg, requests, cache="paged", shape=shape, timeout=timeout)
+    assert stats["decode_fires_paged"] == 0, stats
     for i, (a, b) in enumerate(zip(dense, paged)):
         np.testing.assert_array_equal(a, b, f"request {i}: paged != dense")
-    setup(f"serve: {len(requests)} requests, "
-          f"{sum(len(o) for o in dense)} tokens, paged == dense")
+    total = sum(len(o) for o in dense)
+    setup(f"serve: {len(requests)} requests, {total} tokens, "
+          f"paged (gathered) == dense")
+    if in_place:
+        _, _, stats, own = _serve_once(cfg, requests, cache="paged",
+                                       shape=shape, timeout=timeout,
+                                       feed=rounds)
+        assert stats["decode_fires_gathered"] == 0, stats
+        assert stats["decode_fires_paged"] > 0, stats
+        rel = logits_rel_l2(own, rounds, cfg.vocab_size)
+        setup(f"serve: paged decode in place, fed the gathered session's "
+              f"tokens: {stats['decode_fires_paged']} stage decode fires, "
+              f"largest logits rel L2 vs gathered {rel!r}")
+        assert rel <= IN_PLACE_LOGITS_REL_L2, (rel, IN_PLACE_LOGITS_REL_L2)
     return dense
 
 
@@ -282,11 +428,11 @@ def phase_serve_tp(cfg, requests, *, tp: int = 4, shape=None,
     printed."""
     devs = jax.devices()
     prompt = requests[0][0]
-    tp_out, tp_logits = _serve_once(
+    tp_out, tp_logits, _, _ = _serve_once(
         cfg, requests, mesh=make_mesh((1, tp), ("data", "model"),
                                       devices=devs[:tp]),
         shape=shape, timeout=timeout, logits_of=prompt)
-    one_out, one_logits = _serve_once(
+    one_out, one_logits, _, _ = _serve_once(
         cfg, requests, mesh=make_mesh((1, 1), ("data", "model"),
                                       devices=devs[:1]),
         shape=shape, timeout=timeout, logits_of=prompt)

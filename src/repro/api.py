@@ -695,9 +695,11 @@ class ServeSession:
                 prefill_chunk=self.prefill_chunk,
                 share_prefix=self.share_prefix)
             t0 = time.perf_counter()
+            decodes = 0
             while not sched.done():
                 with TraceAnnotation("serve.plan"):
                     work, meta = sched.plan_round()
+                decodes += sum(m[0] == "decode" for m in meta)
                 results = self._engine.run_round(work, timeout=self.timeout)
                 with TraceAnnotation("serve.absorb"):
                     for m, res in zip(meta, results):
@@ -716,6 +718,13 @@ class ServeSession:
             if pool is not None:
                 self.last_stats["peak_pages"] = pool.peak_pages
                 self.last_stats["shared_pages"] = sched.shared_pages
+                # stage decode fires that read the slabs in place / gathered
+                # (a decode item fires every stage, each on its one path)
+                stages = self.sstaged.stages
+                paged = sum(st.decode_slabs is not None for st in stages)
+                self.last_stats["decode_fires_paged"] = decodes * paged
+                self.last_stats["decode_fires_gathered"] = decodes * (
+                    len(stages) - paged)
             # the expert layers' counts of this call (models with experts)
             self.last_stats.update(
                 take_moe_counts(self.sstaged.stages) or {})
@@ -1157,7 +1166,9 @@ def compile(graph, *, mode: str = "infer",
     whole-stack single-program reference. ``cache="paged"`` swaps the dense
     per-group cache blocks for the preallocated page pool of
     :mod:`repro.serve.paged_cache` (geometry via ``page_len=`` /
-    ``num_pages=``, token-identical to dense), ``sampling=`` takes a
+    ``num_pages=``, token-identical to dense where its decode gathers a
+    window; on the TPU a GQA stack's decode reads the slabs in place and
+    agrees within rounding), ``sampling=`` takes a
     :class:`repro.serve.sampler.SamplingSpec` (default: greedy), and
     ``prefill_chunk=`` (paged only) admits long prompts as bounded chunks
     interleaved with decode rounds.

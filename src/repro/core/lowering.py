@@ -1272,7 +1272,19 @@ class ServeStage:
     so the final chunk's logits feed first-token sampling through the same
     head math as ``decode``.
 
-    With ``moe`` (the slice holds an expert layer) each of the three
+    ``decode_slabs(params, slabs, rows, sids, xin, pos) -> (xout,
+    new_kv)``: ``decode`` over the paged cache's page slabs read in place
+    (:func:`paged_decode_kernel`), on a stage that can (its cache holds only
+    GQA ``k``/``v``, tp = 1, no sliding window) on a platform with the
+    kernel; ``None`` elsewhere. Slot ``b`` attends its cached positions
+    ``< pos[b]`` through table row ``rows[b]`` (none where ``sids[b] < 0``)
+    and its own new entries; ``new_kv`` holds only those, ``(B, KV, hd)``
+    per prologue leaf and ``(P, B, KV, hd)`` per body leaf. The slabs are
+    only read. A paged stage takes one path for every decode fire, so a
+    session's fires by path follow from its decode rounds
+    (``last_stats`` ``decode_fires_paged`` / ``decode_fires_gathered``).
+
+    With ``moe`` (the slice holds an expert layer) each of the four
     programs also returns, last, the int32 counts
     :data:`repro.models.mlp.MOE_COUNTERS` of the fire, summed over the
     slice's layers and the mesh; :func:`split_moe_counts` takes them off
@@ -1292,6 +1304,7 @@ class ServeStage:
     chunk: Callable = None
     moe: bool = False
     moe_counts: MoeCounts = dataclasses.field(default_factory=MoeCounts)
+    decode_slabs: Optional[Callable] = None
 
 
 def split_moe_counts(stage: ServeStage, out):
@@ -1317,6 +1330,18 @@ def take_moe_counts(stages) -> Optional[Dict[str, int]]:
     if not parts:
         return None
     return dict(zip(MOE_COUNTERS, (int(v) for v in sum(parts))))
+
+
+def paged_decode_kernel(platform: str) -> Optional[Callable]:
+    """The paged flash-decode a stage placed on ``platform`` reads its
+    page slabs with (:func:`repro.kernels.flash_decode.kernel.
+    flash_decode_paged_pallas`, compiled for the TPU); ``None`` off the
+    TPU, where paged stages gather a dense window for ``decode``."""
+    if platform != "tpu":
+        return None
+    from repro.kernels.flash_decode.kernel import flash_decode_paged_pallas
+
+    return flash_decode_paged_pallas
 
 
 class ServeStagedProgram:
@@ -1457,6 +1482,7 @@ def lower_serve_stages(cfg, mesh, params: Dict[str, Any], num_stages: int,
 
     # every stage that runs a period shares this one placed body
     body = place(params["body"], pspecs_full["body"])
+    kernel = paged_decode_kernel(mesh.devices.flat[0].platform)
 
     stages: List[ServeStage] = []
     for s, (lo, hi) in enumerate(bounds):
@@ -1479,15 +1505,16 @@ def lower_serve_stages(cfg, mesh, params: Dict[str, Any], num_stages: int,
         sparams = place(sparams, {k: sspecs[k] for k in sparams})
         sparams["body"] = body if periods[1] > periods[0] else []
 
-        def local_decode(p, caches, xin, pos, _first=first, _last=last,
-                         _kinds=pro_kinds, _periods=periods, _moe=moe):
+        def decode_step(p, xin, run_slice, _first=first, _last=last,
+                        _moe=moe):
+            # one decode token through the slice: embed on the first
+            # stage, ``run_slice(x) -> (x, caches, counts)``, the head on
+            # the last
             if _first:
                 x = T.embed_tokens(p["embed"], xin[:, None], plan).astype(adt)
             else:
                 x = xin
-            x, new_caches, counts = T.decode_stack_slice(
-                p, caches, x, pos, cfg, plan, _kinds, _periods,
-                sliding_window=sliding_window)
+            x, new_caches, counts = run_slice(x)
             if _last:
                 x = T.rms_norm(x, p["final_norm"].astype(x.dtype),
                                cfg.norm_eps)
@@ -1497,6 +1524,12 @@ def lower_serve_stages(cfg, mesh, params: Dict[str, Any], num_stages: int,
             if _moe:      # every device's tokens and held experts
                 return out, new_caches, _mesh_sum(counts, plan.axis_names)
             return out, new_caches
+
+        def local_decode(p, caches, xin, pos, _step=decode_step,
+                         _kinds=pro_kinds, _periods=periods):
+            return _step(p, xin, lambda x: T.decode_stack_slice(
+                p, caches, x, pos, cfg, plan, _kinds, _periods,
+                sliding_window=sliding_window))
 
         # stage-distinct program names, so a device trace tells stage 0's
         # programs from stage 1's (jit names its module after the function)
@@ -1509,6 +1542,28 @@ def lower_serve_stages(cfg, mesh, params: Dict[str, Any], num_stages: int,
             local_decode, mesh=mesh,
             in_specs=(sspecs, grp_cspecs, xin_spec, P(dp)),
             out_specs=(xout_spec, grp_cspecs) + counts_spec, check=False))
+
+        decode_slabs = None
+        cache_keys = {k for blk in grp_cspecs["prologue"] + grp_cspecs["body"]
+                      for k in blk}
+        if (kernel is not None and cache_keys == {"k", "v"}
+                and plan.tp == 1 and plan.dp == 1 and not sliding_window):
+            def local_decode_slabs(p, slabs, rows, sids, xin, pos,
+                                   _step=decode_step, _kinds=pro_kinds,
+                                   _periods=periods):
+                lengths = jnp.where(sids >= 0, pos, 0)
+
+                def attend(q, k_slab, v_slab, layer):
+                    return kernel(q, k_slab, v_slab, rows, lengths, layer)
+                return _step(p, xin, lambda x: T.decode_stack_slice_slabs(
+                    p, slabs, x, pos, cfg, plan, _kinds, _periods, attend))
+
+            # the same module name as ``decode``: it is this stage's decode
+            local_decode_slabs.__name__ = f"local_decode_s{s}"
+            decode_slabs = jax.jit(shard_map(
+                local_decode_slabs, mesh=mesh,
+                in_specs=(sspecs, P(), P(), P(), xin_spec, P(dp)),
+                out_specs=(xout_spec, P()) + counts_spec, check=False))
 
         def local_prefill(p, xin, last_index, _first=first, _last=last,
                           _kinds=pro_kinds, _periods=periods, _moe=moe):
@@ -1589,6 +1644,6 @@ def lower_serve_stages(cfg, mesh, params: Dict[str, Any], num_stages: int,
             index=s, decode=decode, prefill=prefill,
             init_caches=init_caches, write_slot=write_slot,
             params=sparams, units=(lo, hi), first=first, last=last,
-            mesh=mesh, chunk=chunk, moe=moe))
+            mesh=mesh, chunk=chunk, moe=moe, decode_slabs=decode_slabs))
     return ServeStagedProgram(cfg, plan, mesh, stages, cache_len,
                               max_prompt_len, group_size)

@@ -101,3 +101,32 @@ def decode_attention_ref(q, k, v, cur_pos, *, sliding_window: int = 0,
         q, k, v, k_offset=0, cur_pos=cur_pos, sliding_window=sliding_window,
         sm_scale=sm_scale)
     return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
+
+
+def flash_decode_paged_ref(q, k_slab, v_slab, rows, lengths, layer, *,
+                           sm_scale: Optional[float] = None):
+    """The paged kernel's math in jnp: gather slot ``b``'s window of layer
+    ``layer`` through its table row ``rows[b]`` and take its partial over
+    the positions ``< lengths[b]`` on mapped pages (``l = acc = 0`` where
+    there are none).
+
+    q: (B, H, D); k_slab, v_slab: (P, num_pages, page_len, KV, D);
+    rows: (B, pages_per_req) int32 (-1 = unmapped); lengths: (B,) int32.
+    """
+    page_len = k_slab.shape[2]
+    L = rows.shape[1] * page_len
+    pos = jnp.arange(L)
+    page = rows[:, pos // page_len]                          # (B, L)
+    ok = (page >= 0) & (pos[None, :] < lengths[:, None])
+    flat = jnp.maximum(page, 0) * page_len + pos[None, :] % page_len
+
+    def window(slab):
+        s = slab[layer]
+        return jnp.take(s.reshape((-1,) + s.shape[2:]), flat, axis=0)
+
+    m, l, acc = flash_decode_partial_ref(
+        q, window(k_slab), window(v_slab),
+        k_positions=jnp.where(ok, pos[None, :], -1), sm_scale=sm_scale)
+    none = ~ok.any(axis=1)[:, None]
+    return (m, jnp.where(none, 0.0, l),
+            jnp.where(none[..., None], 0.0, acc))

@@ -289,6 +289,29 @@ def gqa_decode(p, x, cache_k, cache_v, pos, cfg: ModelConfig, plan: MeshPlan,
     return y_partial, cache_k, cache_v, cache_pos
 
 
+def gqa_decode_paged(p, x, pos, cfg: ModelConfig, plan: MeshPlan, cached,
+                     kv_dtype):
+    """One-token decode over a cache that is read where it lies (tp = 1).
+
+    ``cached(q)`` gives the flash-decode partial ``(m, l, acc)`` of the
+    ``(B, H, hd)`` queries over the cached positions ``< pos``; the new
+    token's own partial, from its K/V at the cache's ``kv_dtype``, joins it
+    in :func:`combine_partials`. Returns ``(out_partial, k_new, v_new)``,
+    the new position's ``(B, KV, hd)`` entries for the caller to write.
+    """
+    B = x.shape[0]
+    q, k_new, v_new = _project_qkv(p, x, x, cfg, plan, pos[:, None],
+                                   pos[:, None])
+    q = q[:, 0]                                             # (B, H, hd)
+    k_new, v_new = k_new.astype(kv_dtype), v_new.astype(kv_dtype)
+    own = flash_decode_partial_ref(q, k_new.astype(q.dtype),
+                                   v_new.astype(q.dtype))
+    m, l, acc = (jnp.stack(pair) for pair in zip(cached(q), own))
+    out = combine_partials(m, l, acc).astype(x.dtype)
+    y_partial = out.reshape(B, 1, -1) @ p["wo"].astype(x.dtype)
+    return y_partial, k_new[:, 0], v_new[:, 0]
+
+
 # ---------------------------------------------------------------------------
 # MLA (DeepSeek multi-head latent attention)
 # ---------------------------------------------------------------------------

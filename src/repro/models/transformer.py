@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.kernels.softmax_xent.ref import combine_stats, local_stats_ref
 from repro.models.attention import (
-    gqa_decode, gqa_forward, gqa_specs, init_gqa, init_mla,
+    gqa_decode, gqa_decode_paged, gqa_forward, gqa_specs, init_gqa, init_mla,
     kv_to_seq_sharded, mla_decode, mla_forward, mla_specs, q_heads_local)
 from repro.models.common import (MeshPlan, certified_pmean, dense_init,
                                  force_vary, rms_norm, split_keys)
@@ -192,14 +192,22 @@ def apply_block(p, x, cfg: ModelConfig, plan: MeshPlan, kind: str,
 
 
 def decode_block(p, x, cache, pos, cfg: ModelConfig, plan: MeshPlan,
-                 kind: str, mlp_kind: str, sliding_window: int = 0):
-    """Single-token step. Returns (x, new_cache, moe_counts)."""
+                 kind: str, mlp_kind: str, sliding_window: int = 0,
+                 attend=None):
+    """Single-token step. Returns (x, new_cache, moe_counts).
+
+    ``attend(p_attn, h) -> (out_partial, k_new, v_new)`` replaces the
+    attention over ``cache`` (a cache read in place: ``new_cache`` then
+    holds only the new position's ``k``/``v``)."""
     psum = (lambda v: jax.lax.psum(v, plan.model_axis)) if plan.tp > 1 \
         else (lambda v: v)
     h = rms_norm(x, p["ln1"].astype(x.dtype), cfg.norm_eps)
     counts = no_moe_counts()
     new_cache = dict(cache)
-    if kind == "attn":
+    if kind == "attn" and attend is not None:
+        a, new_cache["k"], new_cache["v"] = attend(p["attn"], h)
+        x = x + psum(a)
+    elif kind == "attn":
         if cfg.use_mla:
             a, c, kpe = mla_decode(p["attn"], h, cache["c"], cache["kpe"],
                                    pos, cfg, plan, sliding_window)
@@ -626,6 +634,56 @@ def decode_stack_slice(params, caches, x, pos, cfg: ModelConfig,
         (x, counts), new_body = jax.lax.scan(
             one_period, (x, counts),
             (jnp.arange(*periods), caches["body"]))
+    return x, {"prologue": new_pro, "body": new_body}, counts
+
+
+def decode_stack_slice_slabs(params, slabs, x, pos, cfg: ModelConfig,
+                             plan: MeshPlan, pro_kinds,
+                             periods: Tuple[int, int], attend):
+    """:func:`decode_stack_slice` over GQA page slabs read in place.
+
+    ``slabs`` holds each block's ``{"k", "v"}`` slabs as stored: ``(num_pages,
+    page_len, KV, hd)`` per prologue block, stacked ``(P, ...)`` over the
+    slice's periods per body slot. ``attend(q, k_slab, v_slab, layer)``
+    gives the partial of the ``(B, H, hd)`` queries over a layer's cached
+    positions, ``layer`` indexing the slab's leading axis. Returns ``(x,
+    new_kv, moe_counts)``, ``new_kv`` the tree of the new position's
+    entries: ``(B, KV, hd)`` per prologue leaf, ``(P, B, KV, hd)`` per body
+    leaf.
+    """
+    lay = stack_layout(cfg)
+
+    def block(p_blk, x, slab, layer, kind, mlp_kind):
+        def att(p_attn, h):
+            return gqa_decode_paged(
+                p_attn, h, pos, cfg, plan,
+                lambda q: attend(q, slab["k"], slab["v"], layer),
+                slab["k"].dtype)
+        return decode_block(p_blk, x, slab, pos, cfg, plan, kind, mlp_kind,
+                            attend=att)
+
+    counts = no_moe_counts()
+    new_pro = []
+    for p_blk, slab, (kind, mlp_kind) in zip(params["prologue"],
+                                             slabs["prologue"], pro_kinds):
+        x, kv, n = block(p_blk, x, {k: a[None] for k, a in slab.items()},
+                         0, kind, mlp_kind)
+        counts = counts + n
+        new_pro.append(kv)
+    new_body = []
+    if periods[1] > periods[0]:
+        def one_period(carry, i):
+            x, counts = carry
+            p_stk = _period_params(params["body"], i)
+            new_kv = []
+            for j, (kind, mlp_kind) in enumerate(lay.period_slots):
+                x, kv, n = block(p_stk[j], x, slabs["body"][j],
+                                 i - periods[0], kind, mlp_kind)
+                counts = counts + n
+                new_kv.append(kv)
+            return (x, counts), new_kv
+        (x, counts), new_body = jax.lax.scan(
+            one_period, (x, counts), jnp.arange(*periods))
     return x, {"prologue": new_pro, "body": new_body}, counts
 
 

@@ -13,12 +13,18 @@ reservation with the paper's preallocated-register discipline:
   per-request state (SSM ``h``, conv tails) lives in a
   ``(max_requests, *feat)`` row pool indexed by slot id.
 * **Host plans, device executes.** Page allocation/free/refcounting is
-  driver-side numpy bookkeeping (:class:`PagePool`); the stage only ever
-  runs three jitted fixed-shape programs — gather a slot group's windows
-  into the dense layout the unchanged stage decode program expects, scatter
-  the one written position back, scatter a freshly prefilled request into
-  its pages. One stage program therefore serves any mix of request lengths.
-* **Bit identity with the dense path.** A gathered window agrees with the
+  host-side numpy bookkeeping (:class:`PagePool`); the stage only runs
+  jitted fixed-shape programs, so one stage program serves any mix of
+  request lengths. A stage whose cache holds only GQA ``k``/``v`` (tp = 1,
+  no sliding window) on the TPU decodes with ``ServeStage.decode_slabs``:
+  a paged flash-decode reads each live slot's pages through its table row
+  where they lie, and the program returns only the new positions. Every
+  other stage, and every chunked-prefill step, gathers a slot group's
+  windows into the dense layout the stage's decode program takes. Either
+  way a scatter writes the one new position back, and another writes a
+  freshly prefilled request into its pages.
+* **Bit identity with the dense path** (the gathering path; the in-place
+  one agrees within rounding). A gathered window agrees with the
   dense group cache at every position a live request's decode can observe:
   positions ``<= pos`` hold the identical prefill/decode writes, positions
   ``> pos`` are masked by the attention kernels (finite values, exactly
@@ -242,17 +248,16 @@ class PagePool:
 
 class PagedStageCache:
     """One stage's paged serving state: the page slabs + row pools, and the
-    jitted gather/scatter programs that bridge them to the unchanged dense
-    stage programs. Built lazily (like the dense per-group caches) in
-    whichever worker owns the stage.
+    jitted gather/scatter programs around the stage programs. Built lazily
+    (like the dense per-group caches) in whichever worker owns the stage.
 
     Every scatter takes ``self.slabs`` by donation and updates it in place;
     ``self.slabs`` is then the scatter's output. Two invariants make that
     safe: one actor owns each stage's cache, so fires of one stage never
     overlap; and nothing may keep a slab leaf across a scatter (a kept
-    leaf is deleted). A fire's gather reads the slabs before its scatter
-    is dispatched, on the same device queue, so the donation waits for
-    it."""
+    leaf is deleted). A fire's gather or in-place decode program reads the
+    slabs before its scatter is dispatched, on the same device queue, so
+    the donation waits for it."""
 
     def __init__(self, stage, group_size: int, cache_len: int,
                  spec: PagedCacheSpec):
@@ -288,26 +293,43 @@ class PagedStageCache:
     # -- the three work kinds ---------------------------------------------
 
     def run_decode(self, work, xin):
-        """Gather the group's windows, run the unchanged dense decode
-        program, scatter back the one position each live slot wrote (plus
-        the full per-request state rows)."""
+        """One decode fire; the scatter writes the one position each live
+        slot wrote (plus the full per-request state rows) into the slabs.
+
+        Where the stage has ``decode_slabs`` (GQA ``k``/``v`` caches on the
+        TPU) its program reads the slabs in place through the table rows
+        and returns only the new positions. Elsewhere (MLA latents, row-pool
+        state, other platforms) the group's windows are gathered into the
+        dense layout the stage's ``decode`` program takes, and the scatter
+        reads the new positions out of the window it returns. Each path
+        has its own scatter program (``scatter_decode_new``,
+        ``scatter_decode``)."""
         import jax
         from jax.profiler import TraceAnnotation
 
         from repro.core.lowering import split_moe_counts
 
         self._ensure()
-        with TraceAnnotation("stage.gather", kind="decode"):
-            window = self._fns["gather"](self.slabs, work.rows, work.sids)
-        with TraceAnnotation("stage.program", kind="decode"):
-            xout, new_window = split_moe_counts(
-                self.stage, self.stage.decode(self.stage.params, window,
-                                              xin, work.pos))
+        stage = self.stage
+        if stage.decode_slabs is not None:
+            with TraceAnnotation("stage.program", kind="decode"):
+                xout, new = split_moe_counts(stage, stage.decode_slabs(
+                    stage.params, self.slabs, work.rows, work.sids, xin,
+                    work.pos))
+            scatter = self._fns["scatter_decode_new"]
+        else:
+            with TraceAnnotation("stage.gather", kind="decode"):
+                window = self._fns["gather"](self.slabs, work.rows,
+                                             work.sids)
+            with TraceAnnotation("stage.program", kind="decode"):
+                xout, new = split_moe_counts(stage, stage.decode(
+                    stage.params, window, xin, work.pos))
+            scatter = self._fns["scatter_decode"]
         with TraceAnnotation("stage.sync", kind="decode"):
             xout = jax.block_until_ready(xout)
         with TraceAnnotation("stage.scatter", kind="decode"):
-            self.slabs = self._fns["scatter_decode"](
-                self.slabs, work.rows, work.sids, work.pos, new_window)
+            self.slabs = scatter(self.slabs, work.rows, work.sids, work.pos,
+                                 new)
         return xout
 
     def write_prefill(self, work, slot_caches) -> None:
@@ -394,42 +416,59 @@ def _build_paged_ops(spec: PagedCacheSpec, group_size: int, cache_len: int):
                             mode="fill", fill_value=0)
         return map_cache_tree(slabs, g)
 
-    def scatter_decode(slabs, rows, sids, pos, window):
-        rows = jnp.asarray(rows, jnp.int32)
-        sids = jnp.asarray(sids, jnp.int32)
-        pos = jnp.asarray(pos, jnp.int32)
-        b = jnp.arange(B)
-        page = rows[b, pos // pl]                        # (B,)
-        ok = (page >= 0) & (sids >= 0)
-        page = jnp.where(ok, page, 0)
-        off = pos % pl
-        sid_idx = jnp.where(sids >= 0, sids, mr)         # OOB -> drop
-        zero = jnp.zeros((), jnp.int32)
+    def make_scatter_decode(value_at):
+        """The decode scatter, writing each live slot's one new position
+        in place; ``value_at(new, i, lead, pos_i, feat, size)`` is slot
+        ``i``'s entry of a positional leaf of the decode program's output
+        ``new``, shaped like a one-position slice of the slab."""
+        def scatter_decode(slabs, rows, sids, pos, new):
+            rows = jnp.asarray(rows, jnp.int32)
+            sids = jnp.asarray(sids, jnp.int32)
+            pos = jnp.asarray(pos, jnp.int32)
+            b = jnp.arange(B)
+            page = rows[b, pos // pl]                        # (B,)
+            ok = (page >= 0) & (sids >= 0)
+            page = jnp.where(ok, page, 0)
+            off = pos % pl
+            sid_idx = jnp.where(sids >= 0, sids, mr)         # OOB -> drop
+            zero = jnp.zeros((), jnp.int32)
 
-        def sc(k, slab, win, stacked):
-            if k in POSITIONAL_KEYS:
-                # each slot's position written in place on the slab itself:
-                # a scatter into a flattened view lets the compiler relayout
-                # (copy) a slab whose feature width is no multiple of 128;
-                # parked and unmapped slots write back what is there
-                lead = (zero,) if stacked else ()
-                feat = (zero,) * (slab.ndim - len(lead) - 2)
-                size = slab.shape[:len(lead)] + (1, 1) + slab.shape[
-                    len(lead) + 2:]
-                for i in range(B):
-                    start = lead + (page[i], off[i]) + feat
-                    cur = jax.lax.dynamic_slice(slab, start, size)
-                    new = jax.lax.dynamic_slice(
-                        win, lead + (jnp.int32(i), pos[i]) + feat, size)
-                    slab = jax.lax.dynamic_update_slice(
-                        slab, jnp.where(ok[i], new.astype(slab.dtype), cur),
-                        start)
-                return slab
-            if stacked:
-                return slab.at[:, sid_idx].set(win.astype(slab.dtype),
-                                               mode="drop")
-            return slab.at[sid_idx].set(win.astype(slab.dtype), mode="drop")
-        return map2_cache_tree(slabs, window, sc)
+            def sc(k, slab, win, stacked):
+                if k in POSITIONAL_KEYS:
+                    # each slot's position written in place on the slab
+                    # itself: a scatter into a flattened view lets the
+                    # compiler relayout (copy) a slab whose feature width is
+                    # no multiple of 128; parked and unmapped slots write
+                    # back what is there
+                    lead = (zero,) if stacked else ()
+                    feat = (zero,) * (slab.ndim - len(lead) - 2)
+                    size = slab.shape[:len(lead)] + (1, 1) + slab.shape[
+                        len(lead) + 2:]
+                    for i in range(B):
+                        start = lead + (page[i], off[i]) + feat
+                        cur = jax.lax.dynamic_slice(slab, start, size)
+                        val = value_at(win, i, lead, pos[i], feat, size)
+                        slab = jax.lax.dynamic_update_slice(
+                            slab, jnp.where(ok[i], val.astype(slab.dtype),
+                                            cur), start)
+                    return slab
+                if stacked:
+                    return slab.at[:, sid_idx].set(win.astype(slab.dtype),
+                                                   mode="drop")
+                return slab.at[sid_idx].set(win.astype(slab.dtype),
+                                            mode="drop")
+            return map2_cache_tree(slabs, new, sc)
+        return scatter_decode
+
+    # ``new`` is the whole window the dense decode program returns ...
+    scatter_decode = make_scatter_decode(
+        lambda win, i, lead, p, feat, size: jax.lax.dynamic_slice(
+            win, lead + (jnp.int32(i), p) + feat, size))
+    # ... or (``decode_slabs``) only the new positions, one axis fewer
+    scatter_decode_new = make_scatter_decode(
+        lambda new, i, lead, p, feat, size: jax.lax.expand_dims(
+            jax.lax.index_in_dim(new, i, len(lead)), (len(lead) + 1,)))
+    scatter_decode_new.__name__ = "scatter_decode_new"
 
     def scatter_prefill(slabs, write_row, sid, slot_caches):
         write_row = jnp.asarray(write_row, jnp.int32)
@@ -500,5 +539,7 @@ def _build_paged_ops(spec: PagedCacheSpec, group_size: int, cache_len: int):
 
     return {"gather": jax.jit(gather),
             "scatter_decode": jax.jit(scatter_decode, donate_argnums=(0,)),
+            "scatter_decode_new": jax.jit(scatter_decode_new,
+                                          donate_argnums=(0,)),
             "scatter_prefill": jax.jit(scatter_prefill, donate_argnums=(0,)),
             "scatter_chunk": scatter_chunk}

@@ -34,6 +34,8 @@ SMALL_KERNELS = {
                             head_dim=16),
     "flash_decode": dict(batch=2, cache=48, heads=4, kv_heads=2,
                          head_dim=16),
+    "flash_decode_paged": dict(cache=48, page_len=8, layers=2, heads=4,
+                               kv_heads=2, head_dim=16),
     "softmax_xent": dict(rows=16, vocab=300),
     "ssd_scan": dict(batch=1, seq=48, heads=2, head_dim=8, state=16,
                      chunk=16),
@@ -55,6 +57,35 @@ def test_serve_phase_reduced(smoke):
     assert len({g for _, g in reqs}) == len(reqs)      # unequal lengths
     outs = smoke.phase_serve(cfg, reqs, shape=SMALL_SERVE)
     assert [len(o) for o in outs] == [g for _, g in reqs]
+
+
+def test_serve_phase_reduced_in_place(smoke, monkeypatch):
+    """With the paged flash-decode in interpret mode (as on the TPU) a
+    paged session compiled to gather must match the dense tokens exactly,
+    and one that reads the slabs in place, fed its tokens, keeps its
+    logits within the limit."""
+    import functools
+
+    from repro.core import lowering
+    from repro.kernels.flash_decode.kernel import flash_decode_paged_pallas
+    monkeypatch.setattr(lowering, "paged_decode_kernel", lambda platform:
+                        functools.partial(flash_decode_paged_pallas,
+                                          interpret=True))
+    seen = []
+    serve_once = smoke._serve_once
+
+    def spy(*a, **k):
+        out = serve_once(*a, **k)
+        seen.append(out[2].get("decode_fires_paged"))
+        return out
+    monkeypatch.setattr(smoke, "_serve_once", spy)
+    cfg = get_config("qwen3-1.7b").reduced()
+    reqs = smoke.make_requests(cfg.vocab_size, n=4, min_prompt=4,
+                               max_prompt=16, max_new_tokens=5)
+    outs = smoke.phase_serve(cfg, reqs, shape=SMALL_SERVE)
+    assert [len(o) for o in outs] == [g for _, g in reqs]
+    assert seen[0] is None and seen[1] == 0 and seen[2] > 0
+    assert lowering.paged_decode_kernel("cpu") is not None   # restored
 
 
 def test_train_phase_reduced(smoke):

@@ -1,5 +1,7 @@
 """Pallas kernel tests: shape/dtype sweeps vs the pure-jnp oracles
 (interpret=True executes the kernel bodies on CPU)."""
+import functools
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -8,9 +10,12 @@ from numpy.testing import assert_allclose
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.flash_attention.ref import (attention_dense_ref,
                                                flash_attention_ref)
-from repro.kernels.flash_decode.kernel import flash_decode_pallas
+from repro.kernels.flash_decode import kernel as flash_decode_kernel
+from repro.kernels.flash_decode.kernel import (flash_decode_paged_pallas,
+                                               flash_decode_pallas)
 from repro.kernels.flash_decode.ref import (
-    NEG_INF, combine_partials, decode_attention_ref, flash_decode_partial_ref)
+    NEG_INF, combine_partials, decode_attention_ref, flash_decode_paged_ref,
+    flash_decode_partial_ref)
 from repro.kernels.softmax_xent.kernel import xent_local_stats_pallas
 from repro.kernels.softmax_xent.ref import (combine_stats, local_stats_ref,
                                             softmax_xent_ref)
@@ -201,6 +206,116 @@ def test_grouped_decode_sequence_shards_combine(G):
     want = combine_partials(*(a[None] for a in _repeat_partial_ref(
         q, k, v, cur_pos=cur)))
     assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6)
+
+
+def _paged_table(page_len, pages_per_req, num_pages, rng):
+    """Five slots' table rows and lengths over a pool of ``num_pages``:
+    slot 0 parked (length 0, no row), slot 1 ends on a page boundary,
+    slot 2 shares slot 1's first two pages and ends one past a boundary,
+    slot 3 fills the window but its last position (``cache_len - 1``),
+    slot 4 has an unmapped page inside its length. Entries past a length
+    are ``-1``, or stale pages that must not be read."""
+    L = page_len * pages_per_req
+    rows = np.full((5, pages_per_req), -1, np.int32)
+    free = list(rng.permutation(num_pages))
+    lengths = np.array([0, 2 * page_len, 2 * page_len + 1, L - 1,
+                        L - page_len // 2], np.int32)
+    for b in range(1, 5):
+        n = -(-int(lengths[b]) // page_len)
+        rows[b, :n] = [free.pop() for _ in range(n)]
+    rows[2, :2] = rows[1, :2]                          # shared prefix pages
+    rows[1, 2] = free.pop()                            # stale, past length
+    rows[4, 1] = -1                                    # unmapped inside
+    return rows, lengths
+
+
+#: bfloat16 slabs: the paged kernel rounds the softmax weights to V's dtype
+#: before P.V, where ``flash_decode_partial_ref`` keeps them in float32.
+#: Largest output gap over these cases (three table seeds): 0.0018; with
+#: the weights rounded to float8 e4m3 instead, 0.031; kept in float32,
+#: 2.4e-7 (interpret mode on the CPU).
+PAGED_BF16_OUT_ATOL = 6e-3
+
+
+def _attn_out(part):
+    m, l, acc = part
+    return np.asarray(acc) / np.maximum(np.asarray(l), 1e-30)[..., None]
+
+
+@pytest.mark.parametrize("pages_per_block", [2, None])
+@pytest.mark.parametrize("page_len", [16, 6])
+@pytest.mark.parametrize("G", [1, 2, 8])
+def test_paged_decode_partials_equal_gathered_window(G, page_len,
+                                                     pages_per_block,
+                                                     monkeypatch):
+    """The paged flash-decode kernel, reading (P, num_pages, page_len, KV,
+    D) slabs in place through table rows, and its jnp reference give the
+    partials of ``flash_decode_partial_ref`` over the window gathered
+    through the same rows: positions < length on mapped pages, none for a
+    parked slot (where the kernel returns ``l = acc = 0``). Blocks of two
+    pages (several blocks a slot, the last one partial) and of every page
+    of a row."""
+    _check_paged_decode(G, page_len, pages_per_block, jnp.float32,
+                        monkeypatch)
+
+
+@pytest.mark.parametrize("pages_per_block", [2, None])
+@pytest.mark.parametrize("page_len", [16, 6])
+@pytest.mark.parametrize("G", [1, 2, 8])
+def test_paged_decode_bf16_slabs_within_weight_rounding(G, page_len,
+                                                        pages_per_block,
+                                                        monkeypatch):
+    """The same cases over bfloat16 slabs, against the reference at the
+    slabs' dtype: the kernel's ``acc`` carries its bfloat16 softmax
+    weights, so its output ``acc / l`` is held to
+    :data:`PAGED_BF16_OUT_ATOL`; ``m`` and ``l`` stay exact to float32, and
+    the jnp reference to every partial."""
+    _check_paged_decode(G, page_len, pages_per_block, jnp.bfloat16,
+                        monkeypatch)
+
+
+def _check_paged_decode(G, page_len, pages_per_block, dtype, monkeypatch):
+    KV, D, P, R = 2, 16, 3, 5
+    H, N, layer = KV * G, 40, 1
+    if pages_per_block:
+        monkeypatch.setattr(flash_decode_kernel, "BLOCK_ROWS",
+                            pages_per_block * page_len * KV)
+    k = jnp.asarray(RNG.normal(size=(P, N, page_len, KV, D)), dtype)
+    v = jnp.asarray(RNG.normal(size=(P, N, page_len, KV, D)), dtype)
+    q = jnp.asarray(RNG.normal(size=(5, H, D)), dtype)
+    rows, lengths = _paged_table(page_len, R, N, RNG)
+
+    t = np.arange(page_len * R)
+    page = rows[:, t // page_len]
+    flat = np.maximum(page, 0) * page_len + t % page_len
+    win_k = k[layer].reshape(N * page_len, KV, D)[flat]
+    win_v = v[layer].reshape(N * page_len, KV, D)[flat]
+    kpos = np.where((page >= 0) & (t < lengths[:, None]), t, -1)
+    want = flash_decode_partial_ref(q, win_k, win_v,
+                                    k_positions=jnp.asarray(kpos))
+    live = lengths > 0
+    own = flash_decode_partial_ref(q, win_k[:, :1], win_v[:, :1])
+    for fn, exact in ((flash_decode_paged_ref, True),
+                      (functools.partial(flash_decode_paged_pallas,
+                                         interpret=True),
+                       dtype == jnp.float32)):
+        atol = 1e-5 if exact else PAGED_BF16_OUT_ATOL
+        got = fn(q, k, v, jnp.asarray(rows), jnp.asarray(lengths), layer)
+        assert all(g.dtype == jnp.float32 for g in got)
+        for g, w in zip(got[:3 if exact else 2], want):
+            assert_allclose(np.asarray(g)[live], np.asarray(w)[live],
+                            rtol=1e-5, atol=1e-5)
+        assert_allclose(_attn_out(got)[live], _attn_out(want)[live],
+                        rtol=1e-5, atol=atol)
+        assert not np.asarray(got[1])[~live].any()
+        assert not np.asarray(got[2])[~live].any()
+        # joined with a further partial, as the decode joins the new
+        # token's own, every slot gives the same output (the parked one too)
+        joined = [combine_partials(*(jnp.stack(pair)
+                                     for pair in zip(part, own)))
+                  for part in (got, want)]
+        assert_allclose(np.asarray(joined[0]), np.asarray(joined[1]),
+                        rtol=1e-5, atol=atol)
 
 
 # ---------------------------------------------------------------------------

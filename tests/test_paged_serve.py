@@ -1,6 +1,7 @@
 """Paged-cache serving tests: the page pool, gather/scatter bit-identity
-against the dense backend, chunked prefill, shared-prefix refcounting, and
-the analytic cache-bytes accounting.
+against the dense backend, chunked prefill, shared-prefix refcounting, the
+analytic cache-bytes accounting, and the in-place GQA decode (the TPU's
+path) against the gather path.
 
 The dense PR-5 path is the bit-identity reference: greedy decode through
 ``cache="paged"`` must emit the exact token streams of ``cache="dense"``
@@ -421,3 +422,147 @@ class TestDonationOrdering:
         # ... and chunked prefills after decodes have run
         first_decode = steps.index(("run_decode", 0))
         assert any(s[0] == "run_chunk" for s in steps[first_decode:])
+
+
+class TestSlabsReadInPlace:
+    """On the TPU a GQA stage's paged decode reads the page slabs in place
+    through the table rows (``ServeStage.decode_slabs``, the Pallas paged
+    flash-decode) and returns only the new positions; every other stage
+    gathers a dense window. Here the kernel runs in interpret mode on the
+    CPU, where a stage would otherwise gather."""
+
+    @staticmethod
+    def _forced(mp):
+        import functools
+
+        from repro.core import lowering
+        from repro.kernels.flash_decode.kernel import \
+            flash_decode_paged_pallas
+
+        mp.setattr(lowering, "paged_decode_kernel", lambda platform:
+                   functools.partial(flash_decode_paged_pallas,
+                                     interpret=True))
+
+    @staticmethod
+    def _run(sess, reqs):
+        """Generate, keeping every round's work and results."""
+        rounds = []
+        run_round = sess.executor.run_round
+
+        def logged(work, **kw):
+            out = run_round(work, **kw)
+            rounds.append((list(work), out))
+            return out
+        sess.executor.run_round = logged
+        return sess.generate(reqs), rounds
+
+    @pytest.mark.parametrize("backend", ["monolithic", "actors"])
+    def test_in_place_matches_gathered(self, serve_env, backend):
+        """Over a multi-round ``generate`` (two slot groups, admission mid
+        flight, chunked prefills between decodes) the in-place path gives
+        the gather path's greedy tokens, and every round's logits within
+        float32 rounding. ``decode_fires_paged`` counts every stage decode
+        fire of the in-place session, ``decode_fires_gathered`` every one
+        of the other."""
+        from repro.runtime.pipeline import DecodeWork
+
+        cfg, mesh, params, prompts = serve_env
+        rng = np.random.default_rng(5)
+        long = rng.integers(0, cfg.vocab_size, (12,)).astype(np.int32)
+        reqs = list(zip(prompts, GENS)) + [(long, 4)]
+        stages = 1 if backend == "monolithic" else 2
+        kw = dict(backend=backend, stages=stages, cache="paged",
+                  page_len=PAGE_LEN, prefill_chunk=8,
+                  **_kw(params, mesh, group_size=2, max_prompt_len=12,
+                        cache_len=20))
+        runs = {}
+        for path in ("gathered", "paged"):
+            with pytest.MonkeyPatch.context() as mp:
+                if path == "paged":
+                    self._forced(mp)
+                sess = api.compile(cfg, mode="serve", **kw)
+            with sess:
+                assert all((st.decode_slabs is not None) == (path == "paged")
+                           for st in sess.sstaged.stages)
+                runs[path] = self._run(sess, reqs) + (dict(sess.last_stats),)
+        (want, want_rounds, want_stats), (got, got_rounds, stats) = (
+            runs["gathered"], runs["paged"])
+        for i, (g, w) in enumerate(zip(got, want)):
+            assert np.array_equal(g, w), f"request {i}: {g} != {w}"
+        assert len(got_rounds) == len(want_rounds) > 3
+        for (work, outs), (_, ref) in zip(got_rounds, want_rounds):
+            for w, o, r in zip(work, outs, ref):
+                # a parked slot's logits are never read: live slots only
+                live = (np.asarray(w.sids) >= 0
+                        if isinstance(w, DecodeWork) else slice(None))
+                np.testing.assert_allclose(np.asarray(o)[live],
+                                           np.asarray(r)[live],
+                                           rtol=1e-4, atol=1e-4)
+        fires = stages * sum(isinstance(w, DecodeWork)
+                             for work, _ in got_rounds for w in work)
+        assert fires > 0
+        assert (stats["decode_fires_paged"],
+                stats["decode_fires_gathered"]) == (fires, 0)
+        assert (want_stats["decode_fires_paged"],
+                want_stats["decode_fires_gathered"]) == (0, fires)
+
+    def test_prologue_and_expert_stack_in_place(self):
+        """A GQA stack with a dense prologue layer and expert layers (a
+        DeepSeek-shaped stack without latent attention) reads the slabs in
+        place on both stages: the prologue's unstacked slab and the body's
+        stacked one give the gather path's tokens, and the expert counters
+        of the call are the gather path's."""
+        cfg = dataclasses.replace(get_config("deepseek-v2-lite-16b").reduced(),
+                                  use_mla=False, num_layers=3)
+        mesh = make_mesh((1, 1), ("data", "model"))
+        params = build_model(cfg, plan_from_mesh(mesh)).init(
+            jax.random.PRNGKey(0))
+        rng = np.random.default_rng(7)
+        reqs = [(rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32), g)
+                for n, g in ((6, 4), (8, 3), (5, 4))]
+        kw = dict(backend="actors", stages=2, cache="paged",
+                  page_len=PAGE_LEN, params=params, mesh=mesh, num_groups=1,
+                  group_size=2, max_prompt_len=8, max_new_tokens=4,
+                  cache_len=CACHE_LEN)
+        outs, stats = [], []
+        for forced in (False, True):
+            with pytest.MonkeyPatch.context() as mp:
+                if forced:
+                    self._forced(mp)
+                sess = api.compile(cfg, mode="serve", **kw)
+            with sess:
+                assert [st.decode_slabs is not None
+                        for st in sess.sstaged.stages] == [forced] * 2
+                assert len(sess.sstaged.stages[0].params["prologue"]) == 1
+                assert any(st.moe for st in sess.sstaged.stages)
+                outs.append(sess.generate(reqs))
+                stats.append(dict(sess.last_stats))
+        for i, (g, w) in enumerate(zip(outs[1], outs[0])):
+            assert np.array_equal(g, w), f"request {i}: {g} != {w}"
+        moe = [k for k in stats[0] if k.startswith("moe_")]
+        assert moe and all(stats[1][k] == stats[0][k] for k in moe)
+        assert stats[1]["decode_fires_paged"] == stats[0][
+            "decode_fires_gathered"] > 0
+
+    def test_latent_attention_always_gathers(self):
+        """MLA's latent cache has no paged kernel: with the kernel on hand,
+        a DeepSeek-shaped stack still builds no in-place program and every
+        decode fire gathers."""
+        cfg = get_config("deepseek-v2-lite-16b").reduced()
+        mesh = make_mesh((1, 1), ("data", "model"))
+        params = build_model(cfg, plan_from_mesh(mesh)).init(
+            jax.random.PRNGKey(0))
+        rng = np.random.default_rng(6)
+        reqs = [(rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32), g)
+                for n, g in ((6, 3), (8, 2))]
+        with pytest.MonkeyPatch.context() as mp:
+            self._forced(mp)
+            sess = api.compile(cfg, mode="serve", backend="monolithic",
+                               cache="paged", page_len=PAGE_LEN,
+                               params=params, mesh=mesh, num_groups=1,
+                               group_size=2, max_prompt_len=8,
+                               max_new_tokens=3, cache_len=CACHE_LEN)
+        assert all(st.decode_slabs is None for st in sess.sstaged.stages)
+        sess.generate(reqs)
+        assert sess.last_stats["decode_fires_paged"] == 0
+        assert sess.last_stats["decode_fires_gathered"] > 0

@@ -1,4 +1,4 @@
-"""Compile the four Pallas kernels, the paged cache's scatter programs,
+"""Compile the Pallas kernels, the paged cache's scatter programs,
 serve stage programs and the grouped expert form for a described TPU v5e
 (no chip needed).
 
@@ -22,7 +22,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
-from repro.kernels.flash_decode.kernel import flash_decode_pallas
+from repro.kernels.flash_decode.kernel import (flash_decode_paged_pallas,
+                                               flash_decode_pallas)
 from repro.kernels.softmax_xent.kernel import xent_local_stats_pallas
 from repro.kernels.ssd_scan.kernel import ssd_scan_pallas
 
@@ -73,6 +74,23 @@ def test_flash_decode_compiles_qwen3_cache(one_chip):
              ((4, 4096, 8, 128), bf), ((4,), jnp.int32), sharding=one_chip)
 
 
+def test_flash_decode_paged_compiles_qwen3_slabs(one_chip):
+    """The paged flash-decode at the qwen3-1.7b cell's sizes (a stage's 14
+    layers of 1224 pages of 16 positions, 8 KV heads of 128, 4 slots of
+    153 table entries) reads the slabs as they are: the row view is a
+    bitcast, and no slab is copied."""
+    bf = jnp.bfloat16
+    slab = (14, 1224, 16, 8, 128)
+    compiled = _compile(flash_decode_paged_pallas, ((4, 16, 128), bf),
+                        (slab, bf), (slab, bf), ((4, 153), jnp.int32),
+                        ((4,), jnp.int32), ((), jnp.int32),
+                        sharding=one_chip)
+    text = compiled.as_text()
+    assert not re.search(r"bf16\[%s\][^=]*copy\(" % ",".join(map(str, slab)),
+                         text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
 def test_softmax_xent_compiles_vocab_slice(one_chip):
     def fn(logits, labels, off):
         return xent_local_stats_pallas(logits, labels, off)
@@ -89,11 +107,13 @@ def test_ssd_scan_compiles_mamba2_370m(one_chip):
              sharding=one_chip)
 
 
-@pytest.mark.parametrize("kind", ["decode", "prefill", "chunk"])
+@pytest.mark.parametrize("kind", ["decode", "prefill", "chunk",
+                                  "decode_new"])
 def test_paged_scatter_updates_slabs_in_place(one_chip, kind):
     """At the qwen3-1.7b benchmark cell's sizes (one stage of 14 layers,
     2 x 4 slots, cache 2448 in pages of 16), each scatter aliases the
-    donated slabs to its output and copies no whole slab."""
+    donated slabs to its output and copies no whole slab (``decode_new``:
+    the scatter of an in-place decode's new positions alone)."""
     from repro.serve.paged_cache import PagedCacheSpec, _build_paged_ops
 
     P, B, L, KV, HD = 14, 4, 2448, 8, 128
@@ -113,6 +133,10 @@ def test_paged_scatter_updates_slabs_in_place(one_chip, kind):
                                         "v": sds((P, B, L, KV, HD))}]}
     if kind == "decode":
         lowered = fns["scatter_decode"].lower(slabs, rows, vec, vec, window)
+    elif kind == "decode_new":
+        new = {"prologue": [], "body": [{"k": sds((P, B, KV, HD)),
+                                         "v": sds((P, B, KV, HD))}]}
+        lowered = fns["scatter_decode_new"].lower(slabs, rows, vec, vec, new)
     elif kind == "prefill":
         slot = {"prologue": [], "body": [{"k": sds((P, 1, L, KV, HD)),
                                           "v": sds((P, 1, L, KV, HD))}]}
@@ -170,6 +194,7 @@ def test_deepseek_stage_decode_compiles_at_published_widths(one_chip,
     tok = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=rep)
     for st in sstaged.stages:
         assert st.moe
+        assert st.decode_slabs is None      # the latent window is gathered
         window = jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
             jax.eval_shape(st.init_caches, tok))
@@ -236,6 +261,58 @@ def test_qwen3_stage_decode_reads_the_unexpanded_window(one_chip,
         text = st.decode.lower(st.params, window, xin,
                                tok).compile().as_text()
         assert not expanded.search(text), expanded.search(text).group(0)
+
+
+def test_qwen3_paged_stage_decode_reads_slabs_in_place(one_chip,
+                                                      monkeypatch):
+    """qwen3-1.7b at the benchmark cell's sizes (2 stages, 4 slots, cache
+    2448 in 153 pages of 16, a pool of 1224 pages): on a v5e chip each
+    stage has an in-place paged decode program, which compiles with the
+    paged flash-decode's Mosaic call inside ``local_decode_s{n}``. No op
+    or output has a slot group's window shape (``[4,2448,8,128]``,
+    stacked or not): nothing gathers a window and nothing returns one."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.configs.registry import get_config
+    from repro.core.lowering import lower_serve_stages
+    from repro.models.common import MeshPlan
+    from repro.models.model_zoo import build_model
+
+    cfg = get_config("qwen3-1.7b")
+    B, L, pages = 4, 2448, 1224
+    mesh = Mesh(np.array(list(one_chip.device_set)).reshape(1, 1),
+                ("data", "model"))
+    rep = NamedSharding(mesh, P())
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16),
+        jax.eval_shape(build_model(cfg, MeshPlan.single_device()).init,
+                       jax.random.PRNGKey(0)))
+    # a described device holds no arrays: placing the params gives shapes
+    monkeypatch.setattr(jax, "device_put", lambda x, s: jax.tree.map(
+        lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        x, s))
+    sstaged = lower_serve_stages(cfg, mesh, params, num_stages=2,
+                                 cache_len=L, max_prompt_len=2048,
+                                 group_size=B)
+    vec = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=rep)
+    rows = jax.ShapeDtypeStruct((B, L // 16), jnp.int32, sharding=rep)
+    window = re.compile(r"\w+\[(\d+,)*4,2448,8,128\]")
+    for st in sstaged.stages:
+        periods = st.units[1] - st.units[0]    # no prologue: one a layer
+        slab = jax.ShapeDtypeStruct((periods, pages, 16, 8, 128),
+                                    jnp.bfloat16, sharding=rep)
+        slabs = {"prologue": [], "body": [{"k": slab, "v": slab}]}
+        xin = vec if st.first else jax.ShapeDtypeStruct(
+            (B, 1, cfg.d_model), jnp.bfloat16, sharding=rep)
+        lowered = st.decode_slabs.lower(st.params, slabs, rows, vec, xin,
+                                        vec)
+        text = lowered.compile().as_text()
+        assert text.startswith(f"HloModule jit_local_decode_s{st.index}")
+        assert "tpu_custom_call" in text
+        assert not window.search(text), window.search(text).group(0)
+        new = jax.tree.leaves(lowered.out_info[1])
+        assert [a.shape for a in new] == [(periods, B, 8, 128)] * 2
 
 
 def test_serve_stages_share_one_placed_body(one_chip, monkeypatch):
